@@ -90,8 +90,7 @@ def record_history(section: str, rows, stamp: dict) -> None:
 def section_main(section: str, run_fn, argv=None) -> None:
     """Shared ``python -m benchmarks.<section>`` entry point.
 
-    ``--quick`` shrinks the sweep, ``--compiled`` drops interpret mode,
-    ``--json [PATH]`` writes the stamped structured rows (default
+    ``--quick`` shrinks the sweep, ``--json [PATH]`` writes the stamped structured rows (default
     ``BENCH_<section>.json``).  With ``RACE_OBS=1`` the accumulated metrics
     + event snapshot lands in ``OBS_metrics.json``; with
     ``RACE_BENCH_HISTORY`` set the rows also append to the cross-run
@@ -102,8 +101,6 @@ def section_main(section: str, run_fn, argv=None) -> None:
 
     ap = argparse.ArgumentParser(description=f"{section} benchmark")
     ap.add_argument("--quick", action="store_true", help="smaller sweep")
-    ap.add_argument("--compiled", action="store_true",
-                    help="pallas rows compiled (interpret=False; needs TPU)")
     ap.add_argument("--json", nargs="?", const=f"BENCH_{section}.json",
                     default=None, metavar="PATH",
                     help="write stamped structured rows")
@@ -111,7 +108,7 @@ def section_main(section: str, run_fn, argv=None) -> None:
 
     print("name,us_per_call,derived")
     stamp = bench_stamp()
-    rows = run_fn(quick=args.quick, interpret=not args.compiled)
+    rows = run_fn(quick=args.quick)
     if args.json:
         with open(args.json, "w") as f:
             json.dump(dict(stamp=stamp, section=section,

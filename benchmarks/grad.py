@@ -16,8 +16,8 @@ For each case the sweep times, through the compiled-executor serving path,
     compiles forward + adjoint executors, every later step must be pure
     hits (the plan-reuse contract for training loops).
 
-Interpret-mode timings on CPU containers are correctness-plus-plumbing
-signal; absolute µs needs a real accelerator (``--compiled``).
+Interpret-mode timings on the CPU backend are correctness-plus-plumbing
+signal; absolute µs needs a TPU, where Pallas runs compiled.
 """
 from __future__ import annotations
 
@@ -47,8 +47,7 @@ def _grad_fn(res, env, diff_keys):
     return lambda e: grad({k: e[k] for k in diff_keys})
 
 
-def run(print_fn=print, quick: bool = False, repeats: int = None,
-        interpret: bool = True):
+def run(print_fn=print, quick: bool = False, repeats: int = None):
     """Returns one row per case; CSV is printed en route."""
     repeats = repeats or (3 if quick else 7)
     rows = []
@@ -91,7 +90,6 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
             reuse_hit_rate=hit_rate,
             cached_executors=info["currsize"],
             grad_steps=GRAD_STEPS,
-            interpret=interpret,
         )
         if build.ok and hit_rate <= 0.0:  # the plan-reuse contract
             raise AssertionError(

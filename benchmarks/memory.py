@@ -23,10 +23,7 @@ KERNELS = {"calc_tpoints": 512, "gaussian": 500, "psinv": 48, "resid": 48,
 
 def bytes_accessed(fn, env):
     comp = jax.jit(fn).lower(env).compile()
-    ca = comp.cost_analysis()
-    if isinstance(ca, list):  # older jaxlib returns one dict per device
-        ca = ca[0] if ca else {}
-    return float(ca.get("bytes accessed", 0.0))
+    return float(comp.cost_analysis().get("bytes accessed", 0.0))
 
 
 def run(print_fn=print):

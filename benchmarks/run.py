@@ -65,10 +65,6 @@ def main() -> None:
                     help="execution backend for the speedup section; "
                          "'pallas' adds a RACE-pallas column (cases the "
                          "capability probe rejects report their reason)")
-    ap.add_argument("--compiled", action="store_true",
-                    help="run the pallas backend compiled (interpret=False); "
-                         "requires a TPU runtime — interpret-mode timings on "
-                         "CPU are correctness signal only")
     ap.add_argument("--from-frontend", action="store_true",
                     help="add the 'frontend' section: capture the "
                          "plain-Python twins (repro.frontend), report "
@@ -84,15 +80,12 @@ def main() -> None:
         ("table1", lambda: table1.run()),
         ("speedup", lambda: speedup.run(
             cases=["calc_tpoints", "gaussian", "psinv", "derivative"] if args.quick else None,
-            backend=args.backend, interpret=not args.compiled)),
+            backend=args.backend)),
         ("scaling", lambda: scaling.run()),
         ("memory", lambda: memory.run()),
-        ("serving", lambda: serving.run(quick=args.quick,
-                                        interpret=not args.compiled)),
-        ("tuning", lambda: tuning.run(quick=args.quick,
-                                      interpret=not args.compiled)),
-        ("grad", lambda: grad.run(quick=args.quick,
-                                  interpret=not args.compiled)),
+        ("serving", lambda: serving.run(quick=args.quick)),
+        ("tuning", lambda: tuning.run(quick=args.quick)),
+        ("grad", lambda: grad.run(quick=args.quick)),
     ]
     if args.from_frontend:
         from . import frontend

@@ -6,9 +6,9 @@ Measures the three layers of the serving stack (PRs 3/10):
   * ``us_per_call``   median steady-state per-call wall time (cache hot);
   * ``cold_over_steady``  the compile-amortization ratio;
   * ``recompile_ms``  rebuild after an executor-cache eviction — the cost
-    the persistent compilation cache (``RACE_COMPILE_CACHE``) is there to
-    kill: warm it and this collapses to deserialization;
-  * ``compile_cache`` (off/cold/warm) stamped on **every** row: cold-ms
+    the persistent compilation cache (:mod:`repro.core.compile_cache`) is
+    there to kill: warm it and this collapses to deserialization;
+  * ``compile_cache`` (cold/warm) stamped on **every** row: cold-ms
     populations with and without a warm compilation cache are incomparable,
     so history gating must never mix them (it is an identity field in
     ``repro.obs.history``);
@@ -24,8 +24,8 @@ Measures the three layers of the serving stack (PRs 3/10):
     submission throughput vs dispatching the same requests through the
     runtime one at a time (the dynamic-batching acceptance: >= 3x).
 
-Pallas rows run in interpret mode on CPU containers — correctness-plus-
-caching signal only; absolute kernel timings need a TPU (``--compiled``).
+Pallas rows are interpreted on the CPU backend — correctness-plus-caching
+signal only; kernel timings come from a TPU, where they run compiled.
 """
 from __future__ import annotations
 
@@ -56,17 +56,13 @@ CASES = [("calc_tpoints", 64), ("gaussian", 64), ("psinv", 16)]
 QUEUE_CASES = [("gaussian", 24), ("calc_tpoints", 16)]
 
 
-def _compile_cache_state(delta_hits: int, delta_misses: int) -> str:
-    """off / cold / warm for one measured compile, from the persistent
-    cache's traffic while it ran."""
-    if not compile_cache.enabled():
-        return "off"
-    if delta_hits > 0:
-        return "warm"
-    return "cold"
+def _compile_cache_state(delta_hits: int) -> str:
+    """cold / warm for one measured compile, from the persistent cache's
+    hits while it ran."""
+    return "warm" if delta_hits > 0 else "cold"
 
 
-def _bench_backend(res, case, backend, repeats, batch, interpret,
+def _bench_backend(res, case, backend, repeats, batch,
                    block_rows=8, block_cols=8, block_inner=0):
     # the exact candidate config this row ran under: BENCH_serving.json
     # entries stay comparable across PRs even once autotuning can move the
@@ -79,17 +75,16 @@ def _bench_backend(res, case, backend, repeats, batch, interpret,
 
     cc0 = compile_cache.counts()
     t0 = time.perf_counter()
-    jax.block_until_ready(res.run(env, backend, interpret=interpret))
+    jax.block_until_ready(res.run(env, backend))
     cold = time.perf_counter() - t0
     cc1 = compile_cache.counts()
-    cc_state = _compile_cache_state(cc1["hits"] - cc0["hits"],
-                                    cc1["misses"] - cc0["misses"])
+    cc_state = _compile_cache_state(cc1["hits"] - cc0["hits"])
 
     s0 = cache.stats_snapshot()
     ts = []
     for _ in range(repeats):
         t1 = time.perf_counter()
-        jax.block_until_ready(res.run(env, backend, interpret=interpret))
+        jax.block_until_ready(res.run(env, backend))
         ts.append(time.perf_counter() - t1)
     steady = float(np.median(ts))
     s1 = cache.stats_snapshot()
@@ -97,7 +92,7 @@ def _bench_backend(res, case, backend, repeats, batch, interpret,
     hit_rate = (s1["hits"] - s0["hits"]) / served if served else 0.0
 
     ex = compile_plan(res.plan, env, backend, block_rows=block_rows,
-                      block_cols=block_cols, interpret=interpret)
+                      block_cols=block_cols)
     envs = [build_env(case, seed=s) for s in range(batch)]
     jax.block_until_ready(ex.run_batch(envs))  # warm the batched trace
     t2 = time.perf_counter()
@@ -106,10 +101,10 @@ def _bench_backend(res, case, backend, repeats, batch, interpret,
     retraces = ex.trace_count
 
     # eviction-rebuild cost: what a fresh process (or an LRU victim) pays to
-    # serve this plan again — the number RACE_COMPILE_CACHE exists to kill
+    # serve this plan again — the number the compile cache exists to kill
     cache.clear()
     t3 = time.perf_counter()
-    jax.block_until_ready(res.run(env, backend, interpret=interpret))
+    jax.block_until_ready(res.run(env, backend))
     recompile = time.perf_counter() - t3
 
     return dict(
@@ -120,8 +115,7 @@ def _bench_backend(res, case, backend, repeats, batch, interpret,
         batch_us_per_item=t_batch / batch * 1e6,
         batch_ips=batch / max(t_batch, 1e-12),
         cache_entries=len(cache),
-        config=dict(config.as_dict(), interpret=interpret,
-                    plan=plan_hash(res.plan)),
+        config=dict(config.as_dict(), plan=plan_hash(res.plan)),
     )
 
 
@@ -170,8 +164,7 @@ def _bench_queue(res, case, repeats, batch=8):
             rt.warmup([(res.plan, env)], backend=backend)
             cc1 = compile_cache.counts()
             if cc_state is None:
-                cc_state = _compile_cache_state(cc1["hits"] - cc0["hits"],
-                                                cc1["misses"] - cc0["misses"])
+                cc_state = _compile_cache_state(cc1["hits"] - cc0["hits"])
             t0 = time.perf_counter()
             rt.run(res.plan, env, timeout=120)
             firsts.append((time.perf_counter() - t0) * 1e6)
@@ -255,7 +248,7 @@ def _span_tag(spans: dict) -> str:
 
 
 def run(print_fn=print, quick: bool = False, repeats: int = None,
-        batch: int = None, interpret: bool = True):
+        batch: int = None):
     """Returns one row per (case, backend) plus one queue row per case;
     CSV is printed en route.
 
@@ -264,7 +257,6 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
     that row executed — and a case that records *no* pipeline spans is a
     hard error: the instrumentation regressed, not the benchmark.
     """
-    compile_cache.ensure_enabled()
     repeats = repeats or (5 if quick else 20)
     batch = batch or (4 if quick else 8)
     rows = []
@@ -304,8 +296,7 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
             backends.append("pallas")
         for backend in backends:
             spans0 = obs.span_summary() if obs.enabled() else {}
-            row = _bench_backend(res, case, backend, repeats, batch,
-                                 interpret)
+            row = _bench_backend(res, case, backend, repeats, batch)
             derived = (f"cold_ms={row['cold_ms']:.1f}"
                        f";cold_over_steady={row['cold_over_steady']:.0f}x"
                        f";recompile_ms={row['recompile_ms']:.1f}"
@@ -340,25 +331,17 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true", help="smaller sweep")
     ap.add_argument("--repeats", type=int, default=None)
     ap.add_argument("--batch", type=int, default=None)
-    ap.add_argument("--compiled", action="store_true",
-                    help="pallas rows compiled (interpret=False; needs TPU)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable the persistent compilation cache at DIR "
-                         "for this run (same as RACE_COMPILE_CACHE)")
     ap.add_argument("--json", nargs="?", const="BENCH_serving.json",
                     default=None, metavar="PATH",
                     help="write stamped structured rows (default "
                          "BENCH_serving.json)")
     args = ap.parse_args(argv)
 
-    if args.compile_cache:
-        compile_cache.configure(args.compile_cache)
     print("name,us_per_call,derived")
     from .common import bench_stamp, record_history
 
     stamp = bench_stamp()
-    rows = run(quick=args.quick, repeats=args.repeats, batch=args.batch,
-               interpret=not args.compiled)
+    rows = run(quick=args.quick, repeats=args.repeats, batch=args.batch)
     if args.json:
         with open(args.json, "w") as f:
             json.dump(dict(stamp=stamp, section="serving",

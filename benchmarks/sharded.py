@@ -51,8 +51,7 @@ CASES_QUICK = [("j3d27pt", 10)]
 SHARD_COUNTS = (1, 2, 4, 8)
 
 
-def run(print_fn=print, quick: bool = False, repeats: int = None,
-        interpret: bool = True):
+def run(print_fn=print, quick: bool = False, repeats: int = None):
     """Returns one row per (case, shards, strategy) plus a single-device
     baseline row per case; CSV is printed en route."""
     repeats = repeats or (5 if quick else 20)
@@ -81,7 +80,7 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
                     continue
                 mesh = make_stencil_mesh(k, ("sx", "sy"))
                 ex = compile_sharded(res, env, mesh, halo=strategy,
-                                     backend="xla", interpret=interpret)
+                                     backend="xla")
                 t = time_callable(ex, env, repeats=repeats)
                 t_one.setdefault(strategy, t)
                 scaling = t_one[strategy] / t

@@ -39,14 +39,12 @@ BENCH_SIZES = {
 }
 
 
-def run(cases=None, print_fn=print, repeats: int = 5, backend: str = "xla",
-        interpret: bool = True):
+def run(cases=None, print_fn=print, repeats: int = 5, backend: str = "xla"):
     """``backend="pallas"`` additionally times the Pallas realization of the
     RACE plan so the table compares xla vs pallas; ineligible cases report
     the capability probe's fallback reason instead of a silently-identical
-    number.  ``interpret=True`` (the CPU-container default) times the
-    interpreter — correctness signal only; pass ``interpret=False`` on a TPU
-    runtime (``run.py --compiled``) for meaningful kernel timings."""
+    number.  On the CPU backend that times the Pallas interpreter —
+    correctness signal only; kernel timings come from a TPU."""
     rows = []
     for name in cases or TABLE1_ORDER:
         case = get_case(name, BENCH_SIZES.get(name))
@@ -77,8 +75,7 @@ def run(cases=None, print_fn=print, repeats: int = 5, backend: str = "xla",
 
             sel = select_backend(v["RACE"].plan, "auto")
             if sel.backend == "pallas":
-                ex = compile_plan(v["RACE"].plan, env, "pallas",
-                                  interpret=interpret)
+                ex = compile_plan(v["RACE"].plan, env, "pallas")
                 t = time_callable(ex, env, repeats)
                 speed["RACE-pallas"] = t_base / t
                 derived += f";speedup_RACE-pallas={t_base / t:.2f}"

@@ -15,9 +15,9 @@ and reports
 The tuner falls back to the default on ties, so ``tuned_us <= baseline_us``
 up to measurement noise — the sweep asserts it (``never_slower``).
 
-Pallas candidates run in interpret mode on CPU containers: timings there
-are correctness-plus-plumbing signal; the real strategy search needs a TPU
-(``--compiled``).
+Pallas candidates are interpreted on the CPU backend: timings there are
+correctness-plus-plumbing signal; the real strategy search needs a TPU,
+where they run compiled.
 """
 from __future__ import annotations
 
@@ -33,8 +33,7 @@ from .common import build_env, csv_line
 CASES = [("calc_tpoints", 48), ("gaussian", 48), ("psinv", 12)]
 
 
-def run(print_fn=print, quick: bool = False, repeats: int = None,
-        interpret: bool = True):
+def run(print_fn=print, quick: bool = False, repeats: int = None):
     """Returns one row per case; CSV is printed en route."""
     repeats = repeats or (3 if quick else 7)
     levels = (0, 3) if quick else (0, 3, 4)
@@ -45,7 +44,7 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
         case = get_case(name, n)
         env = build_env(case)
         dec = autotune(case.program, env, levels=levels, repeats=repeats,
-                       warmup=1, quick=quick, interpret=interpret,
+                       warmup=1, quick=quick,
                        default_reassociate=case.reassociate,
                        rewrite_div=case.rewrite_div, store=store)
         # same search-shaping options as the first call: the store key now
@@ -72,7 +71,6 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
             n_gated=sum(m.status == "gated" for m in dec.measurements),
             store_hit=redo.from_cache,
             never_slower=dec.tuned_us <= dec.default_us,
-            interpret=interpret,
         )
         if not row["never_slower"]:  # the acceptance invariant
             raise AssertionError(

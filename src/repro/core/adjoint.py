@@ -662,12 +662,12 @@ def finalize_adjoint(spec: InputSpec, env: Mapping, val):
 
 
 def _run_spec(spec: InputSpec, env: Mapping, g: Mapping, *,
-              interpret: bool, backend: Optional[str]):
+              backend: Optional[str]):
     from .executor import compile_plan
 
     res = spec.result()
     adj_env = assemble_adjoint_env(spec, env, g)
-    ex = compile_plan(res.plan, adj_env, backend, interpret=interpret)
+    ex = compile_plan(res.plan, adj_env, backend)
     val = ex(adj_env)[spec.gu]
     return finalize_adjoint(spec, env, val)
 
@@ -705,7 +705,7 @@ def _autodiff_backward(program: Program, env: Mapping, g: Mapping) -> dict:
 
 
 def backward(program: Program, env: Mapping, g: Mapping, *,
-             interpret: bool = True, backend: Optional[str] = None) -> dict:
+             backend: Optional[str] = None) -> dict:
     """VJP of the program's interior-convention outputs w.r.t. ``env``.
 
     ``g`` maps output names to cotangents.  Returns a full-env gradient
@@ -724,8 +724,7 @@ def backward(program: Program, env: Mapping, g: Mapping, *,
     with _obs.span("adjoint_backward"):
         grads = {}
         for spec in build.specs:
-            grads[spec.input] = _run_spec(spec, env, g, interpret=interpret,
-                                          backend=backend)
+            grads[spec.input] = _run_spec(spec, env, g, backend=backend)
     if _obs.enabled():
         _obs.counter("race_adjoint_backward_total", mode="stencil").inc()
     return {k: (grads[k] if k in grads else _zero_cotangent(v))
@@ -737,7 +736,7 @@ def backward(program: Program, env: Mapping, g: Mapping, *,
 # ---------------------------------------------------------------------------
 
 
-def make_custom_vjp(core, program: Program, *, interpret: bool = True):
+def make_custom_vjp(core, program: Program):
     """Wrap an executor core (``env dict -> outputs dict``) so differentiating
     through it runs the adjoint-stencil programs instead of tracing autodiff
     through the forward internals (whose ``optimization_barrier`` has no
@@ -751,7 +750,7 @@ def make_custom_vjp(core, program: Program, *, interpret: bool = True):
         return core(env), dict(env)
 
     def bwd(env, g):
-        return (backward(program, env, g, interpret=interpret),)
+        return (backward(program, env, g),)
 
     call.defvjp(fwd, bwd)
     return call

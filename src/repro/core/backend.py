@@ -15,7 +15,8 @@ gather) all lower — the probe reports them as lowering *facts*, not
 fallbacks.  What remains on XLA are genuinely out-of-model programs only:
 malformed writes, zero-coefficient or fractional subscripts, per-array
 layout/stride inconsistencies, non-unit auxiliary references, and
-scalar-only data.
+scalar-only data — plus, on a TPU, the two mechanisms its compiler refuses
+(in-kernel gather and strided windows).
 
 This module no longer *knows* the restrictions — it delegates to
 :func:`repro.lowering.geometry.analyze_plan`, the same analysis the engine
@@ -32,14 +33,15 @@ loads those lazily), so asking "would this lower?" is free.
 from __future__ import annotations
 
 from dataclasses import dataclass
-
 from repro import obs as _obs
 from repro.lowering.facts import (  # noqa: F401  (stable re-exports)
     FALLBACK_CODES, RETIRED_CODES, R_CONSTANT_DIM, R_DEPTH,
     R_FRACTIONAL_OFFSET, R_INCONSISTENT_LAYOUT, R_LHS_FORM, R_MIXED_STRIDE,
-    R_NEGATIVE_COEF, R_NO_BASE_ARRAY, R_REPEATED_LEVEL, R_STRIDED_AUX,
-    R_ZERO_COEF, FallbackReason, LoweringFact)
-from repro.lowering.geometry import analyze_plan
+    R_NEGATIVE_COEF, R_NO_BASE_ARRAY, R_PLATFORM, R_REPEATED_LEVEL,
+    R_STRIDED_AUX, R_TPU_GATHER, R_TPU_STRIDED, R_ZERO_COEF, FallbackReason,
+    LoweringFact)
+from repro.lowering.geometry import (analyze_plan, platform_reasons,
+                                     target_platform)
 
 from .depgraph import Plan
 
@@ -92,7 +94,8 @@ class BackendUnavailable(RuntimeError):
 
 
 def probe_pallas(plan: Plan) -> Capability:
-    """Probe a plan against the lowering engine's own analysis.
+    """Probe a plan against the lowering engine's own analysis, for the
+    platform its kernels run on (jax's default backend).
 
     The verdict is *re-derived from the engine* — this is literally the
     analysis ``repro.lowering.specialize_stencil`` builds kernels from
@@ -103,9 +106,14 @@ def probe_pallas(plan: Plan) -> Capability:
     hold the plan's halo spread — that per-(array, level) capacity check is
     the one *shape-dependent* failure left at specialize time, and its
     error names the block knob to raise.
+
+    On a TPU the kernel is compiled, and the probe also refuses what the
+    TPU compiler refuses (``tpu-gather``, ``tpu-strided``), so ``auto``
+    never picks a kernel that cannot compile there.
     """
     a = analyze_plan(plan)
-    return Capability(eligible=a.eligible, reasons=a.reasons, facts=a.facts)
+    reasons = a.reasons or platform_reasons(a, target_platform())
+    return Capability(eligible=not reasons, reasons=reasons, facts=a.facts)
 
 
 def select_backend(plan: Plan, requested: str = "auto") -> Selection:

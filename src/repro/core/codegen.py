@@ -31,23 +31,6 @@ import jax.numpy as jnp
 from .depgraph import Plan
 from .ir import Const, Expr, FuncName, Node, Program, Ref, Stmt
 
-# jax<=0.4.x has no batching rule for optimization_barrier, which breaks
-# vmap over the plan evaluator (the executor's run_batch path); the barrier
-# is shape-identity, so the trivial rule is correct.
-def _register_barrier_batching():
-    try:
-        from jax._src.lax.lax import optimization_barrier_p as _p
-        from jax.interpreters import batching
-
-        if _p not in batching.primitive_batchers:
-            batching.primitive_batchers[_p] = \
-                lambda args, dims: (_p.bind(*args), dims)
-    except Exception:  # pragma: no cover - newer jax ships its own rule
-        pass
-
-
-_register_barrier_batching()
-
 FUNCS = {
     "sin": jnp.sin,
     "cos": jnp.cos,
@@ -239,31 +222,6 @@ def build_baseline_evaluator(program: Program):
         return out
 
     return run
-
-
-def build_evaluator(plan: Plan, backend: str = "auto", *, block_rows: int = 8,
-                    block_cols: int = 8, interpret: bool = True):
-    """Backend-dispatching evaluator factory for a plan.
-
-    Returns ``(run, selection)``: ``run(env)`` yields interior-convention
-    outputs on the resolved backend; ``selection`` says which backend was
-    chosen and, on an ``auto`` fallback, why Pallas was ineligible.
-    """
-    from .backend import select_backend
-
-    sel = select_backend(plan, backend)
-    if sel.backend == "pallas":
-        from functools import partial as _partial
-
-        from repro.lowering import race_stencil_call
-
-        run = _partial(race_stencil_call, plan, block_rows=block_rows,
-                       block_cols=block_cols, interpret=interpret)
-        return run, sel
-    from repro.kernels.ref import interior
-
-    plan_run = build_plan_evaluator(plan)
-    return (lambda env: interior(plan, plan_run(env))), sel
 
 
 def required_shapes(program: Program) -> dict:

@@ -269,7 +269,7 @@ class ExecutorKey:
     plan: str  # structural plan hash
     env: tuple  # env_signature
     backend: str  # resolved: "xla" | "pallas"
-    #: (block_rows, block_cols, block_inner, interpret) | None (xla)
+    #: (block_rows, block_cols, block_inner) | None (xla)
     blocks: Optional[tuple]
     donate: bool
     #: device context (``device_context()``); "" only on legacy keys
@@ -335,8 +335,7 @@ class CompiledRace:
 
     def __init__(self, plan: Plan, env_sig: tuple, selection: Selection, *,
                  block_rows: int = 8, block_cols: int = 8,
-                 block_inner: int = 0, interpret: bool = True,
-                 donate: bool = False):
+                 block_inner: int = 0, donate: bool = False):
         self.plan = plan
         self.env_sig = env_sig
         self.selection = selection
@@ -344,7 +343,6 @@ class CompiledRace:
         self.block_rows = block_rows
         self.block_cols = block_cols
         self.block_inner = block_inner
-        self.interpret = interpret
         self.donate = donate
         self.calls = 0
         self.batch_calls = 0
@@ -355,10 +353,10 @@ class CompiledRace:
         self._batch_jit = None
         self._plan_h = plan_hash(plan)
 
-        # zero cold start: if $RACE_COMPILE_CACHE is set, the XLA compile
-        # this executor triggers on its first call is served from (and
-        # persisted to) the on-disk compilation cache.  Must happen before
-        # jit dispatch, hence here in the builder.
+        # zero cold start: the XLA compile this executor triggers on its
+        # first call is served from (and persisted to) the on-disk
+        # compilation cache.  Must happen before jit dispatch, hence here in
+        # the builder.
         from . import compile_cache as _ccache
 
         _ccache.ensure_enabled()
@@ -372,7 +370,7 @@ class CompiledRace:
                     {nm: shp for nm, shp, *_ in env_sig},
                     {nm: np.dtype(dt) for nm, _, dt, *_ in env_sig},
                     block_rows=block_rows, block_cols=block_cols,
-                    interpret=interpret, block_inner=block_inner)
+                    block_inner=block_inner)
                 core = self.spec.apply
             else:
                 from repro.kernels.ref import interior
@@ -392,8 +390,7 @@ class CompiledRace:
         # bare core, so non-grad callers are unaffected.
         from .adjoint import make_custom_vjp
 
-        self._vjp_core = make_custom_vjp(core, plan.program,
-                                         interpret=interpret)
+        self._vjp_core = make_custom_vjp(core, plan.program)
         vjp_core = self._vjp_core
 
         def _call(env_in, env_out):
@@ -699,7 +696,7 @@ def _tuned_choice(plan: Plan, sig: tuple) -> Optional[dict]:
 def compile_plan(plan: Plan, env: Union[Mapping, tuple],
                  backend: Optional[str] = None, *, block_rows: int = 8,
                  block_cols: int = 8, block_inner: int = 0,
-                 interpret: bool = True, donate: Optional[bool] = None,
+                 donate: Optional[bool] = None,
                  cache: Optional[ExecutorCache] = None) -> CompiledRace:
     """Fetch (or build) the compiled executor for this (plan, env) pairing.
 
@@ -733,7 +730,7 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
                         block_cols=int(choice.get("block_cols", block_cols)),
                         block_inner=int(choice.get("block_inner",
                                                    block_inner)),
-                        interpret=interpret, donate=donate, cache=cache)
+                        donate=donate, cache=cache)
                 except ValueError:
                     # stale/corrupt stored block config (e.g. a block too
                     # small for the plan's halo spread, from a hand-edited
@@ -748,11 +745,11 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
         donate = False
     elif donate and jax.default_backend() in ("cpu",):
         donate = False
-    blocks = ((block_rows, block_cols, block_inner, bool(interpret))
+    blocks = ((block_rows, block_cols, block_inner)
               if sel.backend == "pallas" else None)
     key = ExecutorKey(plan_hash(plan), sig, sel.backend, blocks, bool(donate),
                       device=device_context())
     c = cache if cache is not None else _CACHE
     return c.get_or_build(key, lambda: CompiledRace(
         plan, sig, sel, block_rows=block_rows, block_cols=block_cols,
-        block_inner=block_inner, interpret=interpret, donate=bool(donate)))
+        block_inner=block_inner, donate=bool(donate)))

@@ -149,7 +149,7 @@ class RaceResult:
 
     def run(self, env: dict, backend: Optional[str] = None, *,
             block_rows: int = 8, block_cols: int = 8, block_inner: int = 0,
-            interpret: bool = True, donate: Optional[bool] = None):
+            donate: Optional[bool] = None):
         """Execute the plan on the selected backend.
 
         Both backends return the *interior* convention — ``{output name:
@@ -177,7 +177,7 @@ class RaceResult:
             # an explicit backend= on run() opts back into single-device
             return self.run_sharded(
                 env, block_rows=block_rows, block_cols=block_cols,
-                block_inner=block_inner, interpret=interpret)
+                block_inner=block_inner)
         if backend is None and (self._tuned
                                 or self.options.get("tune") is not None):
             entry = self._tuned_entry(env, env_signature(env))
@@ -187,18 +187,17 @@ class RaceResult:
                 ex = compile_plan(
                     target.plan, env, ch.backend, block_rows=ch.block_rows,
                     block_cols=ch.block_cols, block_inner=ch.block_inner,
-                    interpret=interpret, donate=donate)
+                    donate=donate)
                 return ex(env)
         ex = compile_plan(
             self.plan, env, backend or self.options.get("backend", "auto"),
             block_rows=block_rows, block_cols=block_cols,
-            block_inner=block_inner, interpret=interpret, donate=donate)
+            block_inner=block_inner, donate=donate)
         return ex(env)
 
     def run_sharded(self, env: dict, mesh=None, backend: Optional[str] = None,
                     *, halo: Optional[str] = None, block_rows: int = 8,
-                    block_cols: int = 8, block_inner: int = 0,
-                    interpret: bool = True):
+                    block_cols: int = 8, block_inner: int = 0):
         """Execute spatially partitioned over a device mesh.
 
         The plan's iteration box is split across ``mesh`` (falling back to
@@ -228,13 +227,12 @@ class RaceResult:
             else self.options.get("halo", "auto"),
             backend=backend or self.options.get("backend", "auto"),
             block_rows=block_rows, block_cols=block_cols,
-            block_inner=block_inner, interpret=interpret)
+            block_inner=block_inner)
         return ex(env)
 
     def run_batch(self, envs, backend: Optional[str] = None, *,
                   block_rows: int = 8, block_cols: int = 8,
-                  block_inner: int = 0, interpret: bool = True,
-                  donate: Optional[bool] = None):
+                  block_inner: int = 0, donate: Optional[bool] = None):
         """Batched execution: one compiled executor vmapped over ``envs``.
 
         ``envs`` is a sequence of same-signature environments, or an
@@ -271,13 +269,12 @@ class RaceResult:
                 ex = compile_plan(
                     target.plan, sig, ch.backend, block_rows=ch.block_rows,
                     block_cols=ch.block_cols, block_inner=ch.block_inner,
-                    interpret=interpret, donate=donate)
+                    donate=donate)
                 return ex.run_batch(envs)
         ex = compile_plan(
             self.plan, sig, backend or self.options.get("backend", "auto"),
             block_rows=block_rows, block_cols=block_cols,
-            block_inner=block_inner, interpret=interpret,
-            donate=donate)
+            block_inner=block_inner, donate=donate)
         return ex.run_batch(envs)
 
     # --- observability ------------------------------------------------------

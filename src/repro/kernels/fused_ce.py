@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.lowering import pallas_interpret
+
 NEG = -1e30
 
 
@@ -58,9 +60,11 @@ def _kernel(h_ref, w_ref, lab_ref, out_ref, m_ref, l_ref, g_ref, *, v_blk):
             jnp.maximum(l_ref[...], 1e-30)) - g_ref[...]
 
 
-def fused_ce_forward(h, w, labels, t_blk: int = 128, v_blk: int = 2048,
-                     interpret: bool = True):
-    """h: (T, D); w: (D, V); labels: (T,) int32 -> per-token loss (T,) f32."""
+def fused_ce_forward(h, w, labels, t_blk: int = 128, v_blk: int = 2048):
+    """h: (T, D); w: (D, V); labels: (T,) int32 -> per-token loss (T,) f32.
+
+    The Pallas mode is the platform's
+    (:func:`repro.lowering.pallas_interpret`)."""
     T, D = h.shape
     V = w.shape[1]
     t_blk = min(t_blk, T)
@@ -89,14 +93,14 @@ def fused_ce_forward(h, w, labels, t_blk: int = 128, v_blk: int = 2048,
             pltpu.VMEM((t_blk,), jnp.float32),
             pltpu.VMEM((t_blk,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(h, w, labels)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_ce(h, w, labels, interpret=True):
+@jax.custom_vjp
+def fused_ce(h, w, labels):
     """Mean CE loss with the fused forward; backward recomputes via XLA."""
-    return fused_ce_forward(h, w, labels, interpret=interpret).mean()
+    return fused_ce_forward(h, w, labels).mean()
 
 
 def _ce_ref(h, w, labels):
@@ -106,11 +110,11 @@ def _ce_ref(h, w, labels):
     return (lse - gold).mean()
 
 
-def _fwd(h, w, labels, interpret):
-    return fused_ce(h, w, labels, interpret), (h, w, labels)
+def _fwd(h, w, labels):
+    return fused_ce(h, w, labels), (h, w, labels)
 
 
-def _bwd(interpret, res, g):
+def _bwd(res, g):
     h, w, labels = res
     dh, dw = jax.grad(_ce_ref, argnums=(0, 1))(h, w, labels)
     return jax.tree.map(lambda t: (t * g).astype(t.dtype), (dh, dw)) + (None,)

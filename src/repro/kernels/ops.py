@@ -10,19 +10,16 @@ from repro.lowering import race_stencil_call
 
 
 def race_stencil(result: RaceResult, env: dict, block_rows: int = 8,
-                 block_cols: int = 8, interpret: bool = True):
-    """Run a RACE-optimized stencil via the Pallas kernel.
-
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on a TPU runtime pass ``interpret=False`` for the compiled kernel."""
+                 block_cols: int = 8):
+    """Run a RACE-optimized stencil via the Pallas kernel (compiled on a
+    TPU, interpreted on the CPU backend)."""
     fn = partial(race_stencil_call, result.plan, block_rows=block_rows,
-                 block_cols=block_cols, interpret=interpret)
+                 block_cols=block_cols)
     return jax.jit(fn)(env)
 
 
 def optimize_and_run(program, env: dict, reassociate: int = 3,
-                     block_rows: int = 8, block_cols: int = 8,
-                     interpret: bool = True):
+                     block_rows: int = 8, block_cols: int = 8):
     """One-shot: RACE-optimize a stencil program and execute it."""
     res = race(program, reassociate=reassociate)
-    return res, race_stencil(res, env, block_rows, block_cols, interpret)
+    return res, race_stencil(res, env, block_rows, block_cols)

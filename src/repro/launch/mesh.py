@@ -10,11 +10,7 @@ import jax
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the pinned jax has it (added after 0.4.x);
-    older versions default every axis to Auto anyway."""
-    if hasattr(jax.sharding, "AxisType"):
-        return dict(axis_types=(jax.sharding.AxisType.Auto,) * n_axes)
-    return {}
+    return dict(axis_types=(jax.sharding.AxisType.Auto,) * n_axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -76,7 +72,22 @@ def make_stencil_mesh(n_devices=None, axes=("sx", "sy")):
     return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
 
 
-# v5e hardware constants used by the roofline analysis (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW_PER_LINK = 50e9        # B/s per link
+#: Published per-chip peaks keyed by ``device_kind`` as JAX reports it.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+#: HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over four
+#: links).
+PEAKS = {
+    "TPU v5 lite": dict(flops_bf16=197e12, hbm_bw=819e9,
+                        ici_bw_per_link=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of a device kind; an unlisted kind is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            f"repro.launch.mesh.PEAKS with its source") from None

@@ -10,7 +10,7 @@ every client submits one blocking request at a time, so ``--concurrency``
 is the number of requests in flight and the runtime's batching window does
 the coalescing.  Reports per-request p50/p95 latency, sustained rps, the
 runtime's coalescing stats, and the persistent-compilation-cache state
-(off/cold/warm) the warmup observed.
+(cold/warm) the warmup observed.
 
 Legacy LM decode (prefill + KV-cache decode)::
 
@@ -36,11 +36,6 @@ def serve_case(args) -> None:
     from repro.serve import ServeRuntime
     from repro.testing.differential import build_env
 
-    if args.compile_cache:
-        compile_cache.configure(args.compile_cache)
-    else:
-        compile_cache.ensure_enabled()
-
     case = get_case(args.case, args.n)
     res = race(case.program, reassociate=case.reassociate,
                rewrite_div=case.rewrite_div)
@@ -52,12 +47,7 @@ def serve_case(args) -> None:
         cc0 = compile_cache.counts()
         warm = rt.warmup([(res.plan, envs[0])], backend=args.backend)
         cc1 = compile_cache.counts()
-        if not compile_cache.enabled():
-            cc_state = "off"
-        elif cc1["hits"] - cc0["hits"] > 0:
-            cc_state = "warm"
-        else:
-            cc_state = "cold"
+        cc_state = "warm" if cc1["hits"] - cc0["hits"] > 0 else "cold"
 
         per_client = max(1, args.requests // args.concurrency)
         lat_lock = threading.Lock()
@@ -249,9 +239,6 @@ def main():
                     help="serve mode: RACE_SERVE_MAX_BATCH override")
     ap.add_argument("--window-us", type=float, default=None,
                     help="serve mode: RACE_SERVE_WINDOW_US override")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="serve mode: persistent compilation cache dir "
-                         "(same as RACE_COMPILE_CACHE)")
     ap.add_argument("--json", nargs="?", const="-", default=None,
                     metavar="PATH",
                     help="structured output to stdout ('-') or PATH")

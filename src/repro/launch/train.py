@@ -3,11 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3_14b --reduced \
         --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ck
 
-On this CPU container use ``--reduced`` (family-faithful small config).  On a
+On the CPU backend use ``--reduced`` (family-faithful small config).  On a
 TPU pod slice the same entry point runs the full config: each host executes
 this script (jax.distributed initializes from the TPU environment), the mesh
 comes from ``make_production_mesh``, and per-host data sharding follows
-process_index.  ``launch/tpu_pod.sh`` shows the gcloud invocation.
+process_index.
 """
 from __future__ import annotations
 

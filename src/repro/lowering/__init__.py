@@ -17,7 +17,8 @@ modules generic over nest depth and window shape:
     repeated-level and constant-dim references;
   * :mod:`repro.lowering.emit`     — the traceable kernel body plus
     :class:`LoweredStencil`, the one-time specialization artifact the
-    executor caches.
+    executor caches, and :func:`pallas_interpret`, the one place the Pallas
+    mode is chosen: compiled on a TPU, interpreted on the CPU backend.
 
 Importing ``repro.lowering`` itself stays jax-free: the emit-side symbols
 (``specialize_stencil``, ``LoweredStencil``, ``race_stencil_call``, ...)
@@ -29,15 +30,17 @@ from __future__ import annotations
 from .facts import (FALLBACK_CODES, RETIRED_CODES, R_CONSTANT_DIM, R_DEPTH,
                     R_FRACTIONAL_OFFSET, R_INCONSISTENT_LAYOUT, R_LHS_FORM,
                     R_MIXED_STRIDE, R_NEGATIVE_COEF, R_NO_BASE_ARRAY,
-                    R_REPEATED_LEVEL, R_SCALAR_AUX, R_STRIDED_AUX,
-                    R_ZERO_COEF, FallbackReason, LoweringError, LoweringFact)
+                    R_PLATFORM, R_REPEATED_LEVEL, R_SCALAR_AUX,
+                    R_STRIDED_AUX, R_TPU_GATHER, R_TPU_STRIDED, R_ZERO_COEF,
+                    FallbackReason, LoweringError, LoweringFact)
 from .geometry import (K_GATHER, K_WINDOW, ArrayInfo, LoweringAnalysis,
                        analyze_plan, analyze_program, offset_envelopes,
-                       plan_geometry, program_envelopes)
+                       plan_geometry, platform_reasons, program_envelopes,
+                       target_platform)
 
 #: emit-side symbols resolved lazily (they import jax + Pallas)
 _EMIT = ("LoweredStencil", "StencilSpec", "specialize_stencil",
-         "race_stencil_call", "build_kernel")
+         "pallas_interpret", "race_stencil_call", "build_kernel")
 _BLOCKS = ("ArrayPrep", "Layout", "build_layout", "level_blocks")
 _GATHER = ("gather_ref",)
 
@@ -45,11 +48,13 @@ __all__ = [
     "FALLBACK_CODES", "RETIRED_CODES", "R_CONSTANT_DIM", "R_DEPTH",
     "R_FRACTIONAL_OFFSET", "R_INCONSISTENT_LAYOUT", "R_LHS_FORM",
     "R_MIXED_STRIDE", "R_NEGATIVE_COEF", "R_NO_BASE_ARRAY",
-    "R_REPEATED_LEVEL", "R_SCALAR_AUX", "R_STRIDED_AUX", "R_ZERO_COEF",
+    "R_PLATFORM", "R_REPEATED_LEVEL", "R_SCALAR_AUX", "R_STRIDED_AUX",
+    "R_TPU_GATHER", "R_TPU_STRIDED", "R_ZERO_COEF",
     "FallbackReason", "LoweringError", "LoweringFact",
     "K_GATHER", "K_WINDOW", "ArrayInfo", "LoweringAnalysis",
     "analyze_plan", "analyze_program", "offset_envelopes",
-    "plan_geometry", "program_envelopes",
+    "plan_geometry", "platform_reasons", "program_envelopes",
+    "target_platform",
     *_EMIT, *_BLOCKS, *_GATHER,
 ]
 

@@ -3,11 +3,17 @@
 The iteration space is laid out level-major (outermost loop level = axis 0).
 Every level except the innermost is grid-tiled — level 1 by ``block_rows``,
 levels ``2..m-1`` by ``block_cols`` — and the innermost level stays
-full-width for the VPU lanes unless ``block_inner > 0`` tiles it too.  A
-1-D nest tiles its single level by ``block_rows`` (or ``block_inner`` when
-given).  This reproduces the historical 2-D/3-D layouts exactly and extends
-them to any depth: a 4-D nest gets a 3-axis grid (levels 1-3) with 27 halo
-block copies per fully-covered window operand.
+full-width for the VPU lanes unless ``block_inner > 0`` tiles it too.  The
+single level of a 1-D nest is its innermost level, so it follows the same
+rule.  A 4-D nest gets a 3-axis grid (levels 1-3) with 27 halo block copies
+per fully-covered window operand.
+
+Every block satisfies the TPU's tiling rule, also under ``jax.vmap`` (which
+adds a squeezed batch axis in front): the last block axis spans its whole
+array axis (or ``block_inner``, a multiple of 128 on a TPU) and the one
+before it is whole or a multiple of 8.  A window operand that misses a level
+gets a size-1 axis there, so it has rank ``m``, and a 1-D nest is lifted to
+rank 2 by a leading size-1 axis on its windows and outputs.
 
 Per window-class array and blocked level the input window is the standard
 three consecutive input blocks (prev/cur/next) of ``|a|·tile`` elements; a
@@ -38,9 +44,13 @@ from .geometry import K_GATHER, K_WINDOW, LoweringAnalysis
 
 def level_blocks(m: int, block_rows: int, block_cols: int,
                  block_inner: int) -> dict:
-    """{level: tile size} for a depth-``m`` nest (innermost full by default)."""
+    """{level: tile size} for a depth-``m`` nest (innermost full by default).
+
+    The innermost level is the TPU's lane axis, where a block must span the
+    whole axis or a multiple of 128; so the single level of a 1-D nest is
+    tiled only by ``block_inner``, like the innermost level of any nest."""
     if m == 1:
-        return {1: block_inner or block_rows}
+        return {1: block_inner} if block_inner else {}
     blocks = {1: block_rows}
     for l in range(2, m):
         blocks[l] = block_cols
@@ -67,6 +77,7 @@ class ArrayPrep:
     sls: tuple  # per-axis window slice after padding
     n_copies: int  # 3**len(blocked levels); 1 for gather operands
     gather: bool = False  # whole-array operand, indexed in-kernel
+    expand: tuple = ()  # full-rank shape: size-1 axes at missing levels
 
 
 @dataclass
@@ -87,6 +98,7 @@ class Layout:
     prep: dict  # name -> ArrayPrep
     slice_base: dict  # window name -> {level: kernel slice-start base}
     mirror: dict  # window name -> {level: L-1} for mirrored levels
+    lift: bool  # 1-D nest: windows and outputs carry a leading size-1 axis
     gather_names: frozenset
     in_specs: list
     out_specs: list
@@ -130,11 +142,16 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
     in_specs = [pl.BlockSpec((1, max(len(scalar_names), 1)),
                              lambda *pids: (0, 0))]
 
-    def _imap(covered, ds_map):
+    # a 1-D nest is lifted to rank 2: its window operands and outputs carry
+    # a leading size-1 axis, so no block is rank 1
+    lift = m == 1
+    lead = (1,) if lift else ()
+
+    def _imap(covered, ds_map, lead=()):
         # block-index map: blocked axes follow the grid id plus their halo
         # offset d in {0,1,2}; unblocked axes are one full-width block
         def imap(*pids):
-            return tuple(
+            return (0,) * len(lead) + tuple(
                 pids[grid_pos[l]] + ds_map[l] if l in ds_map else 0
                 for l in covered)
         return imap
@@ -201,20 +218,34 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
             sls.append(slice(start + left, start + left + length))
         blk = [l for l in covered if l in blocks]
         n_copies = 3 ** len(blk)
+        expand = ()
+        if len(covered) < m or lift:
+            # a lower-rank operand would put a tiled level (or, under vmap,
+            # the batch axis) in its last two block axes: blocks the TPU
+            # refuses.  Give it a size-1 axis at every level it lacks, and a
+            # 1-D nest a leading size-1 axis, so both are whole.  Every
+            # window operand then spans levels 1..m in order.
+            size = {l: sl.stop - sl.start for l, sl in zip(covered, sls)}
+            bsize = dict(zip(covered, block_shape))
+            covered = tuple(range(1, m + 1))
+            expand = lead + tuple(size.get(l, 1) for l in covered)
+            block_shape = list(lead) + [bsize.get(l, 1) for l in covered]
         prep[nm] = ArrayPrep(tperm, tuple(flips), tuple(pads), tuple(sls),
-                             n_copies)
+                             n_copies, expand=expand)
         slice_base[nm] = sb
         mirror[nm] = mir
         for ds in itertools.product((0, 1, 2), repeat=len(blk)):
-            in_specs.append(pl.BlockSpec(tuple(block_shape),
-                                         _imap(covered, dict(zip(blk, ds)))))
+            in_specs.append(pl.BlockSpec(
+                tuple(block_shape), _imap(covered, dict(zip(blk, ds)), lead)))
 
     out_tile = tuple(blocks.get(l, extents[l - 1]) for l in range(1, m + 1))
     out_padded = tuple(nb[l] * blocks[l] if l in blocks else extents[l - 1]
                        for l in range(1, m + 1))
-    out_shape = [jax.ShapeDtypeStruct(out_padded, dt) for _ in out_names]
-    out_specs = [pl.BlockSpec(out_tile, _imap(tuple(range(1, m + 1)),
-                                              {l: 0 for l in grid_levels}))
+    out_shape = [jax.ShapeDtypeStruct(lead + out_padded, dt)
+                 for _ in out_names]
+    out_specs = [pl.BlockSpec(lead + out_tile,
+                              _imap(tuple(range(1, m + 1)),
+                                    {l: 0 for l in grid_levels}, lead))
                  for _ in out_names]
 
     out_axes = {}
@@ -229,7 +260,7 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
         m=m, extents=extents, lo=lo, blocks=blocks, grid=grid,
         grid_pos=grid_pos, nb=nb, scalar_names=scalar_names,
         base_names=base_names, out_names=out_names, dt=dt, prep=prep,
-        slice_base=slice_base, mirror=mirror,
+        slice_base=slice_base, mirror=mirror, lift=lift,
         gather_names=frozenset(nm for nm in base_names
                                if analysis.arrays[nm].kind == K_GATHER),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass
+from typing import Optional
 
 import numpy as np
 
@@ -45,7 +46,8 @@ from repro.core.ir import Const, Expr, Node, Ref
 from .blocks import ArrayPrep, Layout, build_layout
 from .facts import LoweringError
 from .gather import gather_ref
-from .geometry import (LoweringAnalysis, analyze_plan, aux_shift, ref_affine)
+from .geometry import (LoweringAnalysis, analyze_plan, aux_shift,
+                       platform_reasons, ref_affine, target_platform)
 
 _FUNCS = {"sin": jnp.sin, "cos": jnp.cos, "exp": jnp.exp, "log": jnp.log,
           "sqrt": jnp.sqrt, "tanh": jnp.tanh, "abs": jnp.abs}
@@ -73,6 +75,9 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
     def _tile_width(lvl, re):  # tile width along a level (1-based)
         return out_tile[lvl - 1] + 2 * re[lvl - 1]
 
+    def load(ref):  # a window block, without the 1-D nest's lift axis
+        return ref[0] if layout.lift else ref[...]
+
     def kernel(*refs):
         it = iter(refs)
         scal = next(it)  # (1, n_scalars)
@@ -81,19 +86,17 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
             if nm in layout.gather_names:
                 windows[nm] = next(it)[...]  # the whole operand
                 continue
-            covered = arrays[nm].levels
-            blk = [l for l in covered if l in blocks]
+            blk = [l for l in arrays[nm].levels if l in blocks]
             parts = {}
             for ds in itertools.product((0, 1, 2), repeat=len(blk)):
-                parts[ds] = next(it)[...]
+                parts[ds] = load(next(it))
 
             def assemble(prefix, rem):
                 if not rem:
                     return parts[prefix]
-                ax = covered.index(rem[0])
                 return jnp.concatenate(
                     [assemble(prefix + (d,), rem[1:]) for d in (0, 1, 2)],
-                    axis=ax)
+                    axis=rem[0] - 1)  # operand axes are levels 1..m
 
             windows[nm] = assemble((), tuple(blk))
         outs = [next(it) for _ in layout.out_names]
@@ -150,7 +153,10 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
             sb = layout.slice_base[e.name]
             w = windows[e.name]
             sl = []
-            for lvl in info.levels:
+            for lvl in range(1, m + 1):
+                if lvl not in raw:  # size-1 axis at a level e lacks
+                    sl.append(slice(None))
+                    continue
                 _, b = raw[lvl]
                 if lvl in mir:
                     b = mir[lvl] - b  # mirrored-origin: b' = (L-1) - b
@@ -158,17 +164,7 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
                 width = _tile_width(lvl, re)
                 s0 = sb[lvl] + b - a * re[lvl - 1]
                 sl.append(slice(s0, s0 + a * (width - 1) + 1, a))
-            v = w[tuple(sl)]
-            # insert size-1 axes at missing levels
-            shape = []
-            k = 0
-            for lvl in range(1, m + 1):
-                if lvl in info.levels:
-                    shape.append(v.shape[k])
-                    k += 1
-                else:
-                    shape.append(1)
-            return v.reshape(shape)
+            return w[tuple(sl)]
 
         # auxiliary arrays: VMEM values (the contraction payoff)
         for nm in aux_names:
@@ -177,7 +173,7 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
 
         for ref, st in zip(outs, plan.body):
             val = ev(st.rhs, (0,) * m)
-            ref[...] = jnp.broadcast_to(val, out_tile).astype(ref.dtype)
+            ref[...] = jnp.broadcast_to(val, ref.shape).astype(ref.dtype)
 
     return kernel
 
@@ -206,6 +202,7 @@ class LoweredStencil:
     extents: tuple
     out_axes: dict  # out name -> inverse level-major transpose, or ()
     interpret: bool
+    lift: bool = False  # 1-D nest: outputs carry a leading size-1 axis
     analysis: LoweringAnalysis = None
     _call: object = None  # the constructed pl.pallas_call callable
 
@@ -228,10 +225,14 @@ class LoweredStencil:
             if any(l or r for l, r in pr.pads):
                 arr = jnp.pad(arr, pr.pads)
             arr = arr[pr.sls]
+            if pr.expand:
+                arr = arr.reshape(pr.expand)
             ins.extend([arr] * pr.n_copies)
         outs = self._call(*ins)
         result = {}
         for nm, arr in zip(self.out_names, outs):
+            if self.lift:
+                arr = arr[0]
             arr = arr[tuple(slice(0, e) for e in self.extents)]
             axes = self.out_axes[nm]
             result[nm] = jnp.transpose(arr, axes) if axes else arr
@@ -244,9 +245,19 @@ class LoweredStencil:
 StencilSpec = LoweredStencil
 
 
+def pallas_interpret() -> bool:
+    """The Pallas mode, chosen from the platform kernels run on: compiled on
+    a TPU, interpreted on the CPU backend.  Any other platform has no mode
+    (the capability probe refuses it with ``pallas-platform``)."""
+    platform = target_platform()
+    if platform not in ("tpu", "cpu"):
+        raise LoweringError(platform_reasons(None, platform))
+    return platform == "cpu"
+
+
 def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
                        block_rows: int = 8, block_cols: int = 8,
-                       interpret: bool = True,
+                       interpret: Optional[bool] = None,
                        block_inner: int = 0) -> LoweredStencil:
     """Build the static half of the blocked Pallas execution.
 
@@ -254,19 +265,26 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
     scalars) and ``dtypes`` to their dtypes; together they are the
     environment *signature* the artifact is specialized against.  The grid
     tiles every level but the innermost — level 1 by ``block_rows``, middle
-    levels by ``block_cols`` (a 1-D nest tiles its single level by
-    ``block_rows``).  The innermost level stays full-width by default (VPU
-    lanes); ``block_inner > 0`` grid-tiles it too — for very wide rows whose
-    full-width blocks would not fit VMEM — at the cost of a halo copy along
-    the innermost axis.
+    levels by ``block_cols``.  The innermost level (the only level of a 1-D
+    nest) stays full-width by default (VPU lanes); ``block_inner > 0``
+    grid-tiles it too — for very wide rows whose full-width blocks would not
+    fit VMEM — at the cost of a halo copy along the innermost axis.
+
+    ``interpret=None`` takes the platform's mode (:func:`pallas_interpret`);
+    an explicit value lets a test build the compiled kernel on a CPU host.
 
     Raises :class:`~repro.lowering.facts.LoweringError` (a ``ValueError``)
     carrying the capability probe's exact structured reasons when the plan
-    is outside the lowering model.
+    is outside the lowering model, or, for the compiled kernel, outside
+    what the TPU compiler accepts.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
     analysis = analyze_plan(plan)
-    if not analysis.eligible:
-        raise LoweringError(analysis.reasons)
+    reasons = analysis.reasons or platform_reasons(
+        analysis, "cpu" if interpret else "tpu")
+    if reasons:
+        raise LoweringError(reasons)
     layout = build_layout(analysis, shapes, dtypes, block_rows, block_cols,
                           block_inner)
     kernel = build_kernel(plan, analysis, layout)
@@ -283,12 +301,12 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
                           out_names=layout.out_names, dt=layout.dt,
                           prep=layout.prep, extents=layout.extents,
                           out_axes=layout.out_axes, interpret=interpret,
+                          lift=layout.lift,
                           analysis=analysis, _call=call)
 
 
 def race_stencil_call(plan: Plan, env: dict, block_rows: int = 8,
-                      block_cols: int = 8, interpret: bool = True,
-                      block_inner: int = 0):
+                      block_cols: int = 8, block_inner: int = 0):
     """One-shot execution: specialize for ``env``'s signature, then apply.
 
     env maps base array names -> arrays (laid out as in the program) and
@@ -302,6 +320,6 @@ def race_stencil_call(plan: Plan, env: dict, block_rows: int = 8,
         plan,
         {nm: np.shape(v) for nm, v in env.items()},
         {nm: dtype_of(v) for nm, v in env.items()},
-        block_rows=block_rows, block_cols=block_cols, interpret=interpret,
+        block_rows=block_rows, block_cols=block_cols,
         block_inner=block_inner)
     return spec.apply(env)

@@ -35,6 +35,10 @@ R_INCONSISTENT_LAYOUT = "inconsistent-layout"
 R_STRIDED_AUX = "strided-aux"
 R_SCALAR_AUX = "scalar-aux"
 R_NO_BASE_ARRAY = "no-base-array"
+#: Platform codes: the plan lowers, but not for the executing platform.
+R_TPU_GATHER = "tpu-gather"  # in-kernel index gather: Mosaic has no lowering
+R_TPU_STRIDED = "tpu-strided"  # stride > 1 window slice lowers to a gather
+R_PLATFORM = "pallas-platform"  # neither a TPU (compiled) nor CPU (interpret)
 
 #: Retired fallback codes: since the dimension-generic lowering engine these
 #: never appear as fallback *reasons* — they appear as lowering *facts*
@@ -48,7 +52,8 @@ R_CONSTANT_DIM = "constant-dim"  # → in-kernel index gather
 #: The codes that can still appear in ``Capability.reasons``.
 FALLBACK_CODES = (R_LHS_FORM, R_ZERO_COEF, R_FRACTIONAL_OFFSET,
                   R_MIXED_STRIDE, R_INCONSISTENT_LAYOUT, R_STRIDED_AUX,
-                  R_SCALAR_AUX, R_NO_BASE_ARRAY)
+                  R_SCALAR_AUX, R_NO_BASE_ARRAY, R_TPU_GATHER, R_TPU_STRIDED,
+                  R_PLATFORM)
 
 #: The codes that appear only as lowering facts now.
 RETIRED_CODES = (R_DEPTH, R_NEGATIVE_COEF, R_REPEATED_LEVEL, R_CONSTANT_DIM)

@@ -35,14 +35,16 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from fractions import Fraction
+from typing import Optional
 
 from repro.core.depgraph import Plan, _aux_ref_shifts
 from repro.core.ir import Expr, Program, Ref, expr_refs
 
 from .facts import (R_CONSTANT_DIM, R_DEPTH, R_FRACTIONAL_OFFSET,
                     R_INCONSISTENT_LAYOUT, R_LHS_FORM, R_MIXED_STRIDE,
-                    R_NEGATIVE_COEF, R_NO_BASE_ARRAY, R_REPEATED_LEVEL,
-                    R_SCALAR_AUX, R_STRIDED_AUX, R_ZERO_COEF, FallbackReason,
+                    R_NEGATIVE_COEF, R_NO_BASE_ARRAY, R_PLATFORM,
+                    R_REPEATED_LEVEL, R_SCALAR_AUX, R_STRIDED_AUX,
+                    R_TPU_GATHER, R_TPU_STRIDED, R_ZERO_COEF, FallbackReason,
                     LoweringError, LoweringFact)
 
 #: array classification (ArrayInfo.kind)
@@ -314,6 +316,43 @@ def _analyze(plan: Plan) -> LoweringAnalysis:
         visit_base(plan.aux_exprs[a.name], ext[a.name])
 
     return LoweringAnalysis(plan, m, True, (), facts, arrays, ext)
+
+
+def target_platform() -> str:
+    """The platform kernels run on: jax's default backend (``"tpu"``,
+    ``"cpu"``, ...).  Imports jax on first use only."""
+    import jax
+
+    return jax.default_backend()
+
+
+def platform_reasons(analysis: Optional[LoweringAnalysis],
+                     platform: str) -> tuple:
+    """Reasons an eligible plan cannot run as a Pallas kernel on ``platform``.
+
+    On the CPU backend the kernel is interpreted and every eligible plan
+    runs.  On a TPU it is compiled by Mosaic, which refuses two mechanisms
+    the interpreter accepts: the in-kernel index gather (gather-class
+    arrays) and stride > 1 window slices (lowered to a gather).  Any other
+    platform has no Pallas mode at all (``analysis`` may then be None).
+    """
+    if platform not in ("tpu", "cpu"):
+        return (FallbackReason(
+            R_PLATFORM, f"no Pallas mode on platform {platform!r} (compiled "
+                        f"on tpu, interpreted on cpu)"),)
+    if platform == "cpu" or not analysis.eligible:
+        return ()
+    reasons = []
+    for nm, info in sorted(analysis.arrays.items()):
+        if info.kind == K_GATHER:
+            reasons.append(FallbackReason(
+                R_TPU_GATHER, f"{nm}: in-kernel index gather does not "
+                              f"compile for the TPU"))
+        elif any(a > 1 for a in info.coefs.values()):
+            reasons.append(FallbackReason(
+                R_TPU_STRIDED, f"{nm}: stride-{max(info.coefs.values())} "
+                               f"window slice does not compile for the TPU"))
+    return tuple(reasons)
 
 
 def offset_envelopes(plan: Plan):

@@ -112,8 +112,7 @@ def _smooth_result(S: int, C: int, R: int):
     return res
 
 
-def race_smooth(x, taps, *, radius: int, backend: str = "xla",
-                interpret: bool = True):
+def race_smooth(x, taps, *, radius: int, backend: str = "xla"):
     """Causal FIR residual mixer over the token stream, computed — forward
     *and* backward — through the RACE pipeline.
 
@@ -136,7 +135,7 @@ def race_smooth(x, taps, *, radius: int, backend: str = "xla",
            "sy": jnp.zeros((S, B * C), jnp.float32)}
     for d in range(R + 1):
         env[f"sw{d}"] = taps[d].astype(jnp.float32)
-    y = res.run(env, backend, interpret=interpret)["sy"]
+    y = res.run(env, backend)["sy"]
     return y.reshape(S, B, C).transpose(1, 0, 2).astype(x.dtype)
 
 

@@ -21,9 +21,8 @@ Entry points::
     python -m repro.serve.warm          # replay the tuning store
 
 Knobs: ``RACE_SERVE_MAX_BATCH``, ``RACE_SERVE_WINDOW_US``,
-``RACE_SERVE_QUEUE``, ``RACE_SERVE_WORKERS`` (runtime) and
-``RACE_COMPILE_CACHE`` (persistent executable cache; see
-:mod:`repro.core.compile_cache`).
+``RACE_SERVE_QUEUE``, ``RACE_SERVE_WORKERS`` (runtime).  The persistent
+executable cache (:mod:`repro.core.compile_cache`) is always on.
 """
 from .runtime import (ENV_MAX_BATCH, ENV_QUEUE, ENV_WINDOW_US, ENV_WORKERS,
                       ServeRejected, ServeRuntime)

@@ -84,7 +84,7 @@ def warmup(items: Sequence[Tuple[object, Union[Mapping, tuple]]], *,
     """Eagerly build the executor for each (target, env-or-signature) pair.
 
     Each item's first call triggers the XLA compile — served from the
-    persistent compilation cache when ``$RACE_COMPILE_CACHE`` is warm — so
+    persistent compilation cache when it is warm — so
     the first *real* request finds both the executor cache and the jit
     cache hot.  Returns one report dict per item: ``build_ms`` (executor
     specialization), ``first_ms`` (first call, the compile), and the

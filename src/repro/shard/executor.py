@@ -65,10 +65,8 @@ class ShardedRace:
 
     def __init__(self, result, mesh, partition, halo_prog, local_ex, *,
                  backend: Optional[str], halo: str, block_rows: int,
-                 block_cols: int, block_inner: int, interpret: bool,
-                 cache):
+                 block_cols: int, block_inner: int, cache):
         import jax
-        from jax.experimental.shard_map import shard_map
 
         from repro.core.executor import plan_hash
 
@@ -83,7 +81,7 @@ class ShardedRace:
         self._plan_h = plan_hash(result.plan)
         self._requested = dict(backend=backend, halo=halo,
                                block_rows=block_rows, block_cols=block_cols,
-                               block_inner=block_inner, interpret=interpret)
+                               block_inner=block_inner)
         self._cache = cache
         self._adj_memo: dict = {}
 
@@ -93,11 +91,11 @@ class ShardedRace:
         def body(args):
             return core(hp.device_env(args))
 
-        # check_rep=False: pallas_call (and our replicated tails) have no
-        # replication-rule registration on this jax; correctness is carried
-        # by the differential tests, not the rep checker
-        shmapped = shard_map(body, mesh=mesh, in_specs=(hp.in_specs,),
-                             out_specs=hp.out_specs, check_rep=False)
+        # check_vma=False: pallas_call (and our replicated tails) carry no
+        # varying-manual-axes typing; correctness is carried by the
+        # differential tests, not the checker
+        shmapped = jax.shard_map(body, mesh=mesh, in_specs=(hp.in_specs,),
+                                 out_specs=hp.out_specs, check_vma=False)
 
         def raw(env):
             return shmapped(hp.host_args(env))
@@ -166,8 +164,7 @@ class ShardedRace:
                     res, sig, self.mesh, halo=req["halo"],
                     backend=req["backend"], block_rows=req["block_rows"],
                     block_cols=req["block_cols"],
-                    block_inner=req["block_inner"],
-                    interpret=req["interpret"], cache=self._cache)
+                    block_inner=req["block_inner"], cache=self._cache)
             except ShardingUnavailable as err:
                 if _obs.enabled():
                     _obs.event("shard_adjoint_fallback", plan=self._plan_h,
@@ -177,7 +174,6 @@ class ShardedRace:
                                   block_rows=req["block_rows"],
                                   block_cols=req["block_cols"],
                                   block_inner=req["block_inner"],
-                                  interpret=req["interpret"],
                                   cache=self._cache)
             self._adj_memo[key] = ex
         return ex
@@ -248,8 +244,7 @@ _LOCAL_RACE_KNOBS = ("reassociate", "esr", "contraction", "cost_model",
 def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
                     halo: str = "auto", backend: Optional[str] = None,
                     block_rows: int = 8, block_cols: int = 8,
-                    block_inner: int = 0, interpret: bool = True,
-                    cache=None) -> ShardedRace:
+                    block_inner: int = 0, cache=None) -> ShardedRace:
     """Fetch (or build) the sharded executor for (result, env, mesh).
 
     Raises :class:`ShardingUnavailable` — carrying every structured
@@ -281,7 +276,7 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
     c = cache if cache is not None else executor_cache()
     key = ExecutorKey(
         ph, sig, backend or default_backend(),
-        (block_rows, block_cols, block_inner, bool(interpret)), False,
+        (block_rows, block_cols, block_inner), False,
         device=device_context(),
         mesh=(partition.mesh_axes,
               tuple(int(d.id) for d in mesh.devices.flat)),
@@ -305,7 +300,7 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
             local_ex = compile_plan(
                 local_res.plan, local_sig, backend, block_rows=block_rows,
                 block_cols=block_cols, block_inner=block_inner,
-                interpret=interpret, donate=False, cache=c)
+                donate=False, cache=c)
         if _obs.enabled():
             _obs.event("shard_plan", plan=ph,
                        local_plan=plan_hash(local_res.plan),
@@ -318,6 +313,6 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
         return ShardedRace(result, mesh, partition, hp, local_ex,
                            backend=backend, halo=halo, block_rows=block_rows,
                            block_cols=block_cols, block_inner=block_inner,
-                           interpret=interpret, cache=c)
+                           cache=c)
 
     return c.get_or_build(key, _build)

@@ -33,20 +33,22 @@ Two transport strategies produce bit-identical local slabs:
   into the same jit as the ``shard_map`` consumer — each slab arrived
   doubled — so the slicing lives device-side on purpose.)
 
-``"auto"`` picks by a bytes-over-bandwidth roofline using the
-:mod:`repro.launch.mesh` constants: exchange moves its halo bytes over ICI
-(``ICI_BW_PER_LINK``), recompute pulls one full replicated copy per device
-through HBM (``HBM_BW``).  Auxiliary-array halo *flops* do not enter the comparison:
-both strategies hand the executor the same envelope-extended slab and
-recompute aux values over it locally, so that work is identical and
-cancels.
+``"auto"`` picks by a bytes-over-bandwidth roofline using the chip's
+published peaks (:func:`repro.launch.mesh.chip_peaks`, keyed by device
+kind; an unlisted TPU kind raises): exchange moves its halo bytes over one
+ICI link, recompute pulls one full replicated copy per device through HBM.
+Auxiliary-array halo *flops* do not enter the comparison: both strategies
+hand the executor the same envelope-extended slab and recompute aux values
+over it locally, so that work is identical and cancels.  On the CPU
+backend, which has neither link, ``"auto"`` is always ``"exchange"`` — the
+strategy that exercises the collectives.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
 from repro.core.codegen import required_shapes
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK
+from repro.launch.mesh import chip_peaks
 from repro.lowering.geometry import K_WINDOW, analyze_program
 
 HALO_STRATEGIES = ("auto", "exchange", "recompute")
@@ -156,9 +158,7 @@ class HaloProgram:
             self._restack_bytes(s, n_devices) for s in specs.values()
             if s.mode == M_SLAB)
         if strategy == "auto":
-            strategy = ("exchange"
-                        if self.halo_bytes / ICI_BW_PER_LINK
-                        <= self.restack_bytes / HBM_BW else "recompute")
+            strategy = self._auto_strategy()
         self.strategy = strategy
 
         # shard_map out_specs: local interiors concatenate along each
@@ -180,6 +180,17 @@ class HaloProgram:
             self.out_local_extent[st.lhs.name] = tuple(ext)
         self.in_specs = {nm: self._in_spec(s) for nm, s in specs.items()
                          if s.mode != M_CANVAS}
+
+    def _auto_strategy(self) -> str:
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            return "exchange"
+        peaks = chip_peaks(dev.device_kind)
+        return ("exchange"
+                if self.halo_bytes / peaks["ici_bw_per_link"]
+                <= self.restack_bytes / peaks["hbm_bw"] else "recompute")
 
     # -- static accounting ----------------------------------------------------
 

@@ -134,14 +134,12 @@ def run_case(case, reassociate_levels: Iterable[int] = (0, 3, 4),
              backends: Iterable[str] = ("xla", "pallas"),
              dtype=np.float32, seed: int = 0, block_rows: int = 8,
              block_cols: int = 8, block_inner: int = 0,
-             tolerances: Optional[dict] = None,
-             interpret: bool = True) -> CaseReport:
+             tolerances: Optional[dict] = None) -> CaseReport:
     """Differential-verify one case across plans and backends."""
     tol = tolerances or default_tolerances(dtype)
     with _x64_ctx(dtype):
         return _run_case_impl(case, reassociate_levels, backends, dtype, seed,
-                              block_rows, block_cols, block_inner, tol,
-                              interpret)
+                              block_rows, block_cols, block_inner, tol)
 
 
 def _x64_ctx(dtype):
@@ -152,16 +150,11 @@ def _x64_ctx(dtype):
 
     if np.dtype(dtype) != np.float64:
         return contextlib.nullcontext()
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64  # pinned 0.4.x spelling
-
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
-                   block_rows, block_cols, block_inner, tol,
-                   interpret) -> CaseReport:
+                   block_rows, block_cols, block_inner, tol) -> CaseReport:
     env = build_env(case, dtype=dtype, seed=seed)
     report = CaseReport(case.name)
 
@@ -190,8 +183,7 @@ def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
                         continue
                     out = res.run(env, "pallas", block_rows=block_rows,
                                   block_cols=block_cols,
-                                  block_inner=block_inner,
-                                  interpret=interpret)
+                                  block_inner=block_inner)
                 combo.max_rel_err = _rel_err(out, truth)
                 if combo.max_rel_err > tol["baseline"]:
                     combo.status = "mismatch"
@@ -220,8 +212,7 @@ def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
 def run_grad_case(case, reassociate_levels: Iterable[int] = (0, 3, 4),
                   backends: Iterable[str] = ("xla", "pallas"),
                   dtype=np.float32, seed: int = 0,
-                  tolerances: Optional[dict] = None,
-                  interpret: bool = True) -> CaseReport:
+                  tolerances: Optional[dict] = None) -> CaseReport:
     """Differential-verify ``jax.grad`` through the RACE serving path.
 
     For each (reassociate level, forward backend) combo, takes the gradient
@@ -238,11 +229,11 @@ def run_grad_case(case, reassociate_levels: Iterable[int] = (0, 3, 4),
     tol = tolerances or default_tolerances(dtype)
     with _x64_ctx(dtype):
         return _run_grad_case_impl(case, reassociate_levels, backends, dtype,
-                                   seed, tol, interpret)
+                                   seed, tol)
 
 
-def _run_grad_case_impl(case, reassociate_levels, backends, dtype, seed, tol,
-                        interpret) -> CaseReport:
+def _run_grad_case_impl(case, reassociate_levels, backends, dtype, seed,
+                        tol) -> CaseReport:
     import jax
     import jax.numpy as jnp
 
@@ -289,7 +280,7 @@ def _run_grad_case_impl(case, reassociate_levels, backends, dtype, seed, tol,
                         report.combos.append(combo)
                         continue
                 grads = jax.grad(lambda p: loss_of(res.run(
-                    {**env, **p}, backend, interpret=interpret)))(params0)
+                    {**env, **p}, backend)))(params0)
                 combo.max_rel_err = _rel_err(grads, truth_grads)
                 if combo.max_rel_err > tol["grad"]:
                     combo.status = "mismatch"

@@ -93,7 +93,6 @@ def time_executor_batch(ex, env: Mapping, batch: int, repeats: int = 5,
 def measure_candidate(plan: Plan, config: Config, env: Mapping,
                       truth: Mapping, tolerance: float, *,
                       repeats: int = 5, warmup: int = 2,
-                      interpret: bool = True,
                       batch: int = 0) -> Measurement:
     """Gate then time one candidate; exceptions become ``status="error"``.
 
@@ -114,7 +113,7 @@ def measure_candidate(plan: Plan, config: Config, env: Mapping,
             ex = compile_plan(
                 plan, env, config.backend, block_rows=config.block_rows,
                 block_cols=config.block_cols,
-                block_inner=config.block_inner, interpret=interpret)
+                block_inner=config.block_inner)
             if batch > 0:
                 out = ex.run_batch([env] * batch)
                 first = {k: v[0] for k, v in out.items()}

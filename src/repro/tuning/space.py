@@ -77,25 +77,25 @@ def block_grid(plan: Plan, quick: bool = False) -> List[tuple]:
     Always includes the static default (8, 8, 0).  Extra points are added
     only where the plan's extents make them meaningful — generic over nest
     depth since the lowering engine closed the envelope: a taller row block
-    when level 1 has room (for a 1-D nest ``block_rows`` *is* its only
-    level's tile), a wider column block when any middle level (2..m-1) has
-    room, and an innermost tile when the last level is wide enough that
-    tiling it is a real axis (the ROADMAP's "grid-tile the innermost level"
-    item).
+    when level 1 has room, a wider column block when any middle level
+    (2..m-1) has room, and an innermost tile when the last level is wide
+    enough to split into lane-aligned tiles (multiples of 128, the TPU's
+    lane width).  A 1-D nest's single level is its lane axis and stays one
+    whole block, so it has no extra points.
     """
     prog = plan.program
     m = prog.depth
     ranges = prog.ranges()
     extents = [ranges[l][1] - ranges[l][0] + 1 for l in range(1, m + 1)]
     grid = [(8, 8, 0)]
-    if extents[0] > 8:
+    if m >= 2 and extents[0] > 8:
         grid.append((16, 8, 0))
     if not quick and m >= 3 and any(e > 8 for e in extents[1:-1]):
         grid.append((8, 16, 0))
     inner = extents[-1]
-    if m >= 2 and inner >= 32:
-        # one tile that halves the row at least twice — wide-row relief
-        grid.append((8, 8, max(16, inner // 4)))
+    if m >= 2 and inner >= 256:
+        # one lane-aligned tile that splits the row at least twice
+        grid.append((8, 8, 128 * max(1, inner // 512)))
     return grid
 
 

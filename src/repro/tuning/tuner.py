@@ -162,7 +162,7 @@ def autotune(program: Program, env: Mapping, *,
              levels: Sequence[int] = REASSOCIATE_LEVELS,
              backends: Optional[Sequence[str]] = None,
              grid: Optional[Iterable[tuple]] = None, quick: bool = False,
-             repeats: int = 5, warmup: int = 2, interpret: bool = True,
+             repeats: int = 5, warmup: int = 2,
              default_reassociate: int = 0, rewrite_div: bool = False,
              race_opts: Optional[Mapping] = None,
              tolerance: Optional[float] = None, noise_margin: float = 0.03,
@@ -232,7 +232,7 @@ def autotune(program: Program, env: Mapping, *,
     if 0 not in results:  # the correctness oracle is always r0/xla
         results[0] = race(program, reassociate=0, **opts)
 
-    truth_ex = compile_plan(results[0].plan, env, "xla", interpret=interpret)
+    truth_ex = compile_plan(results[0].plan, env, "xla")
     truth = {k: np.asarray(v) for k, v in truth_ex(env).items()}
     tol = tolerance if tolerance is not None else _baseline_tolerance(env)
 
@@ -248,8 +248,7 @@ def autotune(program: Program, env: Mapping, *,
     with obs.span("autotune", program=prog_h):
         measurements = [
             measure_candidate(plans[c.reassociate], c, env, truth, tol,
-                              repeats=repeats, warmup=warmup,
-                              interpret=interpret)
+                              repeats=repeats, warmup=warmup)
             for c in configs]
         winner, default_m = _pick(measurements, default, noise_margin)
         # batch-aware pass: the batched (vmapped) executor has different
@@ -260,8 +259,7 @@ def autotune(program: Program, env: Mapping, *,
             ok_configs = [m.config for m in measurements if m.ok]
             measurements.extend(
                 measure_candidate(plans[c.reassociate], c, env, truth, tol,
-                                  repeats=repeats, warmup=warmup,
-                                  interpret=interpret, batch=b)
+                                  repeats=repeats, warmup=warmup, batch=b)
                 for b in batch_sizes for c in ok_configs)
     search_s = time.perf_counter() - t0
     if obs.enabled():
@@ -281,8 +279,7 @@ def autotune(program: Program, env: Mapping, *,
             tuned_us=winner.us, search_s=search_s,
             n_candidates=len(measurements),
             n_ok=sum(m.ok for m in measurements),
-            n_gated=sum(m.status == "gated" for m in measurements),
-            interpret=bool(interpret))
+            n_gated=sum(m.status == "gated" for m in measurements))
         s.put(dict(key=key, kind="program", hash=prog_h, device=fence["device"],
                    jax=fence["jax"], search=search,
                    choice=winner.config.as_dict(),
@@ -308,8 +305,7 @@ def autotune(program: Program, env: Mapping, *,
                     device=fence["device"], jax=fence["jax"],
                     choice=best.config.as_dict(),
                     stats=dict(us=best.us,
-                               default_us=ld_m.us if ld_m else None,
-                               interpret=bool(interpret)))
+                               default_us=ld_m.us if ld_m else None))
                 if b:
                     rec["batch"] = b
                 s.put(rec)

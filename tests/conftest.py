@@ -2,8 +2,8 @@
 
 Tier-1 (the default ``python -m pytest -x -q``) runs everything except
 tests marked ``slow``; pass ``--runslow`` for the full-size sweeps.  The
-``pallas`` marker tags tests exercising the Pallas kernel (interpret mode on
-this container), so ``-m pallas`` selects the kernel surface alone; the
+``pallas`` marker tags tests exercising the Pallas kernel (interpreted on
+the CPU backend), so ``-m pallas`` selects the kernel surface alone; the
 ``lowering`` marker mirrors it for the dimension-generic lowering engine
 (``repro.lowering`` — ``-m lowering``); the ``tuning`` marker tags the
 autotuner subsystem (``-m tuning``).
@@ -52,6 +52,18 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip_slow)
+
+
+@pytest.fixture(autouse=True, scope="session")
+def _session_compile_cache(tmp_path_factory):
+    """The persistent compilation cache goes to a session temp dir, never to
+    the checkout's ``.jax-compile-cache``: test runs must not grow the tree
+    (``repro.core.compile_cache`` honors ``$JAX_COMPILATION_CACHE_DIR``)."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("JAX_COMPILATION_CACHE_DIR",
+              str(tmp_path_factory.mktemp("jax-compile-cache")))
+    yield
+    mp.undo()
 
 
 @pytest.fixture(autouse=True)

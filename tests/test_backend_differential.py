@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 from repro.apps.paper_kernels import CASES, Case, get_case
-from repro.core.backend import (R_MIXED_STRIDE, BackendUnavailable,
+from repro.core.backend import (R_MIXED_STRIDE, R_PLATFORM, R_TPU_GATHER,
+                                R_TPU_STRIDED, BackendUnavailable,
                                 probe_pallas, select_backend)
 from repro.core.ir import arr, loopnest, program
 from repro.core.race import race
@@ -137,3 +138,54 @@ def test_unknown_backend_rejected():
     res = race(case.program)
     with pytest.raises(ValueError, match="unknown backend"):
         res.select_backend("cuda")
+
+
+# ---------------------------------------------------------------------------
+# the platform decides the Pallas mode, and what the probe must refuse
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name,code", [("rprj3", R_TPU_STRIDED),
+                                       ("diag2d", R_TPU_GATHER)])
+def test_tpu_refusal_falls_back_loudly(name, code, monkeypatch):
+    """On a TPU, ``auto`` takes XLA with a ``backend_fallback`` event naming
+    the pinned code, and an explicit ``pallas`` raises it; it never
+    interprets.  On the CPU backend the same plan runs interpreted."""
+    import repro.core.backend as backend
+    from repro import obs
+    from repro.core.executor import ExecutorCache, compile_plan
+
+    case = get_case(name, SWEEP_SIZES[name])
+    res = race(case.program, reassociate=case.reassociate)
+    env = build_env(case)
+    ex = compile_plan(res.plan, env, "pallas", cache=ExecutorCache())
+    assert ex.backend == "pallas" and ex.spec.interpret is True
+
+    monkeypatch.setattr(backend, "target_platform", lambda: "tpu")
+    monkeypatch.setenv(obs.ENV_OBS, "1")
+    obs.reset()
+    assert select_backend(res.plan, "auto").backend == "xla"
+    ev = obs.events(kind="backend_fallback")[-1]
+    assert code in ev["codes"] and ev["backend"] == "xla"
+    with pytest.raises(BackendUnavailable, match=code):
+        select_backend(res.plan, "pallas")
+    assert compile_plan(res.plan, env, "auto",
+                        cache=ExecutorCache()).backend == "xla"
+
+
+def test_pallas_mode_follows_the_platform(monkeypatch):
+    import repro.core.backend as backend
+    import repro.lowering.emit as emit
+    from repro.lowering import pallas_interpret
+
+    assert pallas_interpret() is True  # the CPU backend interprets
+    monkeypatch.setattr(emit, "target_platform", lambda: "tpu")
+    assert pallas_interpret() is False  # a TPU compiles
+    monkeypatch.setattr(emit, "target_platform", lambda: "gpu")
+    with pytest.raises(ValueError, match=R_PLATFORM):
+        pallas_interpret()  # no mode here: never a silent interpreter
+    monkeypatch.setattr(backend, "target_platform", lambda: "gpu")
+    res = race(get_case("psinv", 10).program)
+    cap = probe_pallas(res.plan)
+    assert not cap.eligible and cap.reasons[0].code == R_PLATFORM
+    assert select_backend(res.plan, "auto").backend == "xla"

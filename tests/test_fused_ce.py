@@ -25,7 +25,7 @@ def _data(T, D, V, seed=0, dtype=jnp.float32):
 ])
 def test_fused_ce_matches_dense(T, D, V, tb, vb):
     h, w, labels = _data(T, D, V)
-    got = fused_ce_forward(h, w, labels, t_blk=tb, v_blk=vb, interpret=True)
+    got = fused_ce_forward(h, w, labels, t_blk=tb, v_blk=vb)
     logits = h.astype(jnp.float32) @ w.astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
@@ -35,7 +35,7 @@ def test_fused_ce_matches_dense(T, D, V, tb, vb):
 
 def test_fused_ce_bf16_inputs():
     h, w, labels = _data(64, 32, 256, seed=1, dtype=jnp.bfloat16)
-    got = fused_ce_forward(h, w, labels, t_blk=16, v_blk=64, interpret=True)
+    got = fused_ce_forward(h, w, labels, t_blk=16, v_blk=64)
     want = _ce_ref(h, w, labels)
     np.testing.assert_allclose(float(np.asarray(got).mean()), float(want),
                                rtol=2e-2)

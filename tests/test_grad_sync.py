@@ -16,13 +16,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.runtime.grad_sync import compressed_pmean_tree
 
-# axis_types only exists on newer jax; older versions default to Auto
-mesh_kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-           if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((8,), ("data",), **mesh_kw)
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 # per-shard local gradients (8, 64, 32): axis 0 = DP shard
 g_all = jnp.asarray(rng.standard_normal((8, 64, 32)), jnp.float32)
@@ -32,8 +29,8 @@ def sync(g, e):
     m, ne = compressed_pmean_tree({"w": g[0]}, {"w": e[0]}, "data")
     return m["w"][None], ne["w"][None]
 
-f = shard_map(sync, mesh=mesh, in_specs=(P("data"), P("data")),
-              out_specs=(P("data"), P("data")))
+f = jax.shard_map(sync, mesh=mesh, in_specs=(P("data"), P("data")),
+                  out_specs=(P("data"), P("data")))
 mean_c, err = jax.jit(f)(g_all, e0)
 mean_exact = g_all.mean(axis=0)
 m0 = np.asarray(mean_c)[0]

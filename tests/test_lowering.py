@@ -407,11 +407,17 @@ def test_shim_reexports():
 def test_block_grid_generic_depths():
     from repro.tuning.space import block_grid
 
-    case1 = get_case("smooth1d", 48)
+    case1 = get_case("smooth1d", 600)
     plan1 = race(case1.program, reassociate=3).plan
     grid1 = block_grid(plan1)
-    assert (8, 8, 0) in grid1 and (16, 8, 0) in grid1
-    assert all(bi == 0 for _, _, bi in grid1)  # 1-D: rows is the only axis
+    assert grid1 == [(8, 8, 0)]  # 1-D: the lane axis is one whole block
+
+    case2 = get_case("gaussian", 600)
+    plan2 = race(case2.program, reassociate=3).plan
+    grid2 = block_grid(plan2)
+    assert (8, 8, 0) in grid2 and (16, 8, 0) in grid2
+    inner = [bi for _, _, bi in grid2 if bi]
+    assert inner and all(bi % 128 == 0 for bi in inner)  # lane-aligned
 
     case4 = get_case("blocked4d", 14)
     plan4 = race(case4.program, reassociate=3).plan

@@ -29,7 +29,7 @@ def _run(case, dtype=np.float32, block_rows=8, reassociate=None, rtol=None):
     res = race(case.program,
                reassociate=case.reassociate if reassociate is None else reassociate)
     env = _env(case, dtype)
-    got = race_stencil(res, env, block_rows=block_rows, interpret=True)
+    got = race_stencil(res, env, block_rows=block_rows)
     want = kref.reference(res.plan, env)
     rtol = rtol or (2e-2 if dtype == np.float16 else 2e-4)
     for k in want:
@@ -86,7 +86,7 @@ def test_vmem_contraction_no_hbm_aux():
 
     env = _env(case, np.float32)
     lowered = jax.jit(
-        lambda e: race_stencil_call(res.plan, e, interpret=True)).lower(env)
+        lambda e: race_stencil_call(res.plan, e)).lower(env)
     txt = lowered.as_text()
     for aux in res.plan.aux_order:
         assert f"{aux.name}" not in txt  # ...but none ever named in HLO I/O

@@ -3,6 +3,7 @@ backpressure, shutdown semantics, burst submission, eager warmup, tuning-
 store replay, and the persistent compilation cache."""
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -267,13 +268,13 @@ def test_warm_from_store_reports_unmatched(tmp_path):
 
 
 def test_compile_cache_serves_rebuild_after_eviction(tmp_path, monkeypatch):
-    # the env knob, not configure(): every CompiledRace build re-applies
-    # $RACE_COMPILE_CACHE, so the env var is the authoritative switch
-    monkeypatch.setenv(compile_cache.ENV_COMPILE_CACHE, str(tmp_path / "cc"))
+    # every CompiledRace build re-applies $JAX_COMPILATION_CACHE_DIR, so the
+    # env var is the authoritative placement
+    monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path / "cc"))
     case, res = _res()
     env = build_env(case)
     try:
-        assert compile_cache.ensure_enabled()
+        assert compile_cache.ensure_enabled() == str(tmp_path / "cc")
         res.run(env, "xla")  # populate the on-disk cache
         executor_cache().clear()  # evict: force a full rebuild
         c0 = compile_cache.counts()
@@ -282,23 +283,28 @@ def test_compile_cache_serves_rebuild_after_eviction(tmp_path, monkeypatch):
         assert c1["requests"] > c0["requests"]
         assert c1["hits"] > c0["hits"]  # deserialization, not recompilation
         info = compile_cache.info()
-        assert info["enabled"] and info["entries"] >= 1
+        assert info["path"] == str(tmp_path / "cc") and info["entries"] >= 1
     finally:
-        monkeypatch.delenv(compile_cache.ENV_COMPILE_CACHE)
+        monkeypatch.undo()
         compile_cache.ensure_enabled()
-    assert not compile_cache.enabled()
+    assert compile_cache.cache_dir() != str(tmp_path / "cc")
 
 
 def test_compile_cache_env_knob(tmp_path, monkeypatch):
-    monkeypatch.setenv(compile_cache.ENV_COMPILE_CACHE,
-                       str(tmp_path / "envcc"))
+    monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path / "envcc"))
     try:
-        assert compile_cache.ensure_enabled()
+        assert compile_cache.ensure_enabled() == str(tmp_path / "envcc")
         assert compile_cache.cache_dir() == str(tmp_path / "envcc")
+        # unset, the cache lives at a fixed path in the checkout
+        monkeypatch.delenv(compile_cache.ENV_CACHE_DIR)
+        assert compile_cache.resolve_dir() == compile_cache.DEFAULT_DIR
+        root = Path(compile_cache.DEFAULT_DIR).parent
+        assert Path(compile_cache.DEFAULT_DIR).name == ".jax-compile-cache"
+        assert (root / "src" / "repro" / "core" / "compile_cache.py").is_file()
     finally:
-        monkeypatch.delenv(compile_cache.ENV_COMPILE_CACHE)
+        monkeypatch.undo()
         compile_cache.ensure_enabled()
-    assert not compile_cache.enabled()
+    assert compile_cache.cache_dir() != str(tmp_path / "envcc")
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ Four contracts under test:
 The subprocess pattern follows ``test_grad_sync.py``: device-count flags
 are process-global in XLA, and tier-1 must keep seeing one device.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -226,6 +227,37 @@ def test_halo_accounting_and_forced_strategy():
     with pytest.raises(ValueError):
         plan_halo(part, local.plan, sig, strategy="teleport")
     assert set(HALO_STRATEGIES) == {"auto", "exchange", "recompute"}
+
+
+def test_halo_auto_decides_by_device_kind(monkeypatch):
+    """``auto`` takes the chip's published peaks by ``device_kind``; the CPU
+    backend always exchanges, and an unlisted TPU kind is an error."""
+    from types import SimpleNamespace
+
+    from repro.core.executor import env_signature
+    from repro.launch.mesh import PEAKS, chip_peaks
+
+    case = get_case("poisson", 10)
+    res = race(case.program, reassociate=case.reassociate)
+    part = plan_partition(res.program, FakeMesh(sx=2, sy=2))
+    local = race(_local_program(res.program, part),
+                 reassociate=case.reassociate)
+    sig = env_signature(build_env(case, np.float32, seed=0))
+    assert plan_halo(part, local.plan, sig, "auto").strategy == "exchange"
+
+    def on(kind):
+        dev = SimpleNamespace(platform="tpu", device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+        return plan_halo(part, local.plan, sig, "auto")
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e is PEAKS["TPU v5 lite"] and v5e["hbm_bw"] == 819e9
+    hp = on("TPU v5 lite")
+    want = ("exchange" if hp.halo_bytes / v5e["ici_bw_per_link"]
+            <= hp.restack_bytes / v5e["hbm_bw"] else "recompute")
+    assert hp.strategy == want
+    with pytest.raises(ValueError, match="TPU v99"):
+        on("TPU v99")
 
 
 # ---------------------------------------------------------------------------
@@ -443,6 +475,8 @@ def test_forced_4device_registry_sweep_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", _SWEEP], capture_output=True, text=True,
         env={"PYTHONPATH": str(SRC), "PATH": "/usr/bin:/bin",
-             "HOME": "/root"}, timeout=540)
+             "HOME": os.environ.get("HOME", ""),
+             "JAX_COMPILATION_CACHE_DIR":
+                 os.environ["JAX_COMPILATION_CACHE_DIR"]}, timeout=540)
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
     assert "OK sharded 15 refused 4" in r.stdout
