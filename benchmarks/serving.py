@@ -278,7 +278,7 @@ def run(print_fn=print, quick: bool = False, repeats: int = None,
                    f";compile_cache={row['compile_cache']}")
         if obs.enabled():
             spans = _span_delta(spans0, obs.span_summary())
-            if not spans.get("serve"):
+            if not spans.get("serve.batch"):
                 raise AssertionError(
                     f"serving.{name}.queue: RACE_OBS=1 but the runtime "
                     f"emitted zero serve spans — instrumentation regressed")

@@ -18,7 +18,7 @@ but 0-based; arrays are indexed A[i, k, j] like the paper's ``R(i,k,j)``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Optional
 
 from repro.core.ir import Program, Scalar, arr, cos, loopnest, program, sin
@@ -38,6 +38,10 @@ class Case:
     # scalar inputs needed by evaluators
     scalars: tuple = ()
     grid3d: bool = False
+
+    def __post_init__(self):
+        if not self.program.name:  # compiled artifacts carry the case name
+            self.program = replace(self.program, name=self.name)
 
 
 # ---------------------------------------------------------------------------

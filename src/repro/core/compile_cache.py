@@ -26,6 +26,14 @@ process-wide listener mirrors them into plain counters (readable with
 ``compile_cache_miss`` decision events, which is what the CI zero-cold-start
 guard asserts on (``repro.obs.report --require-events compile_cache_hit``).
 
+Compile spans: an executor call made under :class:`specializing` credits
+to that executor JAX's own ``/jax/core/compile/*`` time spans (tracing,
+lowering to MLIR, the XLA compile or the load from this cache) that the
+call sets off.  Where there were any, one ``compile`` span covers them, from
+the first start to the last end, and ``race_compile_cache_total`` counts
+``event="compile"`` (and the call's cache hits and misses) under the
+executor's labels; a call that specializes nothing records neither.
+
 :func:`ensure_enabled` is safe to call repeatedly: configuration is applied
 only when the resolved directory changes, so it costs one env read per
 executor build.
@@ -34,6 +42,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from pathlib import Path
 from typing import Optional
 
@@ -51,10 +60,47 @@ _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_MISS = "/jax/compilation_cache/cache_misses"
 _EV_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 
+#: prefix of JAX's time spans for tracing, lowering and compiling a jit
+_EV_COMPILE = "/jax/core/compile/"
+
 _lock = threading.RLock()
 _active_path: Optional[str] = None  # the currently-applied cache dir
 _listener_registered = False
 _counts = {"hits": 0, "misses": 0, "requests": 0}
+
+#: the executor call in progress on this thread (``specializing``)
+_current = threading.local()
+
+
+class specializing:
+    """Credit the compiles that the enclosed executor call sets off to it
+    (observability-on path only; see the module docstring)."""
+
+    __slots__ = ("labels", "t0", "t1", "prev")
+
+    def __init__(self, **labels):
+        self.labels = labels
+        self.t0 = self.t1 = None
+
+    def __enter__(self) -> "specializing":
+        self.prev = getattr(_current, "call", None)
+        _current.call = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _current.call = self.prev
+        if self.t0 is None:
+            return
+        # JAX stamps its spans with time.time(); spans use perf_counter
+        shift = time.perf_counter() - time.time()
+        _obs.record_span("compile", self.t0 + shift, self.t1 + shift,
+                         **self.labels)
+        _obs.counter("race_compile_cache_total", event="compile",
+                     **self.labels).inc()
+
+    def saw(self, start: float, end: float) -> None:
+        self.t0 = start if self.t0 is None else min(self.t0, start)
+        self.t1 = end if self.t1 is None else max(self.t1, end)
 
 
 def _on_monitoring_event(event: str, **kw) -> None:
@@ -62,15 +108,31 @@ def _on_monitoring_event(event: str, **kw) -> None:
     if event == _EV_HIT:
         _counts["hits"] += 1
         if _obs.enabled():
-            _obs.counter("race_compile_cache_total", event="hit").inc()
+            _obs.counter("race_compile_cache_total", event="hit",
+                         **_call_labels()).inc()
             _obs.event("compile_cache_hit", path=_active_path)
     elif event == _EV_MISS:
         _counts["misses"] += 1
         if _obs.enabled():
-            _obs.counter("race_compile_cache_total", event="miss").inc()
+            _obs.counter("race_compile_cache_total", event="miss",
+                         **_call_labels()).inc()
             _obs.event("compile_cache_miss", path=_active_path)
     elif event == _EV_REQUEST:
         _counts["requests"] += 1
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    """jax.monitoring listener: JAX's compile phases, for ``compile``
+    spans."""
+    if event.startswith(_EV_COMPILE):
+        call = getattr(_current, "call", None)
+        if call is not None:
+            call.saw(start, end)
+
+
+def _call_labels() -> dict:
+    call = getattr(_current, "call", None)
+    return call.labels if call is not None else {}
 
 
 def _register_listener() -> None:
@@ -79,6 +141,7 @@ def _register_listener() -> None:
         import jax
 
         jax.monitoring.register_event_listener(_on_monitoring_event)
+        jax.monitoring.register_event_time_span_listener(_on_time_span)
         _listener_registered = True
 
 

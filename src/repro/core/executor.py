@@ -324,6 +324,16 @@ def _stack_column(vals: Sequence):
     return jnp.stack([jnp.asarray(v) for v in vals])
 
 
+def stack_envs(envs: Sequence[Mapping]) -> dict:
+    """Stack same-signature envs along a new leading batch axis, one
+    :func:`_stack_column` per entry (the input :meth:`CompiledRace.run_batch`
+    takes)."""
+    envs = list(envs)
+    if not envs:
+        raise ValueError("run_batch needs at least one env")
+    return {k: _stack_column([e[k] for e in envs]) for k in envs[0]}
+
+
 class CompiledRace:
     """One compiled specialization of a plan: a reusable jitted callable.
 
@@ -397,6 +407,11 @@ class CompiledRace:
             self.trace_count += 1  # python side effect: fires at trace only
             return vjp_core({**env_in, **env_out})
 
+        # a stable name per program and plan: the device trace shows the
+        # module as jit_<name> (a bare _call would read jit__call for every
+        # executor), and two builds of one plan still lower identically
+        self.name = f"race_{plan.program.label}_{self._plan_h[:8]}"
+        _call.__name__ = _call.__qualname__ = self.name
         jit_kw = dict(donate_argnums=(1,)) if donate else {}
         self._jit = jax.jit(_call, **jit_kw)
 
@@ -414,14 +429,18 @@ class CompiledRace:
         ins, outs = self._split(env)
         if not _obs.enabled():  # the RACE_OBS=0 fast path: one flag read
             return self._jit(ins, outs)
-        # first call pays trace + XLA compile inside the jit dispatch — that
-        # is the "compile" span; every later call is steady-state "run"
-        phase = "compile" if self.calls == 1 else "run"
-        with _obs.span(phase, plan=self._plan_h, backend=self.backend):
-            out = self._jit(ins, outs)
-        _obs.counter("race_executor_runs_total", plan=self._plan_h,
-                     backend=self.backend).inc()
-        return out
+        return self._traced(self._jit, "0", ins, outs)
+
+    def _traced(self, fn, batch: str, *args):
+        """A call with observability on: the ``dispatch`` span times entry
+        to the jit's return, which is the enqueue and not the device work;
+        a call that specializes the jit also records a ``compile`` span
+        inside it (:class:`~repro.core.compile_cache.specializing`)."""
+        from .compile_cache import specializing
+
+        labels = dict(plan=self._plan_h, backend=self.backend, batch=batch)
+        with _obs.span("dispatch", **labels), specializing(**labels):
+            return fn(*args)
 
     __call__ = run
 
@@ -441,11 +460,17 @@ class CompiledRace:
             # jnp.asarray per column would
             stacked = dict(envs)
         else:
-            envs = list(envs)
-            if not envs:
-                raise ValueError("run_batch needs at least one env")
-            stacked = {k: _stack_column([e[k] for e in envs])
-                       for k in envs[0]}
+            stacked = stack_envs(envs)
+        fn = self.batch_jit()
+        self.batch_calls += 1
+        if not _obs.enabled():
+            return fn(stacked)
+        size = np.shape(next(iter(stacked.values())))[0] if stacked else 0
+        return self._traced(fn, str(size), stacked)
+
+    def batch_jit(self):
+        """The jitted ``vmap`` of the executor (built at first use), named
+        ``race_batch_<program>_<plan8>``."""
         if self._batch_jit is None:
             with self._batch_lock:
                 if self._batch_jit is None:
@@ -455,17 +480,11 @@ class CompiledRace:
                         self.batch_trace_count += 1
                         return vjp_core(env)
 
-                    self._batch_jit = jax.jit(jax.vmap(_bcall))
-        self.batch_calls += 1
-        if not _obs.enabled():
-            return self._batch_jit(stacked)
-        phase = "compile" if self.batch_calls == 1 else "run"
-        with _obs.span(phase, plan=self._plan_h, backend=self.backend,
-                       batch="1"):
-            out = self._batch_jit(stacked)
-        _obs.counter("race_executor_batch_runs_total", plan=self._plan_h,
-                     backend=self.backend).inc()
-        return out
+                    batched = jax.vmap(_bcall)
+                    batched.__name__ = batched.__qualname__ = (
+                        self.name.replace("race_", "race_batch_", 1))
+                    self._batch_jit = jax.jit(batched)
+        return self._batch_jit
 
     # -- sharded composition --------------------------------------------------
 

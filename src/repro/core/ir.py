@@ -12,6 +12,7 @@ Everything here is immutable; transformation passes rebuild trees.
 from __future__ import annotations
 
 import itertools
+import re
 from collections import Counter
 from dataclasses import dataclass, field
 from fractions import Fraction
@@ -148,6 +149,17 @@ class Program:
     loops: tuple
     body: tuple
     loc: Optional[SourceLoc] = field(default=None, compare=False, repr=False)
+    #: what the program is called (a registry case, a captured function);
+    #: like ``loc``, metadata only: two programs are the same program
+    #: whatever their names
+    name: str = field(default="", compare=False, repr=False)
+
+    @property
+    def label(self) -> str:
+        """A short identifier for the program's compiled artifacts: its
+        ``name``, or else the arrays it writes."""
+        raw = self.name or "_".join(sorted({st.lhs.name for st in self.body}))
+        return re.sub(r"\W", "_", raw)[:40] or "program"
 
     @property
     def depth(self) -> int:

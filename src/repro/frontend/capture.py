@@ -189,7 +189,8 @@ class _Capturer:
         stmts = self._loop(nest, level=1)
         return Program(
             tuple(self.loops), tuple(stmts),
-            loc=SourceLoc(self.filename, self.fndef.lineno, self.indent))
+            loc=SourceLoc(self.filename, self.fndef.lineno, self.indent),
+            name=self.fndef.name)
 
     # -- pre-loop constant bindings ----------------------------------------
 

@@ -49,6 +49,13 @@ from .gather import gather_ref
 from .geometry import (LoweringAnalysis, analyze_plan, aux_shift,
                        platform_reasons, ref_affine, target_platform)
 
+#: device-trace names: the Pallas kernel's op is named ``<prefix><program
+#: label>`` (under ``vmap``, ``vmap_<prefix><label>_``), and the layout
+#: passes of :meth:`LoweredStencil.apply` sit in these named scopes
+KERNEL_PREFIX = "race_kernel_"
+LAYOUT_IN = "race_layout_in"
+LAYOUT_OUT = "race_layout_out"
+
 _FUNCS = {"sin": jnp.sin, "cos": jnp.cos, "exp": jnp.exp, "log": jnp.log,
           "sqrt": jnp.sqrt, "tanh": jnp.tanh, "abs": jnp.abs}
 
@@ -212,30 +219,34 @@ class LoweredStencil:
                          dtype=self.dt) \
             if self.scalar_names else jnp.zeros((1, 1), self.dt)
         ins = [scal]
-        for nm in self.base_names:
-            pr = self.prep[nm]
-            arr = jnp.asarray(env[nm])
-            if pr.gather:
-                ins.append(arr)
-                continue
-            if pr.tperm:
-                arr = jnp.transpose(arr, pr.tperm)
-            for ax in pr.flips:
-                arr = jnp.flip(arr, ax)
-            if any(l or r for l, r in pr.pads):
-                arr = jnp.pad(arr, pr.pads)
-            arr = arr[pr.sls]
-            if pr.expand:
-                arr = arr.reshape(pr.expand)
-            ins.extend([arr] * pr.n_copies)
+        # the layout passes around the kernel carry named scopes: metadata
+        # for the device trace's op names, no op of their own
+        with jax.named_scope(LAYOUT_IN):
+            for nm in self.base_names:
+                pr = self.prep[nm]
+                arr = jnp.asarray(env[nm])
+                if pr.gather:
+                    ins.append(arr)
+                    continue
+                if pr.tperm:
+                    arr = jnp.transpose(arr, pr.tperm)
+                for ax in pr.flips:
+                    arr = jnp.flip(arr, ax)
+                if any(l or r for l, r in pr.pads):
+                    arr = jnp.pad(arr, pr.pads)
+                arr = arr[pr.sls]
+                if pr.expand:
+                    arr = arr.reshape(pr.expand)
+                ins.extend([arr] * pr.n_copies)
         outs = self._call(*ins)
         result = {}
-        for nm, arr in zip(self.out_names, outs):
-            if self.lift:
-                arr = arr[0]
-            arr = arr[tuple(slice(0, e) for e in self.extents)]
-            axes = self.out_axes[nm]
-            result[nm] = jnp.transpose(arr, axes) if axes else arr
+        with jax.named_scope(LAYOUT_OUT):
+            for nm, arr in zip(self.out_names, outs):
+                if self.lift:
+                    arr = arr[0]
+                arr = arr[tuple(slice(0, e) for e in self.extents)]
+                axes = self.out_axes[nm]
+                result[nm] = jnp.transpose(arr, axes) if axes else arr
         return result
 
     __call__ = apply
@@ -295,6 +306,7 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
         out_specs=layout.out_specs,
         out_shape=layout.out_shape,
         interpret=interpret,
+        name=KERNEL_PREFIX + plan.program.label,
     )
     return LoweredStencil(plan=plan, scalar_names=layout.scalar_names,
                           base_names=layout.base_names,

@@ -9,7 +9,9 @@ One process-wide state (registry + event log) behind a single enabled flag:
 Public surface (every call is safe — and near-free — when disabled):
 
     obs.enabled()                  -> bool (one attribute read)
-    obs.span("detect", **labels)   -> context manager timing a phase
+    obs.span("detect", **labels)   -> context manager timing a phase (also
+                                      a ``race.detect`` profiler annotation)
+    obs.record_span(name, t0, t1)  -> a span timed elsewhere, after the fact
     obs.event("kind", **fields)    -> structured decision event
     obs.counter/gauge/histogram()  -> registry series (get-or-create)
     obs.snapshot(label_filter=..)  -> plain-dict metrics view (+ events)
@@ -35,9 +37,11 @@ from .events import DEFAULT_RING, EventLog, load_jsonl
 from .metrics import DEFAULT_BUCKETS, Counter, Gauge, Histogram, Registry
 from .spans import (DEFAULT_SPAN_RING, NOOP_SPAN, Span, SpanLog,
                     current_path, epoch_of_origin)
+from .spans import record_span as _record_span
 
 __all__ = [
-    "enabled", "configure", "reset", "span", "event", "events",
+    "enabled", "configure", "reset", "span", "record_span", "event",
+    "events",
     "counter", "gauge", "histogram", "metrics", "event_log",
     "snapshot", "span_summary", "span_records", "span_log",
     "render_prometheus", "render_json",
@@ -145,13 +149,27 @@ def reset() -> None:
 # -- instrumentation front doors (cheap when disabled) -----------------------
 
 
-def span(name: str, **labels):
-    """Time a phase: ``with obs.span("detect"): ...``.  Disabled -> a shared
-    no-op context manager (no allocation, no clock read)."""
+def span(name: str, attrs=None, **labels):
+    """Time a phase: ``with obs.span("detect"): ...``.  ``labels`` key the
+    histogram series; ``attrs`` (a dict of per-instance values such as
+    request ids) go only into the timeline record and the profiler
+    annotation.  Disabled -> a shared no-op context manager (no allocation,
+    no clock read)."""
     st = _state
     if not st.enabled:
         return NOOP_SPAN
-    return Span(name, st.registry, labels, st.spans)
+    return Span(name, st.registry, labels, st.spans, attrs)
+
+
+def record_span(name: str, t0: float, t1: float, attrs=None, **labels):
+    """Record a span already over, from ``t0`` to ``t1`` in
+    ``time.perf_counter`` seconds, as a child of the innermost open span;
+    returns its id (None when disabled)."""
+    st = _state
+    if not st.enabled:
+        return None
+    return _record_span(name, t0, t1, st.registry, st.spans, attrs,
+                        **labels)
 
 
 def event(kind: str, **fields):

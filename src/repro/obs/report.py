@@ -3,7 +3,7 @@
     PYTHONPATH=src python -m repro.obs.report OBS_metrics.json
     PYTHONPATH=src python -m repro.obs.report --events obs-events.jsonl
     PYTHONPATH=src python -m repro.obs.report OBS_metrics.json \
-        --require-spans detect,lower,compile,run   # CI wiring guard
+        --require-spans detect,lower,compile,dispatch   # CI wiring guard
     PYTHONPATH=src python -m repro.obs.report OBS_metrics.json \
         --trace-out trace.json    # chrome://tracing / Perfetto timeline
 

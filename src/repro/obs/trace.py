@@ -10,9 +10,10 @@ Format that ``chrome://tracing``, Perfetto (https://ui.perfetto.dev), and
 
 Each completed span becomes one complete ("ph": "X") event whose ``ts`` /
 ``dur`` are microseconds on the shared process time axis, so the nested
-detect / lower / compile / run phases reconstruct visually as a flame
-graph per thread — the event's ``args`` carry the nesting ``path`` and the
-span's labels (plan hash, backend) for click-through inspection.  Thread
+detect / lower / compile / dispatch phases reconstruct visually as a flame
+graph per thread — the event's ``args`` carry the nesting ``path``, the
+span's labels (plan hash, backend) and attributes, and its id and parent
+id, for click-through inspection.  Thread
 metadata ("ph": "M") events name the rows.
 
 The exporter is read-side only: it never touches the live registry, so it
@@ -51,9 +52,13 @@ def chrome_trace(spans: Sequence[Mapping],
         tid = int(tid) if isinstance(tid, (int, float)) else 0
         threads.setdefault(tid, str(rec.get("thread", f"tid-{tid}")))
         args = {"path": str(rec.get("path", name))}
-        labels = rec.get("labels")
-        if isinstance(labels, Mapping):
-            args.update({str(k): str(v) for k, v in labels.items()})
+        for key in ("labels", "attrs"):
+            more = rec.get(key)
+            if isinstance(more, Mapping):
+                args.update({str(k): str(v) for k, v in more.items()})
+        for key in ("id", "parent"):
+            if key in rec:
+                args[key] = str(rec[key])
         events.append(dict(name=name, cat="race", ph="X",
                            ts=ts, dur=dur, pid=TRACE_PID, tid=tid,
                            args=args))

@@ -40,11 +40,17 @@ Knobs (all also constructor arguments, documented in README):
                            ``jax.device_count()``)
 
 Telemetry (``RACE_OBS=1``): ``race_serve_queue_depth`` gauge,
-``race_serve_batch_size`` histogram, ``serve_admit``/``serve_reject``
-events, and a ``serve`` span around every coalesced dispatch.
+``race_serve_batch_size`` histogram, ``serve_reject`` events, and spans:
+per request a ``serve.queue`` span from ``submit`` to the moment a worker
+takes it; per batch a ``serve.batch`` span (its ``requests`` attribute
+lists the request ids, space-separated) with children ``serve.stack``,
+the executor's ``dispatch``, ``serve.fetch`` (the device-to-host copy,
+which waits for the device) and ``serve.fanout``.  A lone request is
+neither stacked nor fanned out.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -55,7 +61,7 @@ from typing import Mapping, Optional, Sequence, Union
 from repro import obs as _obs
 from repro.core.depgraph import Plan
 from repro.core.executor import (CompiledRace, compile_plan, default_backend,
-                                 env_signature, plan_hash)
+                                 env_signature, plan_hash, stack_envs)
 
 ENV_MAX_BATCH = "RACE_SERVE_MAX_BATCH"
 ENV_WINDOW_US = "RACE_SERVE_WINDOW_US"
@@ -92,13 +98,18 @@ class ServeRejected(RuntimeError):
         self.code = code
 
 
+#: request ids, unique in the process (the spans of one request share it)
+_request_ids = itertools.count(1)
+
+
 class _Request:
-    __slots__ = ("env", "future", "t")
+    __slots__ = ("env", "future", "t", "id")
 
     def __init__(self, env: Mapping, t: Optional[float] = None):
         self.env = env
         self.future: Future = Future()
         self.t = time.monotonic() if t is None else t
+        self.id = next(_request_ids)
 
 
 class _Group:
@@ -229,7 +240,6 @@ class ServeRuntime:
             _obs.counter("race_serve_requests_total",
                          outcome="admitted").inc()
             _obs.gauge("race_serve_queue_depth").set(depth)
-            _obs.event("serve_admit", plan=ph, backend=b, queue=depth)
         return req.future
 
     def submit_many(self, target, envs: Sequence[Mapping], *,
@@ -291,8 +301,6 @@ class ServeRuntime:
             _obs.counter("race_serve_requests_total",
                          outcome="admitted").inc(n)
             _obs.gauge("race_serve_queue_depth").set(depth)
-            _obs.event("serve_admit", plan=ph, backend=b, queue=depth,
-                       burst=n)
         return [r.future for r in reqs]
 
     def run(self, target, env: Mapping, *, backend: Optional[str] = None,
@@ -413,9 +421,7 @@ class ServeRuntime:
             if not _obs.enabled():
                 results = self._dispatch(ex, take)
             else:
-                with _obs.span("serve", plan=g.plan_h, backend=ex.backend,
-                               batch=str(n)):
-                    results = self._dispatch(ex, take)
+                results = self._dispatch_traced(g, ex, take)
                 _obs.histogram("race_serve_batch_size",
                                edges=BATCH_EDGES).observe(n)
         except Exception as e:  # noqa: BLE001 - delivered per request
@@ -453,6 +459,35 @@ class ServeRuntime:
         stacked = ex.run_batch([r.env for r in take])
         host = {k: np.asarray(v) for k, v in stacked.items()}
         return [{k: host[k][b] for k in host} for b in range(len(take))]
+
+    @staticmethod
+    def _dispatch_traced(g: _Group, ex: CompiledRace, take: list) -> list:
+        """:meth:`_dispatch` with observability on: the same steps, each in
+        its span, under one ``serve.batch`` span; first each request's
+        ``serve.queue`` span, which ends as the batch starts."""
+        import numpy as np
+
+        n = len(take)
+        labels = dict(plan=g.plan_h, backend=ex.backend)
+        ids = " ".join(str(r.id) for r in take)  # the profiler splits ","
+        with _obs.span("serve.batch", attrs=dict(requests=ids),
+                       batch=str(n), **labels):
+            t = time.perf_counter()
+            shift = t - time.monotonic()  # request times are monotonic
+            for r in take:
+                _obs.record_span("serve.queue", r.t + shift, t,
+                                 attrs=dict(request=r.id), **labels)
+            if n == 1:
+                out = ex.run(take[0].env)
+                with _obs.span("serve.fetch", **labels):
+                    return [{k: np.asarray(v) for k, v in out.items()}]
+            with _obs.span("serve.stack", **labels):
+                stacked = stack_envs([r.env for r in take])
+            out = ex.run_batch(stacked)
+            with _obs.span("serve.fetch", **labels):
+                host = {k: np.asarray(v) for k, v in out.items()}
+            with _obs.span("serve.fanout", **labels):
+                return [{k: host[k][b] for k in host} for b in range(n)]
 
     # -- lifecycle / introspection ------------------------------------------
 

@@ -117,6 +117,9 @@ class ShardedRace:
             self.trace_count += 1  # python side effect: fires at trace only
             return vjp_core(env)
 
+        self.name = (f"race_sharded_{result.plan.program.label}_"
+                     f"{self._plan_h[:8]}")
+        _call.__name__ = _call.__qualname__ = self.name
         self._jit = jax.jit(_call)
 
     # -- forward ------------------------------------------------------------
@@ -129,9 +132,11 @@ class ShardedRace:
         env = dict(env)
         if not _obs.enabled():
             return self._jit(env)
-        phase = "compile" if self.calls == 1 else "run"
-        with _obs.span(phase, plan=self._plan_h, backend=self.backend,
-                       sharded="1"):
+        from repro.core.compile_cache import specializing
+
+        labels = dict(plan=self._plan_h, backend=self.backend, batch="0",
+                      sharded="1")
+        with _obs.span("dispatch", **labels), specializing(**labels):
             out = self._jit(env)
         hp = self.halo_prog
         _obs.counter("race_shard_runs_total", plan=self._plan_h,
@@ -228,7 +233,7 @@ def _local_program(program: Program, partition) -> Program:
         Loop(lp.level, lp.var, lp.lo, lp.lo + chunks[lp.level] - 1)
         if lp.level in chunks else lp
         for lp in program.loops)
-    return Program(loops, program.body, program.loc)
+    return Program(loops, program.body, program.loc, program.name)
 
 
 #: RaceResult.options knobs forwarded to the local (per-chunk) RACE build,

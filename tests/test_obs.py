@@ -23,9 +23,10 @@ from repro import obs
 from repro.apps.paper_kernels import get_case
 from repro.core.adjoint import adjoint_build
 from repro.core.backend import select_backend
-from repro.core.executor import (clear_cache, compile_plan, configure_cache,
-                                 executor_cache, plan_hash)
-from repro.core.ir import arr, loopnest, program
+from repro.core.executor import (ExecutorCache, clear_cache, compile_plan,
+                                 configure_cache, executor_cache, plan_hash,
+                                 stack_envs)
+from repro.core.ir import Program, arr, loopnest, program
 from repro.core.race import race
 from repro.frontend import D_CONTROL_FLOW, CaptureError, capture
 from repro.obs import report
@@ -364,12 +365,13 @@ def test_executor_run_spans_and_counters():
         ex(env)
     summary = obs.span_summary()
     assert summary["lower"]["count"] == 1
-    assert summary["compile"]["count"] == 1  # first call only
-    assert summary["run"]["count"] == 2
+    assert summary["compile"]["count"] == 1  # the one specialization
+    assert summary["dispatch"]["count"] == 3  # every call
     ph = plan_hash(res.plan)
     snap = obs.snapshot()
     assert snap["counters"][
-        f"race_executor_runs_total{{backend=xla,plan={ph}}}"] == 3
+        f"race_compile_cache_total{{backend=xla,batch=0,event=compile,"
+        f"plan={ph}}}"] == 1
 
 
 def test_race_spans_gauges_and_telemetry():
@@ -545,3 +547,151 @@ def test_run_stamp_fields():
     # the host signature keys benchmark-history baselines (env_key)
     assert isinstance(st["host_cpu_count"], int) and st["host_cpu_count"] >= 1
     assert st["host"]
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, compile spans, stable device names
+# ---------------------------------------------------------------------------
+
+
+def _profiled_host_events(tmp_path, body) -> list:
+    """``[(name, {stat: value})]`` of the ``race.*`` host events that a CPU
+    ``jax.profiler`` trace of ``body()`` recorded."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    out = []
+    for path in tmp_path.rglob("*.xplane.pb"):
+        pd = jax.profiler.ProfileData.from_file(str(path))
+        for plane in pd.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                out.extend((e.name, {k: v for k, v in e.stats})
+                           for e in line.events
+                           if e.name.startswith("race."))
+    return out
+
+
+def test_span_lands_in_profiler_trace_with_parent_id(tmp_path):
+    _enable()
+
+    def body():
+        with obs.span("outer", plan="p"):
+            with obs.span("inner", attrs=dict(requests="3 4")):
+                pass
+
+    events = dict(_profiled_host_events(tmp_path, body))
+    assert set(events) == {"race.outer", "race.inner"}
+    outer, inner = events["race.outer"], events["race.inner"]
+    assert outer["parent"] == 0 and outer["plan"] == "p"
+    assert inner["parent"] == outer["id"]
+    assert inner["requests"] == "3 4"
+    # the timeline records carry the same ids
+    recs = {r["name"]: r for r in obs.span_records()}
+    assert recs["outer"]["id"] == outer["id"]
+    assert recs["inner"]["parent"] == recs["outer"]["id"]
+    assert recs["inner"]["attrs"] == {"requests": "3 4"}
+    assert "attrs" not in recs["outer"]
+
+
+def test_disabled_span_reaches_no_profiler_trace(tmp_path):
+    assert not obs.enabled()
+
+    def body():
+        with obs.span("outer"):
+            pass
+
+    assert _profiled_host_events(tmp_path, body) == []
+
+
+def test_record_span_is_a_child_of_the_open_span():
+    _enable()
+    t = time.perf_counter()
+    with obs.span("batch") as outer:
+        rid = obs.record_span("queue", t - 0.002, t, attrs=dict(request=7),
+                              plan="p")
+    rec = next(r for r in obs.span_records() if r["name"] == "queue")
+    assert rec["id"] == rid and rec["parent"] == outer.id
+    assert rec["path"] == "batch/queue"
+    assert rec["dur_us"] == pytest.approx(2000.0)
+    assert rec["attrs"] == {"request": "7"} and rec["labels"] == {"plan": "p"}
+    assert obs.span_summary()["queue"]["count"] == 1
+
+
+def test_record_span_disabled_is_a_noop():
+    assert obs.record_span("queue", 0.0, 1.0) is None
+    assert obs.span_records() == [] and obs.span_summary() == {}
+
+
+def _compile_records(ph):
+    return [r for r in obs.span_records()
+            if r["name"] == "compile" and r["labels"]["plan"] == ph]
+
+
+def test_compile_span_once_per_specialization():
+    """A ``compile`` span for each jit specialization (the single call and
+    each batch size), none for a repeated signature, each inside the
+    ``dispatch`` span of the call that set it off."""
+    _enable()
+    case = get_case("gaussian", 12)
+    res = race(case.program, reassociate=case.reassociate)
+    env = build_env(case)
+    clear_cache()
+    ex = compile_plan(res.plan, env, "xla")
+    ph = plan_hash(res.plan)
+    ex.run(env)
+    ex.run(env)
+    assert [r["labels"]["batch"] for r in _compile_records(ph)] == ["0"]
+    ex.run_batch([env, env])
+    ex.run_batch([env, env])
+    ex.run_batch([env, env, env])
+    recs = _compile_records(ph)
+    assert [r["labels"]["batch"] for r in recs] == ["0", "2", "3"]
+    dispatch = {r["id"]: r for r in obs.span_records()
+                if r["name"] == "dispatch"}
+    assert len(dispatch) == 5
+    for r in recs:
+        parent = dispatch[r["parent"]]
+        assert parent["labels"]["batch"] == r["labels"]["batch"]
+        assert 0 < r["dur_us"] <= parent["dur_us"]
+    counters = obs.snapshot()["counters"]
+    for b in ("0", "2", "3"):
+        assert counters[f"race_compile_cache_total{{backend=xla,batch={b},"
+                        f"event=compile,plan={ph}}}"] == 1
+
+
+def test_stable_module_and_kernel_names():
+    """The jitted steps are named after the program and the plan, so the
+    XLA modules read ``jit_race_<program>_<plan8>``; the Pallas kernel is
+    ``race_kernel_<program>``."""
+    case = get_case("gaussian", 12)
+    res = race(case.program, reassociate=case.reassociate)
+    env = build_env(case)
+    ph = plan_hash(res.plan)[:8]
+    assert res.plan.program.label == "gaussian"
+    ex = compile_plan(res.plan, env, "pallas", cache=ExecutorCache())
+    assert ex.name == f"race_gaussian_{ph}"
+    ins, outs = ex._split(env)
+    low = ex._jit.lower(ins, outs)
+    assert low.as_text().startswith(f"module @jit_race_gaussian_{ph} ")
+    assert "race_kernel_gaussian" in low.as_text(debug_info=True)
+    stacked = stack_envs([env, env])
+    assert ex.batch_jit().lower(stacked).as_text().startswith(
+        f"module @jit_race_batch_gaussian_{ph} ")
+
+
+def test_program_label_falls_back_to_written_arrays():
+    loops, (i,) = loopnest(("i", 1, 8))
+    u, out = arr("u_lbl"), arr("o.lbl")
+    prog = program(loops, [(out[i], u[i - 1] + u[i + 1])])
+    assert prog.label == "o_lbl"
+    named = get_case("psinv", 10).program
+    # the name is metadata: equality ignores it
+    assert named.label == "psinv"
+    assert named == Program(named.loops, named.body)
+    assert Program(named.loops, named.body).label == "U"

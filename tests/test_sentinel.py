@@ -360,7 +360,7 @@ def test_chrome_trace_structure_and_tolerance():
 
 
 def test_pipeline_trace_reconstructs_phase_hierarchy(tmp_path, capsys):
-    """The acceptance scenario: a real detect -> lower -> compile -> run
+    """The acceptance scenario: a real detect -> lower -> compile -> dispatch
     pipeline dumped and exported via ``report --trace-out`` yields valid
     Chrome trace JSON whose span events carry the nesting paths."""
     from repro.apps.paper_kernels import get_case
@@ -373,8 +373,8 @@ def test_pipeline_trace_reconstructs_phase_hierarchy(tmp_path, capsys):
     res = race(case.program, reassociate=case.reassociate)
     clear_cache()
     env = build_env(case)
-    res.run(env, "xla")  # cold: lower + compile spans
-    res.run(env, "xla")  # steady: run span
+    res.run(env, "xla")  # cold: lower + compile (inside dispatch) spans
+    res.run(env, "xla")  # steady: dispatch span
     dump = tmp_path / "dump.json"
     obs.dump(dump)
     trace = tmp_path / "trace.json"
@@ -384,7 +384,7 @@ def test_pipeline_trace_reconstructs_phase_hierarchy(tmp_path, capsys):
     doc = json.loads(trace.read_text())
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in xs}
-    assert {"detect", "lower", "compile", "run"} <= names
+    assert {"detect", "lower", "compile", "dispatch"} <= names
     # every event's path terminates in its own leaf name (hierarchy intact)
     for e in xs:
         assert e["args"]["path"].split("/")[-1] == e["name"]

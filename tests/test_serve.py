@@ -329,3 +329,37 @@ def test_runtime_env_knobs(monkeypatch):
     monkeypatch.setenv("RACE_SERVE_MAX_BATCH", "0")
     with pytest.raises(ValueError, match="RACE_SERVE_MAX_BATCH"):
         ServeRuntime(backend="xla")
+
+
+@pytest.mark.parametrize("burst", [1, 3])
+def test_serve_spans_share_request_id(burst):
+    """With observability on, one request's spans share its id: its
+    ``serve.queue`` span names it, the ``serve.batch`` span that served it
+    lists it, and the batch's steps are that span's children."""
+    from repro import obs
+
+    obs.configure(enabled=True)
+    case, res = _res()
+    envs = [build_env(case, seed=s) for s in range(burst)]
+    want = [res.run(e, "xla") for e in envs]
+    with ServeRuntime(max_batch=8, window_us=10000, workers=1,
+                      backend="xla") as rt:
+        for f, w in zip(rt.submit_many(res.plan, envs), want):
+            _outputs_equal(f.result(timeout=120), w)
+    recs = obs.span_records()
+    queued = [r for r in recs if r["name"] == "serve.queue"]
+    batches = [r for r in recs if r["name"] == "serve.batch"]
+    assert len(queued) == burst and len(batches) == 1
+    batch = batches[0]
+    ids = batch["attrs"]["requests"].split()
+    assert sorted(r["attrs"]["request"] for r in queued) == sorted(ids)
+    assert len(set(ids)) == burst
+    assert batch["labels"]["batch"] == str(burst)
+    children = {r["name"] for r in recs if r["parent"] == batch["id"]}
+    steps = {"serve.queue", "dispatch", "serve.fetch"}
+    if burst > 1:
+        steps |= {"serve.stack", "serve.fanout"}
+    assert children == steps
+    for r in queued:
+        assert r["dur_us"] >= 0 and r["ts_us"] <= batch["ts_us"] + 1.0
+    assert not obs.events(kind="serve_admit")

@@ -156,3 +156,27 @@ def test_sharded_path_compiles_for_v5e_2x2(topo, on_tpu):
         text = ex._jit.lower(args).compile().as_text()
         assert "tpu_custom_call" in text
         assert ("collective-permute" in text) == (halo == "exchange")
+
+
+def test_stable_names_compile_for_v5e(one_chip, on_tpu):
+    """The executor's jitted steps compile to modules named after the
+    program and the plan, and the compiled Pallas kernel's op (the name
+    the device trace's ``XLA Ops`` line shows) is ``race_kernel_<program>``,
+    ``vmap_race_kernel_<program>_`` in the batch."""
+    from repro.core.executor import ExecutorCache, compile_plan, plan_hash
+
+    case, plan = _plan("psinv", 34)
+    args = {k: jax.ShapeDtypeStruct(tuple(s), jnp.float32, sharding=one_chip)
+            for k, s in required_shapes(case.program).items()}
+    ex = compile_plan(plan, args, "pallas", cache=ExecutorCache())
+    assert ex.spec.interpret is False
+    ph = plan_hash(plan)[:8]
+    text = ex._jit.lower(*ex._split(args)).compile().as_text()
+    assert text.startswith(f"HloModule jit_race_psinv_{ph},")
+    assert "%race_kernel_psinv." in text and "%_call." not in text
+    batch = {k: jax.ShapeDtypeStruct((3,) + v.shape, v.dtype,
+                                     sharding=one_chip)
+             for k, v in args.items()}
+    text = ex.batch_jit().lower(batch).compile().as_text()
+    assert text.startswith(f"HloModule jit_race_batch_psinv_{ph},")
+    assert "%vmap_race_kernel_psinv_" in text
