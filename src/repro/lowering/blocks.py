@@ -5,8 +5,7 @@ Every level except the innermost is grid-tiled — level 1 by ``block_rows``,
 levels ``2..m-1`` by ``block_cols`` — and the innermost level stays
 full-width for the VPU lanes unless ``block_inner > 0`` tiles it too.  The
 single level of a 1-D nest is its innermost level, so it follows the same
-rule.  A 4-D nest gets a 3-axis grid (levels 1-3) with 27 halo block copies
-per fully-covered window operand.
+rule.  A 4-D nest gets a 3-axis grid (levels 1-3).
 
 Every block satisfies the TPU's tiling rule, also under ``jax.vmap`` (which
 adds a squeezed batch axis in front): the last block axis spans its whole
@@ -15,14 +14,22 @@ before it is whole or a multiple of 8.  A window operand that misses a level
 gets a size-1 axis there, so it has rank ``m``, and a 1-D nest is lifted to
 rank 2 by a leading size-1 axis on its windows and outputs.
 
-Per window-class array and blocked level the input window is the standard
-three consecutive input blocks (prev/cur/next) of ``|a|·tile`` elements; a
-*center* offset ``c`` positions the reference offsets inside that 3-block
-span.  Ordinary small offsets keep ``c = 0`` (the historical layout);
-mirrored-origin references — whose normalized offsets ``b' = L-1-b`` sit
-near the far end of the axis — recenter instead, so negative coefficients
-cost nothing beyond the per-call ``jnp.flip``.  Unblocked levels carry the
-asymmetric ``[off_lo, off_hi]`` envelope as a compile-time halo pad.
+Each window-class array is one operand with one block per grid step: the
+output tile widened by the array's own ``[off_lo, off_hi]`` envelope on
+every grid-tiled level, so neighbouring steps' windows overlap by the halo.
+The per-call prep cuts the array to start at the envelope's low end
+(``a·lo + off_lo``), so the window of grid step ``p`` starts at element
+``p·|a|·tile`` and a reference with offset ``b`` reads window element
+``b - off_lo``.  Its width is ``|a|·tile + spread`` rounded up to the axis'
+alignment (:func:`_align`): 1 on a leading axis, 8 on the second-minor axis,
+128 on a tiled lane axis.  Mosaic (jax 0.9, TPU v5e) takes such windows as
+``pl.Element`` block dims on all axes or on none; on the last two axes the
+element offset must be provably a multiple of the (8, 128) tiling and the
+size a multiple too, while a leading axis takes any offset and size.  An
+array with no halo on any tiled level takes the bare tile by block index.
+Unblocked levels carry the whole envelope as one full-width block.
+Mirrored-origin references (negative coefficients) are flipped per call,
+so their normalized offsets ``b' = L-1-b`` need no more than any other.
 
 Gather-class arrays bypass the window machinery entirely: the whole
 (untransposed, unpadded) array is one BlockSpec whose index map pins block
@@ -30,7 +37,6 @@ Gather-class arrays bypass the window machinery entirely: the whole
 """
 from __future__ import annotations
 
-import itertools
 from dataclasses import dataclass
 
 import numpy as np
@@ -59,6 +65,17 @@ def level_blocks(m: int, block_rows: int, block_cols: int,
     return blocks
 
 
+def _align(l: int, m: int) -> int:
+    """The multiple a window's size and start keep along level ``l``: the
+    TPU tiles a block's last two axes by (8, 128) and checks that an element
+    offset there is provably a multiple of the tiling."""
+    if l == m:
+        return 128
+    if l == m - 1:
+        return 8
+    return 1
+
+
 def _knob(l: int, m: int, block_inner: int) -> str:
     if l == m and block_inner:
         return "block_inner"
@@ -75,7 +92,6 @@ class ArrayPrep:
     flips: tuple  # post-transpose axes to jnp.flip (mirrored-origin levels)
     pads: tuple  # per-axis (left, right) zero pad
     sls: tuple  # per-axis window slice after padding
-    n_copies: int  # 3**len(blocked levels); 1 for gather operands
     gather: bool = False  # whole-array operand, indexed in-kernel
     expand: tuple = ()  # full-rank shape: size-1 axes at missing levels
 
@@ -105,6 +121,7 @@ class Layout:
     out_shape: list
     out_tile: tuple
     out_axes: dict  # out name -> inverse level-major transpose, or ()
+    read_factor: dict  # base name -> elements fetched / elements it reads
 
 
 def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
@@ -147,18 +164,20 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
     lift = m == 1
     lead = (1,) if lift else ()
 
-    def _imap(covered, ds_map, lead=()):
-        # block-index map: blocked axes follow the grid id plus their halo
-        # offset d in {0,1,2}; unblocked axes are one full-width block
+    def _imap(covered, step, lead=()):
+        # index map: a grid-tiled level moves by ``step[l]`` per grid id (1
+        # for a block index, the window's stride for an element offset);
+        # every other axis is one whole block at 0
         def imap(*pids):
             return (0,) * len(lead) + tuple(
-                pids[grid_pos[l]] + ds_map[l] if l in ds_map else 0
+                pids[grid_pos[l]] * step[l] if l in step else 0
                 for l in covered)
         return imap
 
     prep: dict = {}
     slice_base: dict = {}
     mirror: dict = {}
+    read_factor: dict = {}
     for nm in base_names:
         info = analysis.arrays[nm]
         shape = tuple(shapes[nm])
@@ -167,9 +186,10 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
                 f"{nm}: environment array has rank {len(shape)}, plan "
                 f"references rank {info.ndim}")
         if info.kind == K_GATHER:
-            prep[nm] = ArrayPrep((), (), (), (), 1, gather=True)
+            prep[nm] = ArrayPrep((), (), (), (), gather=True)
             in_specs.append(pl.BlockSpec(
                 shape, _imap(tuple(range(len(shape))), {})))
+            read_factor[nm] = 1.0  # fetched whole, once
             continue
         tperm = info.perm
         if tperm == tuple(range(len(shape))):
@@ -180,6 +200,9 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
         flips, pads, sls, block_shape = [], [], [], []
         sb: dict = {}
         mir: dict = {}
+        stride: dict = {}  # blocked level -> window start per grid step
+        halo = False  # some blocked level's window overlaps its neighbour's
+        region = 1  # elements of the region the operand reads
         for ax, l in enumerate(covered):
             a = info.coefs[l]
             L = shape[ax]
@@ -192,32 +215,33 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
                 off_hi = (L - 1) - info.off_lo[l]
             else:
                 off_lo, off_hi = info.off_lo[l], info.off_hi[l]
+            # the prepared operand starts at the envelope's low end, so a
+            # reference reads window element b - off_lo (+ a·k in the tile)
+            start = a * lo[l - 1] + off_lo
+            span = a * (extents[l - 1] - 1) + (off_hi - off_lo) + 1
+            region *= span
             if l in blocks:
                 abl = a * blocks[l]
-                c_min = off_hi - abl - (a - 1)
-                c_max = off_lo + abl
-                if c_min > c_max:
+                need = abl - (a - 1) + (off_hi - off_lo)
+                if need > 3 * abl:
                     knob = _knob(l, m, block_inner)
                     raise LoweringError(
                         (), f"{nm}: level-{l} halo spread "
                             f"{off_hi - off_lo} exceeds the input block "
                             f"size {abl}; raise {knob}")
-                c = min(max(0, c_min), c_max)
-                start = a * lo[l - 1] - abl + c
-                length = (nb[l] + 2) * abl
-                block_shape.append(abl)
-                sb[l] = abl - c
+                align = _align(l, m)
+                width = -(-max(need, abl) // align) * align
+                length = (nb[l] - 1) * abl + width
+                stride[l] = abl
+                halo = halo or width != abl
             else:
-                start = a * lo[l - 1] + off_lo
-                length = a * (extents[l - 1] - 1) + (off_hi - off_lo) + 1
-                block_shape.append(length)
-                sb[l] = -off_lo
+                width = length = span
+            block_shape.append(width)
+            sb[l] = -off_lo
             left = max(0, -start)
             right = max(0, start + length - L)
             pads.append((left, right))
             sls.append(slice(start + left, start + left + length))
-        blk = [l for l in covered if l in blocks]
-        n_copies = 3 ** len(blk)
         expand = ()
         if len(covered) < m or lift:
             # a lower-rank operand would put a tiled level (or, under vmap,
@@ -231,12 +255,25 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
             expand = lead + tuple(size.get(l, 1) for l in covered)
             block_shape = list(lead) + [bsize.get(l, 1) for l in covered]
         prep[nm] = ArrayPrep(tperm, tuple(flips), tuple(pads), tuple(sls),
-                             n_copies, expand=expand)
+                             expand=expand)
         slice_base[nm] = sb
         mirror[nm] = mir
-        for ds in itertools.product((0, 1, 2), repeat=len(blk)):
+        # the TPU skips a block's copy while its index stays unchanged, so
+        # the window is fetched once per step of the grid axes up to the
+        # innermost one it moves along
+        moves = [grid_pos[l] for l in stride]
+        fetches = int(np.prod(grid[:max(moves) + 1])) if moves else 1
+        read_factor[nm] = fetches * int(np.prod(block_shape)) / region
+        if not halo:
+            # no halo on any tiled level: the bare tile, by block index
             in_specs.append(pl.BlockSpec(
-                tuple(block_shape), _imap(covered, dict(zip(blk, ds)), lead)))
+                tuple(block_shape),
+                _imap(covered, dict.fromkeys(stride, 1), lead)))
+        else:
+            # one overlapping window: element offsets on every axis
+            in_specs.append(pl.BlockSpec(
+                tuple(pl.Element(d) for d in block_shape),
+                _imap(covered, stride, lead)))
 
     out_tile = tuple(blocks.get(l, extents[l - 1]) for l in range(1, m + 1))
     out_padded = tuple(nb[l] * blocks[l] if l in blocks else extents[l - 1]
@@ -245,7 +282,7 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
                  for _ in out_names]
     out_specs = [pl.BlockSpec(lead + out_tile,
                               _imap(tuple(range(1, m + 1)),
-                                    {l: 0 for l in grid_levels}, lead))
+                                    dict.fromkeys(grid_levels, 1), lead))
                  for _ in out_names]
 
     out_axes = {}
@@ -264,4 +301,4 @@ def build_layout(analysis: LoweringAnalysis, shapes: dict, dtypes: dict,
         gather_names=frozenset(nm for nm in base_names
                                if analysis.arrays[nm].kind == K_GATHER),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
-        out_tile=out_tile, out_axes=out_axes)
+        out_tile=out_tile, out_axes=out_axes, read_factor=read_factor)

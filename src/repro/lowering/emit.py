@@ -8,9 +8,11 @@ the streaming loop with a small rolling buffer" re-expressed for the
 HBM->VMEM hierarchy — now generic over nest depth and window shape:
 
   * the iteration space is level-major; ``repro.lowering.blocks`` grid-tiles
-    every level but the innermost (any depth), each blocked level seeing
-    three consecutive input blocks per window operand (block-level halo
-    exchange, the standard Pallas idiom);
+    every level but the innermost (any depth), and each window operand
+    arrives as one overlapping window per grid step: the output tile
+    widened by the operand's offset envelope, aligned to the TPU's (8, 128)
+    tiling on the last two axes (``LoweredStencil.read_factor`` records
+    what that costs in reads);
   * window references — positive *or* negative integer coefficients — lower
     to static strided slices; mirrored-origin references read their flipped
     operand through normalized offsets (``repro.lowering.geometry``);
@@ -30,8 +32,7 @@ one-shot signature by chaining the two.
 """
 from __future__ import annotations
 
-import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs as _obs
 from repro.core.depgraph import Plan
 from repro.core.ir import Const, Expr, Node, Ref
 
@@ -88,24 +90,9 @@ def build_kernel(plan: Plan, analysis: LoweringAnalysis, layout: Layout):
     def kernel(*refs):
         it = iter(refs)
         scal = next(it)  # (1, n_scalars)
-        windows = {}
-        for nm in layout.base_names:
-            if nm in layout.gather_names:
-                windows[nm] = next(it)[...]  # the whole operand
-                continue
-            blk = [l for l in arrays[nm].levels if l in blocks]
-            parts = {}
-            for ds in itertools.product((0, 1, 2), repeat=len(blk)):
-                parts[ds] = load(next(it))
-
-            def assemble(prefix, rem):
-                if not rem:
-                    return parts[prefix]
-                return jnp.concatenate(
-                    [assemble(prefix + (d,), rem[1:]) for d in (0, 1, 2)],
-                    axis=rem[0] - 1)  # operand axes are levels 1..m
-
-            windows[nm] = assemble((), tuple(blk))
+        # a gather operand arrives whole; a window operand as one window
+        windows = {nm: next(it)[...] if nm in layout.gather_names
+                   else load(next(it)) for nm in layout.base_names}
         outs = [next(it) for _ in layout.out_names]
 
         env_scalar = {nm: scal[0, i]
@@ -210,6 +197,9 @@ class LoweredStencil:
     out_axes: dict  # out name -> inverse level-major transpose, or ()
     interpret: bool
     lift: bool = False  # 1-D nest: outputs carry a leading size-1 axis
+    #: base name -> elements the kernel fetches over the grid / elements of
+    #: the region it reads (1.0: each element once)
+    read_factor: dict = field(default_factory=dict)
     analysis: LoweringAnalysis = None
     _call: object = None  # the constructed pl.pallas_call callable
 
@@ -237,7 +227,7 @@ class LoweredStencil:
                 arr = arr[pr.sls]
                 if pr.expand:
                     arr = arr.reshape(pr.expand)
-                ins.extend([arr] * pr.n_copies)
+                ins.append(arr)
         outs = self._call(*ins)
         result = {}
         with jax.named_scope(LAYOUT_OUT):
@@ -308,12 +298,17 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
         interpret=interpret,
         name=KERNEL_PREFIX + plan.program.label,
     )
+    if _obs.enabled():
+        for nm, factor in layout.read_factor.items():
+            _obs.gauge("race_kernel_read_factor", program=plan.program.label,
+                       array=nm).set(factor)
     return LoweredStencil(plan=plan, scalar_names=layout.scalar_names,
                           base_names=layout.base_names,
                           out_names=layout.out_names, dt=layout.dt,
                           prep=layout.prep, extents=layout.extents,
                           out_axes=layout.out_axes, interpret=interpret,
                           lift=layout.lift,
+                          read_factor=layout.read_factor,
                           analysis=analysis, _call=call)
 
 

@@ -223,6 +223,67 @@ def test_mixed_dim_level_order_transpose():
     _check_case(case)
 
 
+def test_overlapping_window_matches_numpy():
+    """Each window operand is one overlapping window per grid step: a low
+    offset of -4 on the second-minor axis (a 14-row envelope in a 16-row
+    window, as in NPB MG's resid) beside a mirrored leading level, checked
+    against NumPy."""
+    M = 12
+    loops, (i, j, k) = loopnest(("i", 1, 10), ("j", 4, 19), ("k", 1, 12))
+    u, out = arr("u"), arr("ow")
+    t = lambda dj: u[-i + M, j + dj, k] + u[-i + (M - 1), j + dj, k + 1]  # noqa: E731
+    prog = program(loops, [(out[i, j, k], (t(-4) + t(-3)) + (t(1) + t(2)))])
+    res = race(prog, reassociate=3)
+    env = {"u": np.random.default_rng(7).standard_normal(
+        (13, 22, 14)).astype(np.float32)}
+    got = res.run(env, "pallas")["ow"]
+
+    I, J, K = np.ix_(np.arange(1, 11), np.arange(4, 20), np.arange(1, 13))
+    U = env["u"].astype(np.float64)
+    T = lambda dj: U[M - I, J + dj, K] + U[M - 1 - I, J + dj, K + 1]  # noqa: E731
+    want = (T(-4) + T(-3)) + (T(1) + T(2))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name,size,exact,at_most", [
+    # 8x8 tiles of levels 1-2: a halo'd operand reads 8 + spread rows on a
+    # leading axis and 16 on the second-minor one, so at most 1.5 x 2
+    ("resid", 18, {"V": 1.0}, {"U": 3.0}),
+    ("psinv", 18, {"U": 1.0}, {"R": 3.0}),
+    # level 1 is second-minor: a 16-row window for an 8-row tile
+    ("hdifft_gm", (10, 14), {"T": 2.0, "S": 2.0}, {}),
+    # levels 1-3 tiled: 8/8 x 9/8 x 16/8 (3**3 = 27 with a copy per
+    # neighbour block)
+    ("blocked4d", 18, {}, {"T4": 9 / 8 * 16 / 8}),
+])
+def test_read_factor(name, size, exact, at_most):
+    """``read_factor``: elements the kernel fetches over the grid per
+    element of the region an operand reads.  hdifft_gm goes through
+    ``run_batch``, whose vmapped kernel fetches each level's window."""
+    case = (CASES[name][0](*size) if isinstance(size, tuple)
+            else get_case(name, size))
+    res = race(case.program, reassociate=case.reassociate,
+               rewrite_div=case.rewrite_div)
+    if name == "hdifft_gm":
+        envs = [build_env(case, np.float32, seed=s) for s in range(3)]
+        got = res.run_batch(envs, "pallas")
+        for b, env in enumerate(envs):
+            want = reference(res.plan, env)
+            for k in want:
+                np.testing.assert_allclose(np.asarray(got[k][b]),
+                                           np.asarray(want[k]),
+                                           rtol=1e-5, atol=1e-6)
+        spec = compile_plan(res.plan, envs[0], "pallas").spec
+    else:
+        spec = specialize_stencil(
+            res.plan, *_sig(build_env(case, np.float32)))
+    assert set(spec.read_factor) == set(exact) | set(at_most)
+    for nm, want in exact.items():
+        assert spec.read_factor[nm] == pytest.approx(want), nm
+    for nm, bound in at_most.items():
+        assert 1.0 < spec.read_factor[nm] <= bound, nm
+
+
 # ---------------------------------------------------------------------------
 # probe == engine: the stale-fallback-drift regression
 # ---------------------------------------------------------------------------

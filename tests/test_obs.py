@@ -392,6 +392,27 @@ def test_race_spans_gauges_and_telemetry():
         assert f"plan={tel['plan']}" in series or "plan=" not in series
 
 
+def test_kernel_read_factor_gauge():
+    """Specializing a Pallas kernel sets one ``race_kernel_read_factor``
+    gauge per operand: the halo'd operand's window over its tile, 1.0 for
+    the operand read at the centre only."""
+    from repro.lowering import specialize_stencil
+
+    _enable()
+    case = get_case("psinv", 18)
+    res = race(case.program, reassociate=case.reassociate)
+    env = build_env(case, np.float32)
+    spec = specialize_stencil(
+        res.plan, {k: np.shape(v) for k, v in env.items()},
+        {k: np.asarray(v).dtype for k, v in env.items()})
+    gauges = obs.snapshot()["gauges"]
+    label = res.plan.program.label
+    for nm, factor in spec.read_factor.items():
+        assert gauges[f"race_kernel_read_factor{{array={nm},program={label}}}"
+                      ] == factor
+    assert gauges[f"race_kernel_read_factor{{array=U,program={label}}}"] == 1.0
+
+
 def test_backend_fallback_event():
     _enable()
     res = race(_strided_program("bf"))

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 import repro.core.backend as backend
-from repro.apps.paper_kernels import get_case
+from repro.apps.paper_kernels import CASES, get_case
 from repro.core.backend import probe_pallas
 from repro.core.codegen import required_shapes
 from repro.core.race import race
@@ -69,7 +69,9 @@ def on_tpu(monkeypatch):
 
 
 def _plan(name, n):
-    case = get_case(name, n)
+    """A registry case at size ``n``, or at ``(nx, ny)`` for a 2-D builder
+    that takes both."""
+    case = CASES[name][0](*n) if isinstance(n, tuple) else get_case(name, n)
     return case, race(case.program, reassociate=case.reassociate,
                       rewrite_div=case.rewrite_div).plan
 
@@ -92,6 +94,8 @@ def _compile(plan, program, sharding, batch=0, **blocks):
 
 @pytest.mark.parametrize("name,n,batch", [
     ("psinv", 256, 0),        # NPB MG class B
+    ("resid", 256, 0),
+    ("hdifft_gm", (320, 384), 60),  # POP gx1v6, all 60 levels in a batch
     ("j3d27pt", 256, 0),
     ("gaussian", 2048, 0),
     ("psinv", 256, 4),        # the serving runtime's vmapped batch
