@@ -295,7 +295,7 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
             local_res = race(local_prog,
                              backend=result.options.get("backend"),
                              **race_kw)
-            with _obs.span("halo_exchange", plan=ph):
+            with _obs.span("halo_plan", plan=ph):
                 hp = plan_halo(partition, local_res.plan, sig, strategy=halo)
             local_sig = tuple(
                 (nm, tuple(hp.specs[nm].local_shape), dt,
@@ -315,6 +315,11 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
                        restack_bytes=hp.restack_bytes,
                        backend=local_ex.backend,
                        refusals=[str(r) for r in partition.refusals])
+            _obs.gauge("race_shard_halo_bytes",
+                       program=result.plan.program.label,
+                       strategy=hp.strategy).set(float(
+                           hp.halo_bytes if hp.strategy == "exchange"
+                           else hp.restack_bytes))
         return ShardedRace(result, mesh, partition, hp, local_ex,
                            backend=backend, halo=halo, block_rows=block_rows,
                            block_cols=block_cols, block_inner=block_inner,

@@ -53,6 +53,13 @@ from repro.lowering.geometry import K_WINDOW, analyze_program
 
 HALO_STRATEGIES = ("auto", "exchange", "recompute")
 
+#: device-trace names (op metadata, no op of their own): the core and tail
+#: slicing of ``HaloProgram.host_args``, with the reshard into the
+#: ``shard_map`` in-specs that it causes, sits in ``SHARD_IN``; the
+#: exchange or recompute slice of ``HaloProgram.device_env`` in ``HALO``
+SHARD_IN = "race_shard_in"
+HALO = "race_halo"
+
 #: ArraySpec.mode values
 M_SLAB = "slab"  # sliced along >=1 sharded dim, halo-extended
 M_REPLICATED = "replicated"  # passed whole to every shard
@@ -266,20 +273,22 @@ class HaloProgram:
     def host_args(self, env) -> dict:
         """Pre-shard_map argument pytree (traceable; runs under the outer
         jit).  Canvas entries never cross the boundary."""
+        import jax
         import jax.numpy as jnp
 
         args = {}
-        for nm, spec in self.specs.items():
-            if spec.mode == M_CANVAS:
-                continue
-            if spec.mode in (M_SCALAR, M_REPLICATED):
-                args[nm] = jnp.asarray(env[nm])
-                continue
-            arr = jnp.asarray(env[nm])
-            if self.strategy == "recompute":
-                args[nm] = arr  # replicated whole; sliced device-side
-            else:
-                args[nm] = self._host_blocks(arr, spec)
+        with jax.named_scope(SHARD_IN):
+            for nm, spec in self.specs.items():
+                if spec.mode == M_CANVAS:
+                    continue
+                if spec.mode in (M_SCALAR, M_REPLICATED):
+                    args[nm] = jnp.asarray(env[nm])
+                    continue
+                arr = jnp.asarray(env[nm])
+                if self.strategy == "recompute":
+                    args[nm] = arr  # replicated whole; sliced device-side
+                else:
+                    args[nm] = self._host_blocks(arr, spec)
         return args
 
     def _host_blocks(self, arr, spec: ArraySpec) -> dict:
@@ -302,6 +311,7 @@ class HaloProgram:
 
     def device_env(self, args) -> dict:
         """Runs *inside* shard_map: assemble the local executor env."""
+        import jax
         import jax.numpy as jnp
         import numpy as np
 
@@ -311,10 +321,11 @@ class HaloProgram:
                 env[nm] = jnp.zeros(spec.local_shape, np.dtype(spec.dtype))
             elif spec.mode in (M_SCALAR, M_REPLICATED):
                 env[nm] = args[nm]
-            elif self.strategy == "recompute":
-                env[nm] = self._device_slice(args[nm], spec)
             else:
-                env[nm] = self._device_exchange(args[nm], spec)
+                carve = (self._device_slice if self.strategy == "recompute"
+                         else self._device_exchange)
+                with jax.named_scope(HALO):
+                    env[nm] = carve(args[nm], spec)
         return env
 
     @staticmethod

@@ -22,6 +22,7 @@ Four contracts under test:
 The subprocess pattern follows ``test_grad_sync.py``: device-count flags
 are process-global in XLA, and tier-1 must keep seeing one device.
 """
+import json
 import os
 import subprocess
 import sys
@@ -379,7 +380,7 @@ def test_shard_plan_span_and_event():
     res.run_sharded(dict(env), mesh)
     spans = obs.span_summary()
     assert spans.get("shard_plan", {}).get("count", 0) >= 1
-    assert spans.get("halo_exchange", {}).get("count", 0) >= 1
+    assert spans.get("halo_plan", {}).get("count", 0) >= 1
     evs = obs.events("shard_plan")
     assert evs
     ev = evs[-1]
@@ -471,12 +472,89 @@ print("OK sharded", sharded, "refused", len(REFUSED))
 """
 
 
-def test_forced_4device_registry_sweep_subprocess():
+def _run_forced(code: str) -> str:
+    """Run ``code`` in a fresh interpreter (it forces its own host device
+    count); its stdout."""
     r = subprocess.run(
-        [sys.executable, "-c", _SWEEP], capture_output=True, text=True,
+        [sys.executable, "-c", code], capture_output=True, text=True,
         env={"PYTHONPATH": str(SRC), "PATH": "/usr/bin:/bin",
              "HOME": os.environ.get("HOME", ""),
              "JAX_COMPILATION_CACHE_DIR":
                  os.environ["JAX_COMPILATION_CACHE_DIR"]}, timeout=540)
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
-    assert "OK sharded 15 refused 4" in r.stdout
+    return r.stdout
+
+
+def test_forced_4device_registry_sweep_subprocess():
+    assert "OK sharded 15 refused 4" in _run_forced(_SWEEP)
+
+
+# ---------------------------------------------------------------------------
+# named halo work and modelled halo bytes: forced 4-device host mesh
+# ---------------------------------------------------------------------------
+
+_NAMED = r"""
+import json, os, re
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["RACE_OBS"] = "1"
+import numpy as np
+from repro import obs
+from repro.apps.paper_kernels import get_case
+from repro.core.executor import ExecutorCache
+from repro.core.race import race
+from repro.launch.mesh import make_stencil_mesh
+from repro.shard import compile_sharded
+from repro.testing.differential import build_env
+
+assert obs.enabled()
+mesh = make_stencil_mesh(4, ("sx", "sy"))
+case = get_case("resid", 10)
+res = race(case.program, reassociate=case.reassociate, mesh=mesh)
+env = build_env(case, np.float32, seed=0)
+ex = compile_sharded(res, env, mesh, cache=ExecutorCache(4))
+text = ex._jit.lower(dict(env)).compile().as_text()
+ops = []
+for line in text.splitlines():
+    m = re.search(r"=\s*\S+\s+([a-z][a-z-]*)\(.*op_name=\"([^\"]*)\"", line)
+    if m:
+        ops.append(m.groups())
+info = ex.cache_info()
+print(json.dumps(dict(
+    ops=ops, info={k: info[k] for k in ("strategy", "halo_bytes")},
+    label=res.plan.program.label, gauges=obs.snapshot()["gauges"])))
+"""
+
+
+@pytest.fixture(scope="module")
+def named_4device():
+    """One sharded MG resid executor over a forced 2x2 host mesh with
+    ``RACE_OBS=1``: its compiled HLO's ops (opcode, metadata op_name), its
+    ``cache_info()`` and the obs gauges."""
+    return json.loads(_run_forced(_NAMED).strip().splitlines()[-1])
+
+
+def test_halo_work_named_on_hlo(named_4device):
+    """The exchange's collective permutes carry ``race_halo``; the core and
+    tail slices before ``shard_map`` carry ``race_shard_in``."""
+    from repro.shard.halo import HALO, SHARD_IN
+
+    ops = named_4device["ops"]
+    assert named_4device["info"]["strategy"] == "exchange"
+    exchange = [n for op, n in ops if n.endswith("/ppermute")]
+    assert exchange and all(f"/{HALO}/" in n for n in exchange)
+    assert any(op.startswith("collective-permute") and f"/{HALO}/" in n
+               for op, n in ops)
+    shard_in = [n for _op, n in ops if f"/{SHARD_IN}/" in n]
+    assert shard_in and all("shard_map" not in n for n in shard_in)
+    assert any(n.endswith(f"/{SHARD_IN}/slice") for n in shard_in)
+
+
+def test_shard_halo_bytes_gauge(named_4device):
+    """Building the executor sets ``race_shard_halo_bytes`` to the halo
+    bytes per call it models for the strategy it chose."""
+    info = named_4device["info"]
+    key = (f"race_shard_halo_bytes{{program={named_4device['label']},"
+           f"strategy={info['strategy']}}}")
+    assert info["halo_bytes"] > 0
+    assert named_4device["gauges"][key] == info["halo_bytes"]
