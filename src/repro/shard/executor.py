@@ -38,7 +38,7 @@ from typing import Mapping, Optional, Union
 from repro import obs as _obs
 from repro.core.ir import Loop, Program
 
-from .halo import plan_halo
+from .halo import L_SHARD, plan_halo
 from .partition import plan_partition
 
 
@@ -216,6 +216,7 @@ class ShardedRace:
         return dict(backend=self.backend, calls=self.calls,
                     trace_count=self.trace_count, strategy=hp.strategy,
                     halo_bytes=hp.halo_bytes, restack_bytes=hp.restack_bytes,
+                    in_layout=dict(hp.in_layout),
                     partition=self.partition.key(),
                     mesh=self.partition.mesh_axes,
                     local=self.local.cache_info())
@@ -313,6 +314,7 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
                        partition=str(partition.key()),
                        strategy=hp.strategy, halo_bytes=hp.halo_bytes,
                        restack_bytes=hp.restack_bytes,
+                       in_layout=dict(hp.in_layout),
                        backend=local_ex.backend,
                        refusals=[str(r) for r in partition.refusals])
             _obs.gauge("race_shard_halo_bytes",
@@ -320,6 +322,10 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
                        strategy=hp.strategy).set(float(
                            hp.halo_bytes if hp.strategy == "exchange"
                            else hp.restack_bytes))
+            for nm, layout in hp.in_layout.items():
+                _obs.gauge("race_shard_in_as_laid",
+                           program=result.plan.program.label,
+                           array=nm).set(float(layout == L_SHARD))
         return ShardedRace(result, mesh, partition, hp, local_ex,
                            backend=backend, halo=halo, block_rows=block_rows,
                            block_cols=block_cols, block_inner=block_inner,

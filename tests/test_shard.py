@@ -15,9 +15,12 @@ Four contracts under test:
     float64 round-off on a forced multi-device host mesh (subprocess, so
     the ``--xla_force_host_platform_device_count`` flag never leaks into
     this process), for *both* halo strategies, across the whole
-    ``paper_kernels`` registry and through ``jax.grad``;
+    ``paper_kernels`` registry and through ``jax.grad``, under both
+    exchange in-layouts (``"shard"``: the array as it lies, edge planes
+    exchanged; ``"core_tail"``), each where the engagement rule puts it;
   * **observability** — sharded runs/refusals emit their spans, counters
-    and structured events.
+    and structured events, and the compiled exchange moves exactly the
+    bytes the halo program models.
 
 The subprocess pattern follows ``test_grad_sync.py``: device-count flags
 are process-global in XLA, and tier-1 must keep seeing one device.
@@ -403,11 +406,28 @@ import jax.numpy as jnp
 from repro.apps.paper_kernels import get_case
 from repro.core.race import race
 from repro.launch.mesh import make_stencil_mesh
-from repro.shard import ShardingUnavailable
+from repro.shard import ShardingUnavailable, compile_sharded
 from repro.testing.differential import build_env
 
 assert jax.device_count() == 4, jax.device_count()
 mesh = make_stencil_mesh(4, ("sx", "sy"))
+
+
+def rule(spec):
+    # the in-layout the engagement rule gives a slab operand's shape:
+    # "shard" when along every sharded dim the shards divide the extent N
+    # and each slab lies in its shard and the two neighbours' (kL, kR <= S)
+    for sd in spec.slabs:
+        n = spec.shape[sd.dim]
+        size = n // sd.shards
+        k_left = max(0, (sd.shards - 1) * (size - sd.chunk))
+        k_right = max(0, sd.chunk + sd.halo - size)
+        if n % sd.shards or k_left > size or k_right > size:
+            return "core_tail"
+    return "shard"
+
+
+seen = {"shard": 0, "core_tail": 0}
 
 # every registry case at a mesh-divisible size; refusals are pinned
 SWEEP = [("poisson", 10), ("j3d27pt", 10), ("diffusion1", 10),
@@ -429,12 +449,22 @@ for nm, n in SWEEP:
     base = {k: np.asarray(v) for k, v in res.run(env).items()}
     scale = max(np.abs(v).max() for v in base.values())
     for strat in ("exchange", "recompute"):
-        got = res.run_sharded(dict(env), mesh, halo=strat)
+        ex = compile_sharded(res, env, mesh, halo=strat, backend="xla")
+        hp = ex.halo_prog
+        slabs = [s for s in hp.specs.values() if s.mode == "slab"]
+        assert set(hp.in_layout) == {s.name for s in slabs}, nm
+        for s in slabs:
+            want = rule(s) if strat == "exchange" else "replicated"
+            assert hp.in_layout[s.name] == want, (nm, s.name, want)
+            seen[want] = seen.get(want, 0) + (strat == "exchange")
+        got = ex(dict(env))
         err = max(float(np.abs(np.asarray(got[k]) - base[k]).max())
                   for k in base)
         assert err <= 1e-10 * scale, (nm, strat, err, scale)
     sharded += 1
 assert sharded == len(SWEEP)
+# the registry's own shapes reach both exchange in-layouts
+assert seen["shard"] and seen["core_tail"], seen
 
 for nm, n, code in REFUSED:
     case = get_case(nm, n)
@@ -489,72 +519,302 @@ def test_forced_4device_registry_sweep_subprocess():
     assert "OK sharded 15 refused 4" in _run_forced(_SWEEP)
 
 
+_MG = r"""
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+import jax
+jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.apps.paper_kernels import get_case
+from repro.core.executor import ExecutorCache
+from repro.core.race import race
+from repro.shard import compile_sharded
+from repro.testing.differential import build_env
+
+N = 18  # E = 16: 2x2 gives 9-plane shards, 4x1 cannot split N = 18 by 4
+INPUTS = {"resid": ("V", "U"), "psinv": ("U", "R")}
+LAYOUT = {(2, 2): "shard", (4, 1): "core_tail"}
+rng = np.random.default_rng(5)
+runs = 0
+for shape, layout in LAYOUT.items():
+    mesh = Mesh(np.array(jax.devices()).reshape(shape), ("sx", "sy"))
+    grid = NamedSharding(mesh, P(None, "sy", "sx"))
+    wrap = jax.jit(lambda x: jnp.pad(x, 1, mode="wrap"))
+    for name, inputs in INPUTS.items():
+        case = get_case(name, N)
+        res = race(case.program, reassociate=case.reassociate,
+                   rewrite_div=case.rewrite_div, backend="xla")
+        env = build_env(case, np.float64, seed=7)
+        # whole (N, N, N) grids, as the benchmark holds them: periodic
+        # wraps of an interior, once committed to P(None, 'sy', 'sx') the
+        # way the wrap leaves them, once replicated host arrays
+        inner = {k: rng.uniform(-1, 1, (N - 2,) * 3) for k in inputs}
+        host = {k: np.pad(v, 1, mode="wrap") for k, v in inner.items()}
+        committed = {k: wrap(jax.device_put(v, grid))
+                     for k, v in inner.items()}
+        if shape == (2, 2):
+            assert all(v.sharding.is_equivalent_to(grid, 3)
+                       for v in committed.values())
+        base = {k: np.asarray(v) for k, v in res.run({**env, **host}).items()}
+        for arrays in (committed, host):
+            got = {}
+            for strat in ("exchange", "recompute"):
+                ex = compile_sharded(res, {**env, **arrays}, mesh,
+                                     halo=strat, backend="xla",
+                                     cache=ExecutorCache(4))
+                want = layout if strat == "exchange" else "replicated"
+                assert set(ex.halo_prog.in_layout.values()) == {want}, (
+                    name, shape, strat, ex.halo_prog.in_layout)
+                got[strat] = {k: np.asarray(v)
+                              for k, v in ex({**env, **arrays}).items()}
+            for k in base:
+                # same slabs, same local executor: bitwise
+                assert np.array_equal(got["exchange"][k],
+                                      got["recompute"][k]), (name, shape, k)
+                err = np.abs(got["exchange"][k] - base[k]).max()
+                assert err <= 1e-12 * np.abs(base[k]).max(), (name, shape, k)
+            runs += 1
+print("OK mg", runs)
+"""
+
+
+def test_forced_4device_mg_in_layouts_subprocess():
+    """MG resid and psinv on whole ghost-extended grids, as the class-D
+    cell holds them: on a 2x2 mesh every slab operand crosses as laid
+    (``"shard"``), on a 4x1 mesh (N = 18 not a multiple of 4) as core and
+    tails.  Under each, with inputs committed to the in-spec sharding and
+    with replicated ones, exchange equals recompute bitwise and both equal
+    the single-device result."""
+    assert "OK mg 8" in _run_forced(_MG)
+
+
 # ---------------------------------------------------------------------------
 # named halo work and modelled halo bytes: forced 4-device host mesh
 # ---------------------------------------------------------------------------
 
 _NAMED = r"""
-import json, os, re
+import json, os
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ["RACE_OBS"] = "1"
 import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.apps.paper_kernels import get_case
 from repro.core.executor import ExecutorCache
 from repro.core.race import race
-from repro.launch.mesh import make_stencil_mesh
 from repro.shard import compile_sharded
+from repro.shard.halo import _edges
 from repro.testing.differential import build_env
 
 assert obs.enabled()
-mesh = make_stencil_mesh(4, ("sx", "sy"))
-case = get_case("resid", 10)
-res = race(case.program, reassociate=case.reassociate, mesh=mesh)
-env = build_env(case, np.float32, seed=0)
-ex = compile_sharded(res, env, mesh, cache=ExecutorCache(4))
-text = ex._jit.lower(dict(env)).compile().as_text()
-ops = []
-for line in text.splitlines():
-    m = re.search(r"=\s*\S+\s+([a-z][a-z-]*)\(.*op_name=\"([^\"]*)\"", line)
-    if m:
-        ops.append(m.groups())
-info = ex.cache_info()
-print(json.dumps(dict(
-    ops=ops, info={k: info[k] for k in ("strategy", "halo_bytes")},
-    label=res.plan.program.label, gauges=obs.snapshot()["gauges"])))
+N = 10
+out = {}
+for layout, shape in (("shard", (2, 2)), ("core_tail", (4, 1))):
+    mesh = Mesh(np.array(jax.devices()).reshape(shape), ("sx", "sy"))
+    grid = NamedSharding(mesh, P(None, "sy", "sx"))
+    wrap = jax.jit(lambda x: jnp.pad(x, 1, mode="wrap"))
+    case = get_case("resid", N)
+    res = race(case.program, reassociate=case.reassociate, mesh=mesh)
+    env = build_env(case, np.float32, seed=0)
+    rng = np.random.default_rng(0)
+    for k in ("V", "U"):  # whole grids, laid out as the benchmark's wrap
+        inner = rng.uniform(-1, 1, (N - 2,) * 3).astype(np.float32)
+        env[k] = wrap(jax.device_put(inner, grid))
+    ex = compile_sharded(res, env, mesh, cache=ExecutorCache(4))
+    hp = ex.halo_prog
+    info = ex.cache_info()
+    out[layout] = dict(
+        hlo=ex._jit.lower(dict(env)).compile().as_text(),
+        info={k: info[k] for k in ("strategy", "halo_bytes", "in_layout")},
+        mesh=dict(shape=list(shape), axes=list(mesh.axis_names)),
+        edges={nm: [[sd.dim, sd.mesh_axis, *_edges(sd, s.shape[sd.dim])]
+                    for sd in s.slabs]
+               for nm, s in hp.specs.items() if s.mode == "slab"},
+        shape=N, label=res.plan.program.label,
+        gauges=obs.snapshot()["gauges"])
+print(json.dumps(out))
 """
 
 
 @pytest.fixture(scope="module")
 def named_4device():
-    """One sharded MG resid executor over a forced 2x2 host mesh with
-    ``RACE_OBS=1``: its compiled HLO's ops (opcode, metadata op_name), its
-    ``cache_info()`` and the obs gauges."""
+    """Sharded MG resid executors over forced 4-device host meshes with
+    ``RACE_OBS=1``, one per exchange in-layout: ``"shard"`` on a 2x2 mesh,
+    ``"core_tail"`` on a 4x1 mesh, both fed whole 10^3 grids committed as
+    the benchmark's periodic wrap leaves them.  Per layout: the compiled
+    HLO text, ``cache_info()``, each slab dim's ``(dim, mesh axis, S, kL,
+    kR)`` and the obs gauges right after the build."""
     return json.loads(_run_forced(_NAMED).strip().splitlines()[-1])
 
 
-def test_halo_work_named_on_hlo(named_4device):
-    """The exchange's collective permutes carry ``race_halo``; the core and
-    tail slices before ``shard_map`` carry ``race_shard_in``."""
+def _hlo_ops(text: str) -> dict:
+    """Compiled HLO text -> ``{(computation, name): op}``, each op a dict
+    of ``comp``, ``name``, ``dims`` (array result; None for tuples),
+    ``opcode``, ``operands``, ``op_name``, ``pairs`` (collective permutes),
+    ``calls`` (fusions) and ``param`` (parameter number)."""
+    import re
+
+    ops = {}
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+) ([a-z][a-z\-]*)\(",
+                     line)
+        if not m or comp is None:
+            continue
+        name, shape, opcode = m.groups()
+        depth, i = 1, m.end()
+        while depth:
+            depth += {"(": 1, ")": -1}.get(line[i], 0)
+            i += 1
+        args = line[m.end():i - 1]
+        dims = re.match(r"[a-z]\w*\[([\d,]*)\]", shape)
+        pairs = re.search(r"source_target_pairs=\{(.*?)\}\}", line)
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        ops[(comp, name)] = dict(
+            comp=comp, name=name, opcode=opcode,
+            dims=(tuple(int(d) for d in dims.group(1).split(",") if d)
+                  if dims else None),
+            operands=re.findall(r"%([\w.\-]+)", args),
+            op_name=op_name.group(1) if op_name else "",
+            pairs=(re.findall(r"\{(\d+),(\d+)", pairs.group(1) + "}")
+                   if pairs else []),
+            calls=calls.group(1) if calls else None,
+            param=int(args) if opcode == "parameter" else None)
+    return ops
+
+
+def _upstream(ops: dict, start):
+    """Every op whose value flows into ``start``, across fusion bounds."""
+    callers = {}
+    for op in ops.values():
+        if op["calls"]:
+            callers.setdefault(op["calls"], []).append(op)
+    roots = {}
+    for (comp, name), op in ops.items():
+        roots[comp] = op  # the computation's last op is its ROOT
+    seen, stack = set(), [start]
+    while stack:
+        op = stack.pop()
+        key = (op["comp"], op["name"])
+        if key in seen:
+            continue
+        seen.add(key)
+        if op is not start:
+            yield op
+        if op["calls"]:
+            stack.append(roots[op["calls"]])
+        if op["param"] is not None:
+            stack += [ops[(c["comp"], c["operands"][op["param"]])]
+                      for c in callers.get(op["comp"], [])]
+        stack += [ops[(op["comp"], o)] for o in op["operands"]
+                  if (op["comp"], o) in ops]
+
+
+def _permutes(ops: dict, scope: str) -> list:
+    return [op for op in ops.values()
+            if op["opcode"] in ("collective-permute",
+                                "collective-permute-start")
+            and f"/{scope}/" in op["op_name"]]
+
+
+@pytest.mark.parametrize("layout", ["shard", "core_tail"])
+def test_halo_work_named_on_hlo(named_4device, layout):
+    """The exchange's collective permutes carry ``race_halo``.  As laid
+    (``"shard"``), nothing before the halo's concatenates writes a whole
+    shard and every permute moves edge planes only (at most
+    ``max(kL, kR)`` along its dim); as core and tails, the core and tail
+    slices before ``shard_map`` carry ``race_shard_in``."""
     from repro.shard.halo import HALO, SHARD_IN
 
-    ops = named_4device["ops"]
-    assert named_4device["info"]["strategy"] == "exchange"
-    exchange = [n for op, n in ops if n.endswith("/ppermute")]
+    case = named_4device[layout]
+    ops = _hlo_ops(case["hlo"])
+    assert case["info"]["strategy"] == "exchange"
+    assert set(case["info"]["in_layout"].values()) == {layout}
+    names = [op["op_name"] for op in ops.values()]
+    exchange = [n for n in names if n.endswith("/ppermute")]
     assert exchange and all(f"/{HALO}/" in n for n in exchange)
-    assert any(op.startswith("collective-permute") and f"/{HALO}/" in n
-               for op, n in ops)
-    shard_in = [n for _op, n in ops if f"/{SHARD_IN}/" in n]
-    assert shard_in and all("shard_map" not in n for n in shard_in)
-    assert any(n.endswith(f"/{SHARD_IN}/slice") for n in shard_in)
+    assert _permutes(ops, HALO)
+    shard_in = [n for n in names if f"/{SHARD_IN}/" in n]
+    assert all("shard_map" not in n for n in shard_in)
+    if layout == "core_tail":
+        assert any(n.endswith(f"/{SHARD_IN}/slice") for n in shard_in)
+        return
+    n = case["shape"]
+    whole = set()
+    for dims in case["edges"].values():
+        shard = [n, n, n]
+        for dim, _axis, size, _kl, _kr in dims:
+            shard[dim] = size
+        whole.add(tuple(shard))
+    joins = [op for op in ops.values() if op["opcode"] == "concatenate"
+             and f"/{HALO}/" in op["op_name"]]
+    assert joins
+    for join in joins:
+        for op in _upstream(ops, join):
+            if f"/{SHARD_IN}/" in op["op_name"] or f"/{HALO}/" in op[
+                    "op_name"]:
+                assert op["dims"] not in whole, (op["name"], op["dims"])
+    # a permute's mesh axis is where its source and target partitions
+    # differ; along that axis' array dim it moves at most max(kL, kR)
+    mesh_shape = case["mesh"]["shape"]
+    widest = {}
+    for dims in case["edges"].values():
+        for dim, axis, _size, k_left, k_right in dims:
+            widest[axis] = max(widest.get(axis, 0), k_left, k_right)
+            widest[axis, "dim"] = dim
+    for op in _permutes(ops, HALO):
+        src, dst = (np.unravel_index(int(i), mesh_shape)
+                    for i in op["pairs"][0])
+        (ax,) = [i for i in range(len(mesh_shape)) if src[i] != dst[i]]
+        axis = case["mesh"]["axes"][ax]
+        assert op["dims"][widest[axis, "dim"]] <= widest[axis], op
 
 
-def test_shard_halo_bytes_gauge(named_4device):
+@pytest.mark.parametrize("layout", ["shard", "core_tail"])
+def test_halo_bytes_mirror_hlo(named_4device, layout):
+    """The modelled halo bytes are the compiled exchange's: each permute
+    under ``race_halo`` moves its result on every source-target pair, and
+    the sum is ``cache_info()["halo_bytes"]``.  As laid, those are the only
+    permutes: no reshard crosses a link."""
+    from repro.shard.halo import HALO
+
+    case = named_4device[layout]
+    ops = _hlo_ops(case["hlo"])
+    moved = sum(4 * int(np.prod(op["dims"])) * len(op["pairs"])
+                for op in _permutes(ops, HALO))
+    assert moved == case["info"]["halo_bytes"] > 0
+    if layout == "shard":
+        every = [op for op in ops.values()
+                 if op["opcode"].startswith("collective-permute")
+                 and not op["opcode"].endswith("-done")]
+        assert len(every) == len(_permutes(ops, HALO))
+
+
+@pytest.mark.parametrize("layout", ["shard", "core_tail"])
+def test_shard_halo_bytes_gauge(named_4device, layout):
     """Building the executor sets ``race_shard_halo_bytes`` to the halo
-    bytes per call it models for the strategy it chose."""
-    info = named_4device["info"]
-    key = (f"race_shard_halo_bytes{{program={named_4device['label']},"
+    bytes per call it models for the strategy it chose, and
+    ``race_shard_in_as_laid`` per slab operand to 1 where it crosses as
+    laid and 0 where it crosses as core and tails."""
+    case = named_4device[layout]
+    info, gauges, label = case["info"], case["gauges"], case["label"]
+    key = (f"race_shard_halo_bytes{{program={label},"
            f"strategy={info['strategy']}}}")
     assert info["halo_bytes"] > 0
-    assert named_4device["gauges"][key] == info["halo_bytes"]
+    assert gauges[key] == info["halo_bytes"]
+    assert set(info["in_layout"]) == {"U", "V"}
+    for nm, got in info["in_layout"].items():
+        key = f"race_shard_in_as_laid{{array={nm},program={label}}}"
+        assert got == layout
+        assert gauges[key] == float(layout == "shard")
