@@ -61,7 +61,7 @@ def baseline_truth(case, res, envs: list) -> list:
     import jax
     import numpy as np
 
-    from repro.kernels.ref import interior
+    from repro.core.codegen import interior
 
     run = jax.jit(lambda e: interior(res.plan, res.baseline_evaluator()(e)))
     cpu = jax.devices("cpu")[0]

@@ -4,8 +4,9 @@
 
 Takes the 27-point Jacobi stencil (paper Table 1 'j3d27pt'), runs RACE, then
 executes the optimized plan three ways — XLA baseline, XLA RACE evaluator,
-and the blocked Pallas kernel (interpreted on CPU, compiled on a TPU) —
-validating they agree and reporting op counts and wall-clock.
+and the blocked Pallas kernel through ``compile_plan(..., "pallas")``
+(interpreted on CPU, compiled on a TPU) — validating they agree and
+reporting op counts and wall-clock.
 
 Two entry paths are demonstrated:
   * the internal DSL (``repro.core.ir`` builders, as in ``paper_kernels``);
@@ -27,10 +28,10 @@ import jax
 from repro.apps import frontend_kernels
 from repro.apps.paper_kernels import stencil_j3d27pt
 from repro.core.codegen import required_shapes
+from repro.core.executor import compile_plan
 from repro.core.race import race
 from repro.frontend import race_kernel
-from repro.kernels import ref as kref
-from repro.kernels.ops import race_stencil
+from repro.testing import reference, reference_plan
 
 
 def main():
@@ -61,10 +62,10 @@ def main():
 
     t_base, t_opt = bench(base_fn, env), bench(opt_fn, env)
     t0 = time.perf_counter()
-    pallas_out = race_stencil(res, env, block_rows=8)
+    pallas_out = compile_plan(res.plan, env, "pallas").run(env)
     t_pal = time.perf_counter() - t0
 
-    want = kref.reference(res.plan, env)
+    want = reference(res.plan, env)
     for k in want:
         np.testing.assert_allclose(np.asarray(pallas_out[k]),
                                    np.asarray(want[k]), rtol=2e-4, atol=2e-4)
@@ -84,7 +85,7 @@ def main():
     t_fe = time.perf_counter() - t0
     fe_res = kern.trace({nm: np.shape(v) for nm, v in env.items()})
     assert fe_res.program == case.program, "frontend/DSL divergence"
-    want_fe = kref.reference_plan(fe_res.plan, env)  # interior convention
+    want_fe = reference_plan(fe_res.plan, env)  # interior convention
     # kern.run is the jitted executor path; XLA fusion reorders f32 rounding
     # relative to the eager oracle, so compare at same-plan f32 tolerance
     np.testing.assert_allclose(np.asarray(fe_out["j27"]),

@@ -207,6 +207,21 @@ def build_plan_evaluator(plan: Plan):
     return run
 
 
+def interior(plan: Plan, full_outputs: dict) -> dict:
+    """Slice evaluator outputs (full-array layout) down to the statement
+    ranges, matching the Pallas kernel's return convention."""
+    ranges = plan.program.ranges()
+    out = {}
+    for st in plan.body:
+        arr = full_outputs[st.lhs.name]
+        sl = []
+        for s in st.lhs.subs:
+            lo, hi = ranges[s.s]
+            sl.append(slice(lo + int(s.b), hi + int(s.b) + 1))
+        out[st.lhs.name] = jnp.asarray(arr)[tuple(sl)]
+    return out
+
+
 def build_baseline_evaluator(program: Program):
     """Evaluator for the unmodified program (same machinery, no auxs)."""
     full = program.ranges()

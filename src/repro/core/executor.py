@@ -26,7 +26,7 @@ Layers:
   * :class:`ExecutorCache` — thread-safe process-wide LRU with hit/miss/
     eviction stats; :func:`compile_plan` is the front door every consumer
     (``RaceResult.run``, the ``@race_kernel`` frontend, the differential
-    harness, the benchmarks) goes through.
+    harness, the serve runtime, the benchmark) goes through.
 
 Zero-retrace guarantee: a second ``run()`` with the same signature is a
 cache hit returning the *same* ``CompiledRace``, whose jitted callable hits
@@ -383,9 +383,7 @@ class CompiledRace:
                     block_inner=block_inner)
                 core = self.spec.apply
             else:
-                from repro.kernels.ref import interior
-
-                from .codegen import build_plan_evaluator
+                from .codegen import build_plan_evaluator, interior
 
                 self.spec = None
                 plan_run = build_plan_evaluator(plan)

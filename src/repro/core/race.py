@@ -286,7 +286,7 @@ class RaceResult:
         metrics series and decision events carrying this plan's hash.
 
         This is the per-result view of the process-wide telemetry in
-        :mod:`repro.obs`; serving dashboards and the benchmarks read it
+        :mod:`repro.obs`; serving dashboards and the benchmark read it
         instead of poking at internals."""
         from .executor import executor_cache, plan_hash, program_hash
 

@@ -111,9 +111,6 @@ def serve_case(args) -> None:
           f"p50={row['p50_us']}us p95={row['p95_us']}us "
           f"batches={row['batches']} coalesced={row['coalesced']} "
           f"compile_cache={cc_state}")
-    from repro.obs.history import append_rows
-
-    append_rows("serve", [row], doc["stamp"])
     from repro import obs
 
     if obs.enabled():
@@ -203,11 +200,6 @@ def decode_arch(args) -> None:
             with open(args.json, "w") as f:
                 f.write(out + "\n")
             print(f"wrote {args.json}")
-        # decode-latency trajectory: one history row per serve run, keyed
-        # like the benchmark sections (no-op without $RACE_BENCH_HISTORY)
-        from repro.obs.history import append_rows
-
-        append_rows("serve", [doc], doc["stamp"])
     else:
         print(json.dumps(doc))
 

@@ -2,9 +2,8 @@
 
 The paper's claim is that hash-based redundancy detection is
 *pattern-agnostic*; this package makes the fast execution path equally so.
-It replaces the former 2-D/3-D special-case kernel
-(``repro.kernels.race_stencil``, now a compatibility shim) with per-concern
-modules generic over nest depth and window shape:
+It is organised as per-concern modules generic over nest depth and window
+shape:
 
   * :mod:`repro.lowering.facts`    — structured fallback reasons / lowering
     facts shared with the capability probe (pure data);
@@ -21,7 +20,7 @@ modules generic over nest depth and window shape:
     mode is chosen: compiled on a TPU, interpreted on the CPU backend.
 
 Importing ``repro.lowering`` itself stays jax-free: the emit-side symbols
-(``specialize_stencil``, ``LoweredStencil``, ``race_stencil_call``, ...)
+(``specialize_stencil``, ``LoweredStencil``, ...)
 load lazily on first access, so ``repro.core.backend`` can probe plans
 without touching Pallas.
 """
@@ -35,12 +34,11 @@ from .facts import (FALLBACK_CODES, RETIRED_CODES, R_CONSTANT_DIM, R_DEPTH,
                     FallbackReason, LoweringError, LoweringFact)
 from .geometry import (K_GATHER, K_WINDOW, ArrayInfo, LoweringAnalysis,
                        analyze_plan, analyze_program, offset_envelopes,
-                       plan_geometry, platform_reasons, program_envelopes,
-                       target_platform)
+                       platform_reasons, program_envelopes, target_platform)
 
 #: emit-side symbols resolved lazily (they import jax + Pallas)
-_EMIT = ("LoweredStencil", "StencilSpec", "specialize_stencil",
-         "pallas_interpret", "race_stencil_call", "build_kernel")
+_EMIT = ("LoweredStencil", "specialize_stencil", "pallas_interpret",
+         "build_kernel")
 _BLOCKS = ("ArrayPrep", "Layout", "build_layout", "level_blocks")
 _GATHER = ("gather_ref",)
 
@@ -53,8 +51,7 @@ __all__ = [
     "FallbackReason", "LoweringError", "LoweringFact",
     "K_GATHER", "K_WINDOW", "ArrayInfo", "LoweringAnalysis",
     "analyze_plan", "analyze_program", "offset_envelopes",
-    "plan_geometry", "platform_reasons", "program_envelopes",
-    "target_platform",
+    "platform_reasons", "program_envelopes", "target_platform",
     *_EMIT, *_BLOCKS, *_GATHER,
 ]
 

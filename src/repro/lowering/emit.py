@@ -27,15 +27,12 @@ once — analysis, layout, BlockSpecs, grid, kernel closure, the
 ``pl.pallas_call`` construction itself — and returns a
 :class:`LoweredStencil` whose ``apply(env)`` is the pure per-call data path
 (transpose/flip/pad/slice/pallas_call/unpad), fully ``jax.jit``-traceable
-and ``jax.vmap``-batchable.  ``race_stencil_call`` keeps the historical
-one-shot signature by chaining the two.
+and ``jax.vmap``-batchable.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Optional
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -242,10 +239,6 @@ class LoweredStencil:
     __call__ = apply
 
 
-#: historical name (pre-engine API); kept for the compatibility shim
-StencilSpec = LoweredStencil
-
-
 def pallas_interpret() -> bool:
     """The Pallas mode, chosen from the platform kernels run on: compiled on
     a TPU, interpreted on the CPU backend.  Any other platform has no mode
@@ -310,23 +303,3 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
                           lift=layout.lift,
                           read_factor=layout.read_factor,
                           analysis=analysis, _call=call)
-
-
-def race_stencil_call(plan: Plan, env: dict, block_rows: int = 8,
-                      block_cols: int = 8, block_inner: int = 0):
-    """One-shot execution: specialize for ``env``'s signature, then apply.
-
-    env maps base array names -> arrays (laid out as in the program) and
-    scalar names -> scalars.  Returns {output name: interior array} shaped by
-    the statement ranges (level-major layout transposed back to each output's
-    own dim order).  Steady-state callers should go through
-    ``repro.core.executor``, which caches the specialization."""
-    from repro.core.executor import dtype_of
-
-    spec = specialize_stencil(
-        plan,
-        {nm: np.shape(v) for nm, v in env.items()},
-        {nm: dtype_of(v) for nm, v in env.items()},
-        block_rows=block_rows, block_cols=block_cols,
-        block_inner=block_inner)
-    return spec.apply(env)

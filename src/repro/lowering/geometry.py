@@ -45,7 +45,7 @@ from .facts import (R_CONSTANT_DIM, R_DEPTH, R_FRACTIONAL_OFFSET,
                     R_NEGATIVE_COEF, R_NO_BASE_ARRAY, R_PLATFORM,
                     R_REPEATED_LEVEL, R_SCALAR_AUX, R_STRIDED_AUX,
                     R_TPU_GATHER, R_TPU_STRIDED, R_ZERO_COEF, FallbackReason,
-                    LoweringError, LoweringFact)
+                    LoweringFact)
 
 #: array classification (ArrayInfo.kind)
 K_WINDOW = "window"  # blocked halo-exchange windows (the fast path)
@@ -451,33 +451,3 @@ def ref_affine(ref: Ref) -> dict:
             raise ValueError("fractional offsets unsupported")
         info[s.s] = (s.a, b)
     return info
-
-
-def plan_geometry(plan: Plan):
-    """Back-compat wrapper for the pre-engine ``plan_geometry`` API.
-
-    Returns the historical ``(ext, perms, levels_of, coefs, pad_in)`` tuple
-    for plans whose arrays are all positive-stride window class; raises
-    :class:`LoweringError` (a ``ValueError``) otherwise, like the old code
-    raised on anything outside the 2-D/3-D positive-coefficient envelope.
-    New code should call :func:`analyze_plan` instead.
-    """
-    a = analyze_plan(plan)
-    if not a.eligible:
-        raise LoweringError(a.reasons)
-    bad = [i for i in a.arrays.values()
-           if i.kind != K_WINDOW or i.mirrored_levels]
-    if bad:
-        raise LoweringError(
-            (), f"arrays {sorted(i.name for i in bad)} need the gather or "
-                f"mirrored-window mechanisms; use analyze_plan()")
-    perms = {nm: i.perm for nm, i in a.arrays.items()}
-    levels_of = {nm: i.levels for nm, i in a.arrays.items()}
-    coefs = {nm: dict(i.coefs) for nm, i in a.arrays.items()}
-    pad_in = {}
-    for nm, i in a.arrays.items():
-        p = [0] * a.depth
-        for l in i.levels:
-            p[l - 1] = max(i.off_hi[l], -i.off_lo[l], 0)
-        pad_in[nm] = tuple(p)
-    return a.ext, perms, levels_of, coefs, pad_in

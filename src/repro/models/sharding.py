@@ -14,11 +14,11 @@ from __future__ import annotations
 
 from typing import Optional
 
-import numpy as np
-
 import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
+
+from repro.shard.partition import divides
 
 from .config import ModelConfig
 
@@ -30,25 +30,6 @@ def batch_axes(mesh) -> tuple:
 def fsdp_axes(mesh) -> tuple:
     # FSDP shards params over the data axes; 'model' already shards via TP
     return batch_axes(mesh)
-
-
-def _axsize(mesh, axes) -> int:
-    if axes is None:
-        return 1
-    if isinstance(axes, str):
-        axes = (axes,)
-    return int(np.prod([mesh.shape[a] for a in axes]))
-
-
-def divides(mesh, dim: int, axes) -> bool:
-    """Public face of the ``_fit`` divisibility guard: True when ``dim`` is
-    positive and the total size of ``axes`` over ``mesh`` divides it evenly.
-
-    The sharded-execution partitioner (``repro.shard.partition``) applies the
-    same rule to grid-level *extents* that ``_fit`` applies to tensor dims:
-    a mesh axis only lands on a dimension it divides.
-    """
-    return dim > 0 and dim % _axsize(mesh, axes) == 0
 
 
 def _fit(mesh, shape, spec) -> P:

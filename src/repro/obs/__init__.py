@@ -247,12 +247,8 @@ def render_json(label_filter=None) -> str:
 def run_stamp() -> dict:
     """Identity stamp for machine-readable artifacts: schema version, UTC
     timestamp, device/backend string, jax version, and the host signature
-    (CPU count + node name).  Shared by ``obs.dump``, every
-    ``BENCH_*.json``, and ``launch/serve.py --json`` so artifact
-    trajectories are diffable across runs and machines — the benchmark
-    history store (:mod:`repro.obs.history`) keys baselines on the
-    (device, jax, host_cpu_count) triple, so numbers from a 1-core CI
-    container never gate against a 96-core workstation's."""
+    (CPU count + node name).  Shared by ``obs.dump`` and
+    ``launch/serve.py --json`` so artifacts say where they were made."""
     import datetime
     import platform
 

@@ -216,8 +216,7 @@ class Registry:
 
     def span_summary(self) -> dict:
         """Aggregate of the ``race_span_seconds`` histograms by leaf span
-        name: ``{span: {"count": n, "total_s": s}}`` — the compact breakdown
-        benchmarks annotate their rows with."""
+        name: ``{span: {"count": n, "total_s": s}}``."""
         agg: dict = {}
         for (name, labels), m in self._items(self._histograms):
             if name != "race_span_seconds":

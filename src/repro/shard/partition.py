@@ -40,7 +40,7 @@ ever.  Legality is exactly the points where that construction breaks:
 * ``shard-envelope``      — ``lo + off_lo < 0``: a chunk would read left of
   its own slab start.
 * ``shard-divisibility``  — the mesh axis size does not divide ``E``
-  (the ``models/sharding.py`` ``divides`` guard applied to grid extents).
+  (:func:`divides`).
 * ``shard-halo-exceeds-chunk`` — ``t > e``: the halo spans more than the
   immediate neighbor, so one ``ppermute`` hop cannot supply it.
 * ``shard-no-axis``       — no mesh axis could be placed on any level.
@@ -55,6 +55,7 @@ replicated over it; the plan as a whole is refused (``ok=False``) only when
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 from repro.lowering.geometry import K_GATHER, K_WINDOW, analyze_program
@@ -73,6 +74,14 @@ SHARD_REFUSAL_CODES = frozenset({
     S_GEOMETRY, S_MIRRORED, S_STRIDED, S_GATHER, S_ENVELOPE,
     S_DIVISIBILITY, S_HALO, S_NO_AXIS,
 })
+
+
+def divides(mesh, dim: int, axes) -> bool:
+    """True when ``dim`` is positive and the total size of ``axes`` (one
+    mesh axis name or a tuple of them) over ``mesh`` divides it evenly: a
+    mesh axis only lands on a dimension it divides."""
+    names = (axes,) if isinstance(axes, str) else tuple(axes)
+    return dim > 0 and dim % math.prod(int(mesh.shape[a]) for a in names) == 0
 
 
 @dataclass(frozen=True)
@@ -205,8 +214,6 @@ def plan_partition(program, mesh) -> PartitionPlan:
             False, (), (ShardRefusal(
                 S_GEOMETRY, f"program has no offset envelopes ({why})"),),
             (), mesh_axes)
-
-    from repro.models.sharding import divides  # deferred: pulls jax
 
     verdicts = _level_verdicts(analysis, program.ranges())
     refusals = [r for v in verdicts for r in v.refusals]

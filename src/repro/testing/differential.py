@@ -32,9 +32,10 @@ import numpy as np
 
 from repro.apps.paper_kernels import CASES, get_case
 from repro.core.backend import select_backend
-from repro.core.codegen import required_shapes
+from repro.core.codegen import (build_baseline_evaluator,
+                                build_plan_evaluator, interior,
+                                required_shapes)
 from repro.core.race import race
-from repro.kernels.ref import interior
 
 #: grid sizes keeping a full CPU interpret-mode sweep under a minute
 SWEEP_SIZES = {
@@ -76,6 +77,18 @@ def build_env(case, dtype=np.float32, seed: int = 0) -> dict:
         else:
             env[nm] = rng.uniform(-1, 1, shp).astype(dtype)
     return env
+
+
+def reference(plan, env: dict) -> dict:
+    """Oracle: the *baseline* program (ground-truth semantics), restricted
+    to the statement interior every executor returns."""
+    return interior(plan, build_baseline_evaluator(plan.program)(env))
+
+
+def reference_plan(plan, env: dict) -> dict:
+    """Secondary oracle: the transformed-program evaluator (checks that a
+    kernel agrees with the XLA realization of the same plan)."""
+    return interior(plan, build_plan_evaluator(plan)(env))
 
 
 @dataclass
@@ -159,7 +172,7 @@ def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
     report = CaseReport(case.name)
 
     base_res = race(case.program)  # plan only used for its program/interior
-    truth = interior(base_res.plan, base_res.baseline_evaluator()(env))
+    truth = reference(base_res.plan, env)
 
     for lvl in reassociate_levels:
         res = race(case.program, reassociate=lvl,

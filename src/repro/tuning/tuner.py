@@ -11,8 +11,7 @@ machine and every later process reuses the decision with zero re-measurement.
 Selection is conservative by construction: the static default config is
 always part of the space, and the winner must beat it by more than
 ``noise_margin`` or the default is kept — a tuned selection is never slower
-than the static default up to measurement noise (pinned by tests and the
-``benchmarks/tuning.py`` sweep).
+than the static default up to measurement noise (pinned by tests).
 """
 from __future__ import annotations
 

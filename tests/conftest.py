@@ -85,9 +85,6 @@ def _isolated_obs(monkeypatch):
     monkeypatch.delenv(obs.ENV_EVENTS, raising=False)
     monkeypatch.delenv(obs.ENV_RING, raising=False)
     monkeypatch.delenv(obs.ENV_SPANS, raising=False)
-    # the benchmark history is persistent cross-run state exactly like the
-    # tuning store: tests must never read or grow the developer's file
-    monkeypatch.delenv("RACE_BENCH_HISTORY", raising=False)
     obs.reset()
     yield
     obs.reset()

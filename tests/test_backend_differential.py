@@ -15,8 +15,8 @@ from repro.core.backend import (R_MIXED_STRIDE, R_PLATFORM, R_TPU_GATHER,
                                 probe_pallas, select_backend)
 from repro.core.ir import arr, loopnest, program
 from repro.core.race import race
-from repro.kernels.ref import reference
-from repro.testing import build_env, coverage_matrix, run_case, sweep_registry
+from repro.testing import (build_env, coverage_matrix, reference, run_case,
+                           sweep_registry)
 from repro.testing.differential import SWEEP_SIZES
 
 pytestmark = pytest.mark.pallas

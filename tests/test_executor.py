@@ -16,7 +16,7 @@ from repro.core.executor import (CompiledRace, ExecutorCache, compile_plan,
                                  plan_fingerprint, plan_hash)
 from repro.core.ir import Scalar, arr, loopnest, mul, program
 from repro.core.race import race
-from repro.testing.differential import build_env
+from repro.testing.differential import build_env, reference_plan
 
 
 @pytest.fixture(autouse=True)
@@ -153,12 +153,10 @@ def test_second_run_zero_retrace(backend):
 @pytest.mark.parametrize("backend", ["xla", pytest.param(
     "pallas", marks=pytest.mark.pallas)])
 def test_executor_matches_oracle(backend):
-    from repro.kernels import ref as kref
-
     case, res = _res()
     env = build_env(case)
     got = res.run(env, backend)
-    want = kref.reference_plan(res.plan, env)
+    want = reference_plan(res.plan, env)
     for k in want:
         np.testing.assert_allclose(np.asarray(got[k], np.float64),
                                    np.asarray(want[k], np.float64),
@@ -369,11 +367,11 @@ def test_scalar_only_rhs_probed_not_crashed():
 
 
 def test_scalar_only_rhs_direct_kernel_call_clear_error():
-    from repro.kernels.race_stencil import race_stencil_call
+    from repro.lowering import specialize_stencil
 
     res = _scalar_only_plan()
     with pytest.raises(ValueError, match="array operand"):
-        race_stencil_call(res.plan, {"s": np.float32(0.5)})
+        specialize_stencil(res.plan, {"s": ()}, {"s": np.dtype(np.float32)})
 
 
 def test_repeated_ref_sliced_once_per_statement():

@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.fused_ce import _ce_ref, fused_ce, fused_ce_forward
+from repro.models.fused_ce import _ce_ref, fused_ce, fused_ce_forward
 
 
 def _data(T, D, V, seed=0, dtype=jnp.float32):

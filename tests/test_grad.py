@@ -31,9 +31,9 @@ from repro.apps.paper_kernels import get_case
 from repro.core.adjoint import (ADJOINT_PREFIX, REPEATED_LEVEL, STRIDED_READ,
                                 adjoint_build, adjoint_mode,
                                 adjoint_reassociate, backward)
+from repro.core.codegen import interior
 from repro.core.executor import executor_cache, plan_hash
 from repro.core.race import race
-from repro.kernels.ref import interior
 from repro.testing.differential import (build_env, default_tolerances,
                                         run_grad_case)
 

@@ -31,12 +31,12 @@ from repro.core.detect import AuxDef, Transformed
 from repro.core.executor import clear_cache, compile_plan, dtype_of
 from repro.core.ir import Scalar, arr, loopnest, program
 from repro.core.race import race
-from repro.kernels.ref import reference, reference_plan
 from repro.lowering import (R_FRACTIONAL_OFFSET, R_INCONSISTENT_LAYOUT,
                             R_LHS_FORM, R_MIXED_STRIDE, R_NO_BASE_ARRAY,
                             R_STRIDED_AUX, R_ZERO_COEF, RETIRED_CODES,
                             LoweringError, analyze_plan, specialize_stencil)
-from repro.testing import build_env, coverage_matrix, run_case
+from repro.testing import (build_env, coverage_matrix, reference,
+                           reference_plan, run_case)
 from repro.testing.differential import SWEEP_SIZES
 
 pytestmark = [pytest.mark.pallas, pytest.mark.lowering]
@@ -452,17 +452,6 @@ def test_mirrored_case_run_backend_auto():
     for k in want:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-4, atol=1e-5)
-
-
-def test_shim_reexports():
-    """kernels.race_stencil is a thin compatibility shim over the engine."""
-    import repro.kernels.race_stencil as shim
-    import repro.lowering as lowering
-
-    assert shim.specialize_stencil is lowering.specialize_stencil
-    assert shim.race_stencil_call is lowering.race_stencil_call
-    assert shim.StencilSpec is lowering.LoweredStencil
-    assert shim.plan_geometry is lowering.plan_geometry
 
 
 def test_block_grid_generic_depths():

@@ -1,9 +1,12 @@
 """The observability layer (PR 7): metrics, spans, structured events.
 
-Three contracts under test:
+Four contracts under test:
 
   * the primitives — thread-safe registry, fixed log-bucket histograms,
     nested spans, bounded event ring + JSONL sink (degrading, never fatal);
+  * the span timeline and its Chrome-trace export (``repro.obs.trace``,
+    ``report --trace-out``) — a real pipeline's nested spans reconstruct as
+    containment-consistent "X" events, the disabled path records nothing;
   * the zero-cost disabled path — with ``RACE_OBS`` unset every
     instrumentation site is a no-op: the shared ``NOOP_SPAN``, no registry
     series, no ring entries, and no measurable per-call cost added to
@@ -31,6 +34,7 @@ from repro.core.race import race
 from repro.frontend import D_CONTROL_FLOW, CaptureError, capture
 from repro.obs import report
 from repro.obs.metrics import DEFAULT_BUCKETS, Histogram, Registry
+from repro.obs.trace import chrome_trace
 from repro.testing.differential import build_env
 from repro.tuning.measure import measure_candidate
 from repro.tuning.space import Config
@@ -565,7 +569,7 @@ def test_run_stamp_fields():
     assert st["ts"].endswith("+00:00")  # UTC
     assert ":" in st["device"]
     assert st["jax"] not in ("", None)
-    # the host signature keys benchmark-history baselines (env_key)
+    # the host signature says where an artifact was made
     assert isinstance(st["host_cpu_count"], int) and st["host_cpu_count"] >= 1
     assert st["host"]
 
@@ -716,3 +720,135 @@ def test_program_label_falls_back_to_written_arrays():
     assert named.label == "psinv"
     assert named == Program(named.loops, named.body)
     assert Program(named.loops, named.body).label == "U"
+
+
+# ---------------------------------------------------------------------------
+# span timeline + Chrome-trace export
+# ---------------------------------------------------------------------------
+
+
+#: run stamp of the hand-built trace documents below
+STAMP = {"schema": 1, "device": "cpu:TestCpu", "jax": "0.0.test",
+         "host_cpu_count": 1, "host": "test-host"}
+
+
+def test_span_records_nest_on_shared_time_axis():
+    _enable()
+    with obs.span("race"):
+        with obs.span("detect"):
+            pass
+        with obs.span("lower", plan="ab12", backend="xla"):
+            pass
+    recs = obs.span_records()
+    assert [r["name"] for r in recs] == ["detect", "lower", "race"]
+    by = {r["name"]: r for r in recs}
+    assert by["detect"]["path"] == "race/detect"
+    assert by["lower"]["labels"] == {"plan": "ab12", "backend": "xla"}
+    # children are contained in the parent on the shared ts axis
+    for child in ("detect", "lower"):
+        c, p = by[child], by["race"]
+        assert p["ts_us"] <= c["ts_us"]
+        assert c["ts_us"] + c["dur_us"] <= p["ts_us"] + p["dur_us"] + 1e-3
+    assert all(r["tid"] == recs[0]["tid"] for r in recs)
+
+
+def test_span_log_disabled_records_nothing():
+    assert not obs.enabled()
+    with obs.span("race"):
+        pass
+    assert obs.span_records() == []
+
+
+def test_span_log_is_bounded(monkeypatch):
+    monkeypatch.setenv(obs.ENV_SPANS, "4")
+    monkeypatch.setenv(obs.ENV_OBS, "1")
+    obs.reset()
+    for i in range(10):
+        with obs.span(f"s{i}"):
+            pass
+    recs = obs.span_records()
+    assert [r["name"] for r in recs] == ["s6", "s7", "s8", "s9"]
+    assert obs.span_log().dropped == 6
+
+
+def test_chrome_trace_structure_and_tolerance():
+    recs = [
+        dict(name="race", path="race", ts_us=0.0, dur_us=100.0, tid=7,
+             thread="MainThread", labels={}),
+        dict(name="detect", path="race/detect", ts_us=10.0, dur_us=20.0,
+             tid=7, thread="MainThread", labels={"plan": "ab"}),
+        {"corrupt": "record"},  # skipped, never fatal
+    ]
+    doc = chrome_trace(recs, stamp=STAMP, origin_epoch=123.0)
+    assert doc["displayTimeUnit"] == "ms"
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in xs] == ["race", "detect"]  # ts-sorted
+    assert all(e["pid"] == 1 and e["tid"] == 7 for e in xs)
+    assert xs[1]["args"] == {"path": "race/detect", "plan": "ab"}
+    meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
+    assert any(e["name"] == "thread_name"
+               and e["args"]["name"] == "MainThread" for e in meta)
+    assert doc["otherData"]["span_origin_epoch"] == 123.0
+    assert doc["otherData"]["device"] == STAMP["device"]
+    json.dumps(doc)  # loadable = serializable
+
+
+def test_pipeline_trace_reconstructs_phase_hierarchy(tmp_path, capsys):
+    """The acceptance scenario: a real detect -> lower -> compile -> dispatch
+    pipeline dumped and exported via ``report --trace-out`` yields valid
+    Chrome trace JSON whose span events carry the nesting paths."""
+    from repro.apps.paper_kernels import get_case
+    from repro.core.executor import clear_cache
+    from repro.core.race import race
+    from repro.testing.differential import build_env
+
+    _enable()
+    case = get_case("gaussian", 16)
+    res = race(case.program, reassociate=case.reassociate)
+    clear_cache()
+    env = build_env(case)
+    res.run(env, "xla")  # cold: lower + compile (inside dispatch) spans
+    res.run(env, "xla")  # steady: dispatch span
+    dump = tmp_path / "dump.json"
+    obs.dump(dump)
+    trace = tmp_path / "trace.json"
+    rc = report.main([str(dump), "--trace-out", str(trace)])
+    assert rc == 0
+    assert "trace:" in capsys.readouterr().out
+    doc = json.loads(trace.read_text())
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    names = {e["name"] for e in xs}
+    assert {"detect", "lower", "compile", "dispatch"} <= names
+    # every event's path terminates in its own leaf name (hierarchy intact)
+    for e in xs:
+        assert e["args"]["path"].split("/")[-1] == e["name"]
+        assert e["dur"] >= 0.0
+    # executor events carry the plan-hash label for click-through
+    lower = next(e for e in xs if e["name"] == "lower")
+    assert lower["args"]["plan"]
+    # telemetry() scopes the same records to one plan
+    tel = res.telemetry()
+    assert tel["spans"] and all(
+        s["labels"]["plan"] == tel["plan"] for s in tel["spans"])
+
+
+def test_report_trace_out_without_spans_exits_2(tmp_path, capsys):
+    dump = tmp_path / "d.json"
+    dump.write_text(json.dumps({"metrics": {}, "events": []}))
+    rc = report.main([str(dump), "--trace-out", str(tmp_path / "t.json")])
+    assert rc == 2
+    assert "NO SPAN RECORDS" in capsys.readouterr().err
+    assert not (tmp_path / "t.json").exists()
+
+
+def test_require_spans_failure_prints_timing_context(tmp_path, capsys):
+    _enable()
+    with obs.span("detect"):
+        pass
+    dump = tmp_path / "d.json"
+    obs.dump(dump)
+    rc = report.main([str(dump), "--require-spans", "lower"])
+    assert rc == 2
+    err = capsys.readouterr().err
+    assert "MISSING SPANS: lower" in err
+    assert "recorded spans" in err and "detect" in err and "p95" in err

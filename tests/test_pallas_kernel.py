@@ -1,5 +1,5 @@
-"""Pallas RACE-stencil kernel vs the pure-jnp oracle: shape/dtype sweeps in
-interpret mode (assignment requirement c)."""
+"""Pallas RACE-stencil kernel, run through ``compile_plan(..., "pallas")``,
+vs the pure-jnp oracles: shape/dtype sweeps in interpret mode."""
 import numpy as np
 import pytest
 
@@ -9,9 +9,9 @@ from repro.apps.paper_kernels import (get_case, pop_calc_tpoints,
                                       stencil_gaussian, stencil_j3d27pt,
                                       stencil_poisson)
 from repro.core.codegen import required_shapes
+from repro.core.executor import compile_plan
 from repro.core.race import race
-from repro.kernels import ref as kref
-from repro.kernels.ops import race_stencil
+from repro.testing import reference, reference_plan
 
 
 def _env(case, dtype, seed=0):
@@ -29,15 +29,15 @@ def _run(case, dtype=np.float32, block_rows=8, reassociate=None, rtol=None):
     res = race(case.program,
                reassociate=case.reassociate if reassociate is None else reassociate)
     env = _env(case, dtype)
-    got = race_stencil(res, env, block_rows=block_rows)
-    want = kref.reference(res.plan, env)
+    got = compile_plan(res.plan, env, "pallas", block_rows=block_rows).run(env)
+    want = reference(res.plan, env)
     rtol = rtol or (2e-2 if dtype == np.float16 else 2e-4)
     for k in want:
         np.testing.assert_allclose(
             np.asarray(got[k], np.float64), np.asarray(want[k], np.float64),
             rtol=rtol, atol=rtol, err_msg=k)
     # also agree with the XLA realization of the same plan (tight: same order)
-    want2 = kref.reference_plan(res.plan, env)
+    want2 = reference_plan(res.plan, env)
     for k in want2:
         np.testing.assert_allclose(
             np.asarray(got[k], np.float64), np.asarray(want2[k], np.float64),
@@ -82,11 +82,8 @@ def test_vmem_contraction_no_hbm_aux():
     assert res.n_aux_materialized() > 0  # plan does have auxs...
     import jax
 
-    from repro.kernels.race_stencil import race_stencil_call
-
     env = _env(case, np.float32)
-    lowered = jax.jit(
-        lambda e: race_stencil_call(res.plan, e)).lower(env)
-    txt = lowered.as_text()
+    ex = compile_plan(res.plan, env, "pallas")
+    txt = jax.jit(ex.core_fn).lower(env).as_text()
     for aux in res.plan.aux_order:
         assert f"{aux.name}" not in txt  # ...but none ever named in HLO I/O

@@ -8,6 +8,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.apps.paper_kernels import TABLE1_ORDER
+
 REPO = Path(__file__).resolve().parents[1]
 
 
@@ -105,17 +107,129 @@ def test_quantized_kv_decode_close_to_exact():
     assert (outs[True].argmax(-1) == outs[False].argmax(-1)).all()
 
 
+def variants(case) -> dict:
+    """``{tag: RaceResult}`` for RACE-NR, ESR+ and full RACE.  Full RACE
+    takes whichever of the case's own reassociation level, levels 3 and 4,
+    and NR has the least static ``weighted_total``."""
+    from repro.core.race import race
+
+    out = {"RACE-NR": race(case.program)}
+    out["ESR+"] = race(case.program, reassociate=3, esr=True)
+    cands = [race(case.program, reassociate=lvl, rewrite_div=case.rewrite_div)
+             for lvl in dict.fromkeys((case.reassociate, 3, 4))]
+    cands.append(out["RACE-NR"])
+    out["RACE"] = min(cands, key=lambda r: r.op_table()["weighted_total"])
+    return out
+
+
 def test_esr_plus_vs_race_across_paper_kernels():
     """Paper section 9.3: RACE beats or ties ESR+ on every case (static op
     totals stand in for runtime on this container)."""
-    import sys
-
-    sys.path.insert(0, str(REPO))
-    from benchmarks.common import variants
-
     from repro.apps.paper_kernels import get_case
 
     for name in ["calc_tpoints", "hdifft_gm", "psinv", "gaussian", "poisson"]:
         v = variants(get_case(name))  # RACE with profit-driven level choice
         assert (v["RACE"].op_table()["weighted_total"]
                 <= v["ESR+"].op_table()["weighted_total"] + 1e-9), name
+
+
+#: paper Table 1 static analysis of full RACE: (reduced ops, auxiliary
+#: arrays, algorithm rounds) per case, as this implementation computes them
+TABLE1 = {
+    "hdifft_gm": (0.786, 3, 3), "calc_tpoints": (0.625, 9, 3),
+    "ocn_export": (0.214, 3, 1), "rhs_ph1": (0.380, 9, 3),
+    "rhs_ph2": (0.380, 9, 3), "diffusion1": (0.286, 4, 2),
+    "diffusion2": (0.283, 5, 2), "diffusion3": (0.287, 5, 2),
+    "psinv": (0.419, 5, 3), "resid": (0.419, 5, 3), "rprj3": (0.267, 6, 2),
+    "gaussian": (0.540, 13, 5), "j3d27pt": (0.648, 10, 5),
+    "poisson": (0.364, 3, 2), "derivative": (0.556, 9, 2),
+}
+
+
+@pytest.mark.parametrize("name", TABLE1_ORDER)
+def test_table1_static_counts(name):
+    from repro.apps.paper_kernels import get_case
+
+    full = variants(get_case(name))["RACE"]
+    assert (round(full.reduced_ops(), 3), full.n_aux(),
+            full.rounds()) == TABLE1[name]
+
+
+def _hlo_op_counts(fn, env) -> dict:
+    """Sine/cosine and multiply ops in the compiled HLO of ``fn``."""
+    import re
+
+    txt = jax.jit(fn).lower(env).compile().as_text()
+    return {
+        "sincos": len(re.findall(r"= (?:\w+\s+)?(?:cosine|sine)\(", txt))
+        + len(re.findall(r" (?:cosine|sine)\(", txt)),
+        "mul": len(re.findall(r" multiply\(", txt)),
+    }
+
+
+def test_calc_tpoints_hlo_transcendentals():
+    """The elimination survives XLA: the compiled plan evaluator of
+    calc_tpoints emits 5 sine/cosine ops where the baseline emits 20, and 5
+    multiplies where it emits 11."""
+    from repro.apps.paper_kernels import get_case
+    from repro.core.race import race
+    from repro.testing import build_env
+
+    case = get_case("calc_tpoints")
+    res = race(case.program, reassociate=case.reassociate,
+               rewrite_div=case.rewrite_div)
+    env = build_env(case)
+    assert _hlo_op_counts(res.baseline_evaluator(), env) == {
+        "sincos": 20, "mul": 11}
+    assert _hlo_op_counts(res.evaluator(), env) == {"sincos": 5, "mul": 5}
+
+
+_LAYERING = r"""
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import importlib, pkgutil, sys
+import numpy as np
+import repro.core, repro.lowering, repro.serve, repro.shard
+for pkg in (repro.core, repro.lowering, repro.serve, repro.shard):
+    for m in pkgutil.iter_modules(pkg.__path__, pkg.__name__ + "."):
+        importlib.import_module(m.name)
+from repro.apps.paper_kernels import get_case
+from repro.core.race import race
+from repro.launch.mesh import make_stencil_mesh
+from repro.testing import build_env, reference
+case = get_case("psinv", 18)
+env = build_env(case)
+res = race(case.program, mesh=make_stencil_mesh(4))
+got = res.run_sharded(env)
+want = reference(res.plan, env)
+for k in want:
+    np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                               rtol=1e-4, atol=1e-5)
+above = sorted(m for m in sys.modules
+               if m.split(".")[0] == "benchmarks"
+               or m.startswith(("repro.models", "repro.kernels",
+                                "repro.configs")))
+assert not above, above
+print("OK layering")
+"""
+
+
+def test_race_imports_nothing_above_it():
+    """RACE's core, lowering, serve and shard packages, and a sharded psinv
+    run on a forced 4-device host mesh, import nothing from the LM stack
+    (``repro.models``, ``repro.configs``), the removed ``kernels/``
+    package or the removed CPU benchmark suite.  Subprocess: the
+    device-count flag is process-global."""
+    import os
+    import subprocess
+    import sys
+
+    r = subprocess.run(
+        [sys.executable, "-c", _LAYERING], capture_output=True, text=True,
+        env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", ""),
+             "JAX_COMPILATION_CACHE_DIR":
+                 os.environ["JAX_COMPILATION_CACHE_DIR"]}, timeout=300)
+    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+    assert "OK layering" in r.stdout
