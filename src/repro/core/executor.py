@@ -15,8 +15,9 @@ Layers:
     definitions; loop *variable names* are cosmetic and excluded), memoized
     on the plan instance;
   * :class:`CompiledRace` — one specialization per ``(plan hash, env
-    signature, backend, block config)``: the XLA evaluator path jitted (the
-    pre-PR-3 ``RaceResult.run`` re-jitted on *every* call), or the Pallas
+    signature, backend, block config, iteration order)``: the XLA evaluator
+    path jitted (the pre-PR-3 ``RaceResult.run`` re-jitted on *every*
+    call), or the Pallas
     path specialized once against the dimension-generic lowering engine's
     :class:`~repro.lowering.LoweredStencil` artifact
     (:func:`repro.lowering.specialize_stencil`) with a jitted per-call data
@@ -49,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs as _obs
+from repro.lowering.geometry import ORDER_AS_LAID
 
 from .backend import BACKENDS, Selection, select_backend
 from .depgraph import Plan
@@ -272,6 +274,9 @@ class ExecutorKey:
     #: (block_rows, block_cols, block_inner) | None (xla)
     blocks: Optional[tuple]
     donate: bool
+    #: the Pallas kernel's iteration order (``repro.lowering.ORDERS``);
+    #: "" for xla
+    order: str = ""
     #: device context (``device_context()``); "" only on legacy keys
     device: str = ""
     #: sharded entries only: (((axis, size), ...), (device ids, ...))
@@ -345,7 +350,8 @@ class CompiledRace:
 
     def __init__(self, plan: Plan, env_sig: tuple, selection: Selection, *,
                  block_rows: int = 8, block_cols: int = 8,
-                 block_inner: int = 0, donate: bool = False):
+                 block_inner: int = 0, donate: bool = False,
+                 order: str = ORDER_AS_LAID):
         self.plan = plan
         self.env_sig = env_sig
         self.selection = selection
@@ -353,6 +359,7 @@ class CompiledRace:
         self.block_rows = block_rows
         self.block_cols = block_cols
         self.block_inner = block_inner
+        self.order = order if self.backend == "pallas" else None
         self.donate = donate
         self.calls = 0
         self.batch_calls = 0
@@ -380,7 +387,7 @@ class CompiledRace:
                     {nm: shp for nm, shp, *_ in env_sig},
                     {nm: np.dtype(dt) for nm, _, dt, *_ in env_sig},
                     block_rows=block_rows, block_cols=block_cols,
-                    block_inner=block_inner)
+                    block_inner=block_inner, order=order)
                 core = self.spec.apply
             else:
                 from .codegen import build_plan_evaluator, interior
@@ -497,7 +504,7 @@ class CompiledRace:
     # -- introspection ------------------------------------------------------
 
     def cache_info(self) -> dict:
-        return dict(backend=self.backend, calls=self.calls,
+        return dict(backend=self.backend, order=self.order, calls=self.calls,
                     batch_calls=self.batch_calls,
                     trace_count=self.trace_count,
                     batch_trace_count=self.batch_trace_count,
@@ -677,11 +684,12 @@ def _resolve(plan: Plan, backend: str) -> Selection:
     return sel
 
 
-def _tuned_choice(plan: Plan, sig: tuple) -> Optional[dict]:
+def _tuned_choice(plan: Plan, sig: tuple, order: str) -> Optional[dict]:
     """Consult the persistent autotuning store (``repro.tuning``) for this
-    (plan, env signature) on this device/jax version.  Returns the recorded
-    choice dict, or None — and *never* raises: a corrupt or stale store must
-    degrade to the static default, not take the serving path down.
+    (plan, env signature, iteration order) on this device/jax version.
+    Returns the recorded choice dict, or None — and *never* raises: a
+    corrupt or stale store must degrade to the static default, not take the
+    serving path down.
 
     Runs on every ``backend="auto"`` call — i.e. on the steady-state serving
     path — so the expensive key construction (JSON of the env signature plus
@@ -694,9 +702,10 @@ def _tuned_choice(plan: Plan, sig: tuple) -> Optional[dict]:
         memo = getattr(plan, "_tuning_key_memo", None)
         if memo is None:
             memo = plan._tuning_key_memo = {}
-        key = memo.get(sig)
+        key = memo.get((sig, order))
         if key is None:
-            key = memo[sig] = record_key("plan", plan_hash(plan), sig)
+            key = memo[sig, order] = record_key("plan", plan_hash(plan), sig,
+                                                order=order)
         choice = plan_choice(key)
         if not isinstance(choice, dict):
             return None
@@ -714,7 +723,8 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
                  backend: Optional[str] = None, *, block_rows: int = 8,
                  block_cols: int = 8, block_inner: int = 0,
                  donate: Optional[bool] = None,
-                 cache: Optional[ExecutorCache] = None) -> CompiledRace:
+                 cache: Optional[ExecutorCache] = None,
+                 order: str = ORDER_AS_LAID) -> CompiledRace:
     """Fetch (or build) the compiled executor for this (plan, env) pairing.
 
     ``env`` is either an environment mapping or a precomputed
@@ -732,12 +742,21 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
     by every call, so the caller must re-supply fresh buffers each time —
     hence off by default (and forced off on CPU, which ignores donation and
     would warn per call).
+
+    ``order`` is the Pallas kernel's iteration order
+    (``repro.lowering.ORDERS``), part of the key and of the tuning record
+    consulted.  One chip's executors iterate as laid (the default): their
+    operands cross into the module as the caller laid them, so a
+    level-major kernel would pay a transpose per operand and output.  The
+    sharded executor (:func:`repro.shard.compile_sharded`) passes
+    level-major for its local kernel, whose operands its halo prologue
+    writes anew in the same module.
     """
     sig = env if isinstance(env, tuple) else env_signature(env)
     if backend is None:
         backend = default_backend()
     if backend == "auto":
-        choice = _tuned_choice(plan, sig)
+        choice = _tuned_choice(plan, sig, order)
         if choice is not None:
             if choice["backend"] == "pallas":
                 try:
@@ -747,7 +766,7 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
                         block_cols=int(choice.get("block_cols", block_cols)),
                         block_inner=int(choice.get("block_inner",
                                                    block_inner)),
-                        donate=donate, cache=cache)
+                        donate=donate, cache=cache, order=order)
                 except ValueError:
                     # stale/corrupt stored block config (e.g. a block too
                     # small for the plan's halo spread, from a hand-edited
@@ -762,11 +781,11 @@ def compile_plan(plan: Plan, env: Union[Mapping, tuple],
         donate = False
     elif donate and jax.default_backend() in ("cpu",):
         donate = False
-    blocks = ((block_rows, block_cols, block_inner)
-              if sel.backend == "pallas" else None)
+    pallas = sel.backend == "pallas"
+    blocks = (block_rows, block_cols, block_inner) if pallas else None
     key = ExecutorKey(plan_hash(plan), sig, sel.backend, blocks, bool(donate),
-                      device=device_context())
+                      order=order if pallas else "", device=device_context())
     c = cache if cache is not None else _CACHE
     return c.get_or_build(key, lambda: CompiledRace(
         plan, sig, sel, block_rows=block_rows, block_cols=block_cols,
-        block_inner=block_inner, donate=bool(donate)))
+        block_inner=block_inner, donate=bool(donate), order=order))

@@ -9,7 +9,8 @@ shape:
     facts shared with the capability probe (pure data);
   * :mod:`repro.lowering.geometry` — plan analysis: eligibility, aux tile
     extensions, offset envelopes, mirrored-origin normalization for negative
-    coefficients (pure; imports no jax — the probe delegates here);
+    coefficients, the two iteration orders (pure; imports no jax — the
+    probe delegates here);
   * :mod:`repro.lowering.blocks`   — N-D BlockSpec/grid construction for any
     nest depth (1-D scans through ≥4-D tensors);
   * :mod:`repro.lowering.gather`   — in-kernel index gather for
@@ -32,9 +33,10 @@ from .facts import (FALLBACK_CODES, RETIRED_CODES, R_CONSTANT_DIM, R_DEPTH,
                     R_PLATFORM, R_REPEATED_LEVEL, R_SCALAR_AUX,
                     R_STRIDED_AUX, R_TPU_GATHER, R_TPU_STRIDED, R_ZERO_COEF,
                     FallbackReason, LoweringError, LoweringFact)
-from .geometry import (K_GATHER, K_WINDOW, ArrayInfo, LoweringAnalysis,
-                       analyze_plan, analyze_program, offset_envelopes,
-                       platform_reasons, program_envelopes, target_platform)
+from .geometry import (K_GATHER, K_WINDOW, ORDER_AS_LAID, ORDER_LEVEL_MAJOR,
+                       ORDERS, ArrayInfo, LoweringAnalysis, analyze_plan,
+                       analyze_program, offset_envelopes, platform_reasons,
+                       program_envelopes, target_platform)
 
 #: emit-side symbols resolved lazily (they import jax + Pallas)
 _EMIT = ("LoweredStencil", "specialize_stencil", "pallas_interpret",
@@ -49,7 +51,8 @@ __all__ = [
     "R_PLATFORM", "R_REPEATED_LEVEL", "R_SCALAR_AUX", "R_STRIDED_AUX",
     "R_TPU_GATHER", "R_TPU_STRIDED", "R_ZERO_COEF",
     "FallbackReason", "LoweringError", "LoweringFact",
-    "K_GATHER", "K_WINDOW", "ArrayInfo", "LoweringAnalysis",
+    "K_GATHER", "K_WINDOW", "ORDER_AS_LAID", "ORDER_LEVEL_MAJOR", "ORDERS",
+    "ArrayInfo", "LoweringAnalysis",
     "analyze_plan", "analyze_program", "offset_envelopes",
     "platform_reasons", "program_envelopes", "target_platform",
     *_EMIT, *_BLOCKS, *_GATHER,

@@ -1,8 +1,12 @@
 """N-D BlockSpec / grid construction for the generic lowering engine.
 
-The iteration space is laid out level-major (outermost loop level = axis 0).
-Every level except the innermost is grid-tiled — level 1 by ``block_rows``,
-levels ``2..m-1`` by ``block_cols`` — and the innermost level stays
+The iteration space is laid out level-major over the plan it is given
+(outermost loop level = axis 0).  An as-laid specialization gives it the plan
+with its levels renumbered in the first output's dim order
+(:func:`~repro.lowering.geometry.relabel_levels`), so the same rules tile
+the output's own dims and its last dim takes the lanes.  Every level
+except the innermost is grid-tiled — level 1 by ``block_rows``, levels
+``2..m-1`` by ``block_cols`` — and the innermost level stays
 full-width for the VPU lanes unless ``block_inner > 0`` tiles it too.  The
 single level of a 1-D nest is its innermost level, so it follows the same
 rule.  A 4-D nest gets a 3-axis grid (levels 1-3).
@@ -88,7 +92,7 @@ def _knob(l: int, m: int, block_inner: int) -> str:
 class ArrayPrep:
     """Per-call data movement for one base array (static amounts)."""
 
-    tperm: tuple  # transpose into ascending-level order, or () if identity
+    tperm: tuple  # transpose into the kernel's axis order, or () if identity
     flips: tuple  # post-transpose axes to jnp.flip (mirrored-origin levels)
     pads: tuple  # per-axis (left, right) zero pad
     sls: tuple  # per-axis window slice after padding
@@ -120,7 +124,7 @@ class Layout:
     out_specs: list
     out_shape: list
     out_tile: tuple
-    out_axes: dict  # out name -> inverse level-major transpose, or ()
+    out_axes: dict  # out name -> transpose to its own dim order, or ()
     read_factor: dict  # base name -> elements fetched / elements it reads
 
 

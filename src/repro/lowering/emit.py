@@ -7,8 +7,14 @@ size O(tile + reuse-halo), the paper's "compute the precompute loop inside
 the streaming loop with a small rolling buffer" re-expressed for the
 HBM->VMEM hierarchy — now generic over nest depth and window shape:
 
-  * the iteration space is level-major; ``repro.lowering.blocks`` grid-tiles
-    every level but the innermost (any depth), and each window operand
+  * the iteration space is laid out in one of two orders
+    (``specialize_stencil(order=...)``): as laid, the kernel's axes follow
+    the first output's own dim order, so operands laid the same way and the
+    outputs cross without a transpose; level-major, they follow the loop
+    levels.  Either way the engine sees a level-major nest: as laid is the
+    plan with its levels renumbered (:func:`~repro.lowering.geometry.
+    relabel_levels`).  ``repro.lowering.blocks`` grid-tiles every axis but
+    the last (any depth), and each window operand
     arrives as one overlapping window per grid step: the output tile
     widened by the operand's offset envelope, aligned to the TPU's (8, 128)
     tiling on the last two axes (``LoweredStencil.read_factor`` records
@@ -45,8 +51,9 @@ from repro.core.ir import Const, Expr, Node, Ref
 from .blocks import ArrayPrep, Layout, build_layout
 from .facts import LoweringError
 from .gather import gather_ref
-from .geometry import (LoweringAnalysis, analyze_plan, aux_shift,
-                       platform_reasons, ref_affine, target_platform)
+from .geometry import (ORDER_AS_LAID, ORDER_LEVEL_MAJOR, LoweringAnalysis,
+                       analyze_plan, aux_shift, nest_levels, platform_reasons,
+                       ref_affine, relabel_levels, target_platform)
 
 #: device-trace names: the Pallas kernel's op is named ``<prefix><program
 #: label>`` (under ``vmap``, ``vmap_<prefix><label>_``), and the layout
@@ -182,7 +189,9 @@ class LoweredStencil:
     ops, so one artifact serves arbitrarily many calls (and batches) without
     redoing host-side prep.  ``analysis`` carries the lowering facts
     (mirrored windows, gather operands, N-D depth) this specialization
-    engaged."""
+    engaged, in the plan's own level numbers; ``order`` is the order the
+    kernel iterates the nest in (``extents`` and the kernel's axes follow
+    it)."""
 
     plan: Plan
     scalar_names: tuple
@@ -191,8 +200,9 @@ class LoweredStencil:
     dt: object  # result dtype of the kernel operands/outputs
     prep: dict  # base name -> ArrayPrep
     extents: tuple
-    out_axes: dict  # out name -> inverse level-major transpose, or ()
+    out_axes: dict  # out name -> transpose to its own dim order, or ()
     interpret: bool
+    order: str = ORDER_AS_LAID  # ORDER_AS_LAID | ORDER_LEVEL_MAJOR
     lift: bool = False  # 1-D nest: outputs carry a leading size-1 axis
     #: base name -> elements the kernel fetches over the grid / elements of
     #: the region it reads (1.0: each element once)
@@ -252,17 +262,23 @@ def pallas_interpret() -> bool:
 def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
                        block_rows: int = 8, block_cols: int = 8,
                        interpret: Optional[bool] = None,
-                       block_inner: int = 0) -> LoweredStencil:
+                       block_inner: int = 0,
+                       order: str = ORDER_AS_LAID) -> LoweredStencil:
     """Build the static half of the blocked Pallas execution.
 
     ``shapes`` maps env entry names to ``np.shape``-style tuples (``()`` for
     scalars) and ``dtypes`` to their dtypes; together they are the
-    environment *signature* the artifact is specialized against.  The grid
-    tiles every level but the innermost — level 1 by ``block_rows``, middle
-    levels by ``block_cols``.  The innermost level (the only level of a 1-D
-    nest) stays full-width by default (VPU lanes); ``block_inner > 0``
-    grid-tiles it too — for very wide rows whose full-width blocks would not
-    fit VMEM — at the cost of a halo copy along the innermost axis.
+    environment *signature* the artifact is specialized against.
+
+    ``order`` is the order the kernel iterates the nest in
+    (:data:`~repro.lowering.geometry.ORDERS`): as laid (the default, one
+    chip's executors) its axes follow the first output's dims, level-major
+    (a sharded executor's local kernel) the loop levels.  The grid tiles
+    every axis but the last — the first by ``block_rows``, middle ones by
+    ``block_cols``.  The last axis (the only one of a 1-D nest) stays
+    full-width by default (VPU lanes); ``block_inner > 0`` grid-tiles it
+    too — for very wide rows whose full-width blocks would not fit VMEM — at
+    the cost of a halo copy along it.
 
     ``interpret=None`` takes the platform's mode (:func:`pallas_interpret`);
     an explicit value lets a test build the compiled kernel on a CPU host.
@@ -279,9 +295,14 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
         analysis, "cpu" if interpret else "tpu")
     if reasons:
         raise LoweringError(reasons)
-    layout = build_layout(analysis, shapes, dtypes, block_rows, block_cols,
+    levels = nest_levels(plan, order)
+    kplan, kanalysis = plan, analysis
+    if levels != tuple(sorted(levels)):
+        kplan = relabel_levels(plan, levels)
+        kanalysis = analyze_plan(kplan)
+    layout = build_layout(kanalysis, shapes, dtypes, block_rows, block_cols,
                           block_inner)
-    kernel = build_kernel(plan, analysis, layout)
+    kernel = build_kernel(kplan, kanalysis, layout)
     call = pl.pallas_call(
         kernel,
         grid=layout.grid,
@@ -292,14 +313,17 @@ def specialize_stencil(plan: Plan, shapes: dict, dtypes: dict,
         name=KERNEL_PREFIX + plan.program.label,
     )
     if _obs.enabled():
+        label = plan.program.label
         for nm, factor in layout.read_factor.items():
-            _obs.gauge("race_kernel_read_factor", program=plan.program.label,
+            _obs.gauge("race_kernel_read_factor", program=label,
                        array=nm).set(factor)
+        _obs.gauge("race_kernel_level_major", program=label).set(
+            float(order == ORDER_LEVEL_MAJOR))
     return LoweredStencil(plan=plan, scalar_names=layout.scalar_names,
                           base_names=layout.base_names,
                           out_names=layout.out_names, dt=layout.dt,
                           prep=layout.prep, extents=layout.extents,
                           out_axes=layout.out_axes, interpret=interpret,
-                          lift=layout.lift,
+                          order=order, lift=layout.lift,
                           read_factor=layout.read_factor,
                           analysis=analysis, _call=call)

@@ -33,12 +33,13 @@ far end of the axis) recenter instead of padding the whole array.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from fractions import Fraction
 from typing import Optional
 
 from repro.core.depgraph import Plan, _aux_ref_shifts
-from repro.core.ir import Expr, Program, Ref, expr_refs
+from repro.core.ir import (Expr, Loop, Program, Ref, Stmt, Sub, expr_refs,
+                           map_expr)
 
 from .facts import (R_CONSTANT_DIM, R_DEPTH, R_FRACTIONAL_OFFSET,
                     R_INCONSISTENT_LAYOUT, R_LHS_FORM, R_MIXED_STRIDE,
@@ -50,6 +51,17 @@ from .facts import (R_CONSTANT_DIM, R_DEPTH, R_FRACTIONAL_OFFSET,
 #: array classification (ArrayInfo.kind)
 K_WINDOW = "window"  # blocked halo-exchange windows (the fast path)
 K_GATHER = "gather"  # whole-array operand + in-kernel index gather
+
+#: the order a Pallas specialization iterates the nest in
+#: (``LoweredStencil.order``).  As laid, the kernel's axes follow the first
+#: output's own dim order, so an operand laid the same way and the outputs
+#: need no per-call transpose; one chip's executors take it.  Level-major,
+#: they follow the loop levels, outermost first; a sharded executor's local
+#: kernel takes it, since its halo prologue writes every operand anew in the
+#: same module.
+ORDER_AS_LAID = "as_laid"
+ORDER_LEVEL_MAJOR = "level_major"
+ORDERS = (ORDER_AS_LAID, ORDER_LEVEL_MAJOR)
 
 
 @dataclass
@@ -316,6 +328,53 @@ def _analyze(plan: Plan) -> LoweringAnalysis:
         visit_base(plan.aux_exprs[a.name], ext[a.name])
 
     return LoweringAnalysis(plan, m, True, (), facts, arrays, ext)
+
+
+def nest_levels(plan: Plan, order: str) -> tuple:
+    """The loop levels in the order the kernel's axes iterate them: the
+    first output's dim order (as laid) or ascending (level-major)."""
+    if order == ORDER_AS_LAID:
+        return tuple(s.s for s in plan.body[0].lhs.subs)
+    if order == ORDER_LEVEL_MAJOR:
+        return tuple(range(1, plan.program.depth + 1))
+    raise ValueError(f"unknown iteration order {order!r}; one of {ORDERS}")
+
+
+def relabel_levels(plan: Plan, levels: tuple) -> Plan:
+    """The same plan with loop level ``levels[k]`` renumbered ``k + 1``.
+
+    RACE's plans are fully parallel nests over a box, so renumbering the
+    levels changes the order a kernel iterates them in and nothing it
+    computes: every reference, auxiliary and range keeps its meaning."""
+    new = {old: k + 1 for k, old in enumerate(levels)}
+
+    def sub(s: Sub) -> Sub:
+        return Sub(s.a, new[s.s], s.b) if s.s else s
+
+    def ref(r: Ref) -> Ref:
+        return Ref(r.name, tuple(sub(s) for s in r.subs)) if r.subs else r
+
+    def expr(e: Expr) -> Expr:
+        return map_expr(e, lambda x: ref(x) if isinstance(x, Ref) else x)
+
+    def per_level(d: dict) -> dict:
+        return {nm: {new[l]: v for l, v in lv.items()}
+                for nm, lv in d.items()}
+
+    prog = plan.program
+    loops = tuple(Loop(new[lp.level], lp.var, lp.lo, lp.hi)
+                  for lp in sorted(prog.loops, key=lambda lp: new[lp.level]))
+    body = tuple(Stmt(ref(st.lhs), expr(st.rhs), st.loc) for st in plan.body)
+    return replace(
+        plan,
+        program=Program(loops, tuple(Stmt(ref(st.lhs), expr(st.rhs), st.loc)
+                                     for st in prog.body),
+                        prog.loc, prog.name),
+        body=body,
+        aux_order=[replace(a, levels=tuple(sorted(new[l] for l in a.levels)),
+                           expr=expr(a.expr)) for a in plan.aux_order],
+        aux_exprs={nm: expr(e) for nm, e in plan.aux_exprs.items()},
+        ranges=per_level(plan.ranges), windows=per_level(plan.windows))
 
 
 def target_platform() -> str:

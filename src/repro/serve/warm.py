@@ -123,8 +123,10 @@ def warmup(items: Sequence[Tuple[object, Union[Mapping, tuple]]], *,
 
 def store_plan_keys(store=None) -> list:
     """``(plan_hash, env signature, batch)`` for every plan-kind record in
-    the tuning store matching this process's runtime fence.  Tolerant of
-    malformed keys (skipped) and a missing store (empty list)."""
+    the tuning store matching this process's runtime fence and measured as
+    laid (the order of the one-chip executors a warm-up builds).  Tolerant
+    of malformed keys (skipped) and a missing store (empty list)."""
+    from repro.lowering.geometry import ORDER_AS_LAID
     from repro.tuning.store import default_store, runtime_fence, sig_json
 
     try:
@@ -138,12 +140,13 @@ def store_plan_keys(store=None) -> list:
             if parts[3] != str(fence["device"]) or parts[4] != str(
                     fence["jax"]):
                 continue
-            batch = 0
-            if len(parts) >= 6 and parts[5].startswith("batch="):
-                try:
-                    batch = int(parts[5][len("batch="):])
-                except ValueError:
-                    continue
+            extra = dict(p.partition("=")[::2] for p in parts[5:])
+            if extra.get("order") != ORDER_AS_LAID:
+                continue
+            try:
+                batch = int(extra.get("batch", 0))
+            except ValueError:
+                continue
             try:
                 import json
 

@@ -6,7 +6,9 @@ takes a :class:`~repro.core.race.RaceResult` plus a device mesh, re-ranges the
 plan's sharded levels to one chunk, runs RACE on the *local* program with the
 global result's own knobs (so the per-shard plan is the same optimization the
 single-device path would execute on a chunk-sized grid), compiles it through
-the ordinary plan-keyed executor cache, and wraps its raw core in a
+the ordinary plan-keyed executor cache with a level-major Pallas kernel (the
+halo prologue writes each operand anew in the same module, so the kernel's
+order costs no pass of its own), and wraps its raw core in a
 ``shard_map`` whose in/out specs and device prologue come from the halo
 program.  The whole dispatch — host slab layout, collective exchange, local
 stencil — is jitted once per :class:`ShardedRace`.
@@ -37,6 +39,7 @@ from typing import Mapping, Optional, Union
 
 from repro import obs as _obs
 from repro.core.ir import Loop, Program
+from repro.lowering.geometry import ORDER_LEVEL_MAJOR
 
 from .halo import L_SHARD, plan_halo
 from .partition import plan_partition
@@ -306,7 +309,7 @@ def compile_sharded(result, env: Union[Mapping, tuple], mesh, *,
             local_ex = compile_plan(
                 local_res.plan, local_sig, backend, block_rows=block_rows,
                 block_cols=block_cols, block_inner=block_inner,
-                donate=False, cache=c)
+                donate=False, cache=c, order=ORDER_LEVEL_MAJOR)
         if _obs.enabled():
             _obs.event("shard_plan", plan=ph,
                        local_plan=plan_hash(local_res.plan),

@@ -441,9 +441,11 @@ class HaloProgram:
         shard sits at ``kL`` in ``[left, own, right]`` and the slab starts
         ``L_p = p·(S − e)`` planes left of it.  A later dim's edge planes
         are cut from the block extended along the earlier dims (uncut, so
-        ``kL + S + kR`` long there); with a single cut at the end, XLA
-        fuses the joins and the cut into the slab's first consumer and
-        writes no whole-shard intermediate."""
+        ``kL + S + kR`` long there); a single cut at the end lets XLA fuse
+        the joins and the cut into one fusion.  That fusion still writes
+        the slab whole, beside the level-major swap before it and the
+        kernel's pad after it (NPB MG class D on a v5e 2x2: seven
+        whole-shard ops per module besides the kernel)."""
         import jax.numpy as jnp
         from jax import lax
 

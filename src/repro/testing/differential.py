@@ -35,7 +35,9 @@ from repro.core.backend import select_backend
 from repro.core.codegen import (build_baseline_evaluator,
                                 build_plan_evaluator, interior,
                                 required_shapes)
+from repro.core.executor import compile_plan
 from repro.core.race import race
+from repro.lowering.geometry import ORDER_AS_LAID
 
 #: grid sizes keeping a full CPU interpret-mode sweep under a minute
 SWEEP_SIZES = {
@@ -147,12 +149,14 @@ def run_case(case, reassociate_levels: Iterable[int] = (0, 3, 4),
              backends: Iterable[str] = ("xla", "pallas"),
              dtype=np.float32, seed: int = 0, block_rows: int = 8,
              block_cols: int = 8, block_inner: int = 0,
-             tolerances: Optional[dict] = None) -> CaseReport:
-    """Differential-verify one case across plans and backends."""
+             tolerances: Optional[dict] = None,
+             order: str = ORDER_AS_LAID) -> CaseReport:
+    """Differential-verify one case across plans and backends; ``order`` is
+    the Pallas kernel's iteration order (``repro.lowering.ORDERS``)."""
     tol = tolerances or default_tolerances(dtype)
     with _x64_ctx(dtype):
         return _run_case_impl(case, reassociate_levels, backends, dtype, seed,
-                              block_rows, block_cols, block_inner, tol)
+                              block_rows, block_cols, block_inner, tol, order)
 
 
 def _x64_ctx(dtype):
@@ -167,7 +171,8 @@ def _x64_ctx(dtype):
 
 
 def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
-                   block_rows, block_cols, block_inner, tol) -> CaseReport:
+                   block_rows, block_cols, block_inner, tol,
+                   order) -> CaseReport:
     env = build_env(case, dtype=dtype, seed=seed)
     report = CaseReport(case.name)
 
@@ -194,9 +199,11 @@ def _run_case_impl(case, reassociate_levels, backends, dtype, seed,
                         combo.reason = sel.capability.explain()
                         report.combos.append(combo)
                         continue
-                    out = res.run(env, "pallas", block_rows=block_rows,
-                                  block_cols=block_cols,
-                                  block_inner=block_inner)
+                    out = compile_plan(res.plan, env, "pallas",
+                                       block_rows=block_rows,
+                                       block_cols=block_cols,
+                                       block_inner=block_inner,
+                                       order=order).run(env)
                 combo.max_rel_err = _rel_err(out, truth)
                 if combo.max_rel_err > tol["baseline"]:
                     combo.status = "mismatch"

@@ -4,14 +4,16 @@ One JSON-lines file — ``$RACE_TUNING_CACHE`` (a directory, or a ``*.jsonl``
 file path) or ``~/.cache/repro-race/tuning.jsonl`` — holds one record per
 line.  Records are keyed by
 
-    (kind, structural hash, env signature, device kind, jax version)
+    (kind, structural hash, env signature, device kind, jax version,
+     iteration order)
 
 where ``kind`` is ``"program"`` (the tuner's full decision, reassociation
 level included) or ``"plan"`` (backend + block config for one already-chosen
 plan — what ``compile_plan(..., backend="auto")`` consults), the structural
 hash is :func:`repro.core.executor.program_hash` / ``plan_hash``, and device
 kind + jax version fence records to the hardware/runtime they were measured
-on.
+on; the Pallas kernel's iteration order keeps one chip's records (as laid)
+from a sharded executor's level-major local kernel.
 
 Durability contract (pinned by tests):
 
@@ -42,6 +44,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Mapping, Optional
+
+from repro.lowering.geometry import ORDER_AS_LAID
 
 SCHEMA_VERSION = 1
 
@@ -134,18 +138,21 @@ def sig_json(sig: tuple) -> str:
 
 def record_key(kind: str, struct_hash: str, sig: tuple,
                fence: Optional[Mapping] = None, opts: str = "",
-               batch: int = 0) -> str:
+               batch: int = 0, order: str = ORDER_AS_LAID) -> str:
     """Store key.  ``opts`` is a canonical token of the *search-shaping*
     options (program-kind records only): a decision found by a narrower
     search (``backends=("xla",)``, restricted ``levels``, ...) must never
     answer a later full-space request, so the searched space is part of the
     record's identity.  ``batch > 0`` marks a record measured on the
     *batched* (vmapped) executor at that batch size — a separate population
-    from per-call records (``batch=0``, the historical key shape, unchanged
-    so existing stores stay live)."""
+    from per-call records (``batch=0``).  ``order`` is the Pallas kernel's
+    iteration order (``repro.lowering.ORDERS``) the record was measured in:
+    the tuner measures one chip's executors, which iterate as laid, and a
+    sharded executor's level-major local kernel never takes their
+    records."""
     f = fence or runtime_fence()
     parts = [kind, struct_hash, sig_json(sig), str(f["device"]),
-             str(f["jax"])]
+             str(f["jax"]), f"order={order}"]
     if opts:
         parts.append(opts)
     if batch > 0:

@@ -13,8 +13,10 @@ from repro.apps.paper_kernels import CASES, Case, get_case
 from repro.core.backend import (R_MIXED_STRIDE, R_PLATFORM, R_TPU_GATHER,
                                 R_TPU_STRIDED, BackendUnavailable,
                                 probe_pallas, select_backend)
+from repro.core.executor import compile_plan
 from repro.core.ir import arr, loopnest, program
 from repro.core.race import race
+from repro.lowering import ORDERS
 from repro.testing import (build_env, coverage_matrix, reference, run_case,
                            sweep_registry)
 from repro.testing.differential import SWEEP_SIZES
@@ -36,6 +38,36 @@ def test_registry_differential(name):
     # the whole registry now lowers to Pallas — a regression back to the
     # XLA fallback (even a "reasoned" one) would silently void the claim
     assert report.pallas_covered(), coverage_matrix([report])
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("name", sorted(CASES) + ["hdifft_gm-batched"])
+def test_registry_differential_orders(name, order):
+    """Every registry case in both iteration orders of the Pallas kernel:
+    as laid (one chip's executors) and level-major (a sharded executor's
+    local kernel).  The corners: flips (mirror_deriv), a 4-D nest
+    (blocked4d), the 1-D lift (smooth1d), rank-1 operands (rhs_ph1), a
+    gather operand in its whole-array block (diag2d), and hdifft_gm through
+    the vmapped batch."""
+    base, _, batched = name.partition("-")
+    case = get_case(base, SWEEP_SIZES.get(base))
+    levels = sorted({0, case.reassociate})
+    if not batched:
+        report = run_case(case, reassociate_levels=levels, order=order)
+        assert not report.failures(), coverage_matrix([report])
+        assert report.pallas_covered(), coverage_matrix([report])
+        return
+    envs = [build_env(case, np.float32, seed=s) for s in range(3)]
+    for lvl in levels:
+        res = race(case.program, reassociate=lvl)
+        ex = compile_plan(res.plan, envs[0], "pallas", order=order)
+        assert ex.spec.order == order
+        got = ex.run_batch(envs)
+        for b, env in enumerate(envs):
+            for k, want in reference(res.plan, env).items():
+                np.testing.assert_allclose(np.asarray(got[k][b]),
+                                           np.asarray(want), rtol=1e-5,
+                                           atol=1e-6, err_msg=f"{k}[{b}]")
 
 
 @pytest.mark.slow

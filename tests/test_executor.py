@@ -16,6 +16,7 @@ from repro.core.executor import (CompiledRace, ExecutorCache, compile_plan,
                                  plan_fingerprint, plan_hash)
 from repro.core.ir import Scalar, arr, loopnest, mul, program
 from repro.core.race import race
+from repro.lowering import ORDER_AS_LAID, ORDER_LEVEL_MAJOR, ORDERS
 from repro.testing.differential import build_env, reference_plan
 
 
@@ -110,6 +111,29 @@ def test_distinct_keys_per_specialization():
     # xla executors ignore block config in the key (no spurious misses)
     assert (compile_plan(res.plan, env32, "xla", block_rows=4)
             is compile_plan(res.plan, env32, "xla"))
+
+
+def test_orders_never_share_an_executor():
+    """The Pallas kernel's iteration order is part of the key: one chip's
+    default (as laid) and a sharded local kernel's level-major are two
+    executors, each reporting its order; XLA executors have none."""
+    case, res = _res("psinv", 10)
+    env = build_env(case)
+    laid = compile_plan(res.plan, env, "pallas")
+    lm = compile_plan(res.plan, env, "pallas", order=ORDER_LEVEL_MAJOR)
+    assert laid is not lm
+    assert laid is compile_plan(res.plan, env, "pallas", order=ORDER_AS_LAID)
+    assert laid.cache_info()["order"] == laid.spec.order == ORDER_AS_LAID
+    assert lm.cache_info()["order"] == lm.spec.order == ORDER_LEVEL_MAJOR
+    assert {k.order for k in executor_cache().keys()} == set(ORDERS)
+    xla = compile_plan(res.plan, env, "xla")
+    assert xla is compile_plan(res.plan, env, "xla", order=ORDER_LEVEL_MAJOR)
+    assert xla.cache_info()["order"] is None
+    for k, want in xla(env).items():
+        for ex in (laid, lm):
+            np.testing.assert_allclose(np.asarray(ex(env)[k]),
+                                       np.asarray(want), rtol=1e-5,
+                                       atol=1e-6, err_msg=k)
 
 
 def test_lru_eviction():

@@ -34,7 +34,8 @@ from repro.core.race import race
 from repro.lowering import (R_FRACTIONAL_OFFSET, R_INCONSISTENT_LAYOUT,
                             R_LHS_FORM, R_MIXED_STRIDE, R_NO_BASE_ARRAY,
                             R_STRIDED_AUX, R_ZERO_COEF, RETIRED_CODES,
-                            LoweringError, analyze_plan, specialize_stencil)
+                            ORDER_AS_LAID, ORDERS, LoweringError,
+                            analyze_plan, specialize_stencil)
 from repro.testing import (build_env, coverage_matrix, reference,
                            reference_plan, run_case)
 from repro.testing.differential import SWEEP_SIZES
@@ -245,13 +246,44 @@ def test_overlapping_window_matches_numpy():
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("order", ORDERS)
+def test_as_laid_renumbers_every_level(order):
+    """As laid, the kernel iterates the written array's own dim order: an
+    operand laid like the output crosses untransposed, one laid otherwise
+    (w, over levels 2-3 in order) and the auxiliary over levels 2-3 that
+    RACE builds from it are renumbered with the nest; level-major, the
+    transposes fall the other way.  Both agree with NumPy."""
+    loops, (i, j, k) = loopnest(("i", 1, 9), ("j", 1, 10), ("k", 1, 11))
+    w, u, out = arr("w"), arr("u"), arr("po")
+    pair = lambda dk: w[j, k + dk] + w[j, k + dk + 1]  # noqa: E731
+    prog = program(loops, [(out[k, j, i], pair(0) * u[k, j, i]
+                            + pair(1) * u[k, j, i + 1])])
+    res = race(prog, reassociate=3)
+    assert [a.levels for a in res.plan.aux_order] == [(2, 3)]
+    rng = np.random.default_rng(3)
+    env = {"w": rng.standard_normal((12, 14)).astype(np.float32),
+           "u": rng.standard_normal((14, 12, 11)).astype(np.float32)}
+    spec = specialize_stencil(res.plan, *_sig(env), order=order)
+    laid = order == ORDER_AS_LAID
+    assert spec.prep["u"].tperm == (() if laid else (2, 1, 0))
+    assert spec.prep["w"].tperm == ((1, 0) if laid else ())
+    assert spec.out_axes == {"po": () if laid else (2, 1, 0)}
+    got = compile_plan(res.plan, env, "pallas", order=order)(env)["po"]
+    K, J, I = np.ix_(np.arange(1, 12), np.arange(1, 11), np.arange(1, 10))
+    W, U = env["w"].astype(np.float64), env["u"].astype(np.float64)
+    P = lambda dk: W[J, K + dk] + W[J, K + dk + 1]  # noqa: E731
+    want = P(0) * U[K, J, I] + P(1) * U[K, J, I + 1]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("name,size,exact,at_most", [
     # 8x8 tiles of levels 1-2: a halo'd operand reads 8 + spread rows on a
     # leading axis and 16 on the second-minor one, so at most 1.5 x 2
     ("resid", 18, {"V": 1.0}, {"U": 3.0}),
     ("psinv", 18, {"U": 1.0}, {"R": 3.0}),
-    # level 1 is second-minor: a 16-row window for an 8-row tile
-    ("hdifft_gm", (10, 14), {"T": 2.0, "S": 2.0}, {}),
+    # as laid, T[i, j]'s first dim i leads and is second-minor: one
+    # 16-row window over the 10 rows its one 8-row tile reads
+    ("hdifft_gm", (10, 14), {"T": 1.6, "S": 1.6}, {}),
     # levels 1-3 tiled: 8/8 x 9/8 x 16/8 (3**3 = 27 with a copy per
     # neighbour block)
     ("blocked4d", 18, {}, {"T4": 9 / 8 * 16 / 8}),

@@ -417,6 +417,26 @@ def test_kernel_read_factor_gauge():
     assert gauges[f"race_kernel_read_factor{{array=U,program={label}}}"] == 1.0
 
 
+def test_kernel_level_major_gauge():
+    """``race_kernel_level_major{program}`` says which order a Pallas
+    specialization iterates in: 0 as laid (one chip's executors), 1
+    level-major (a sharded executor's local kernel)."""
+    from repro.lowering import ORDERS, specialize_stencil
+
+    _enable()
+    case = get_case("psinv", 18)
+    res = race(case.program, reassociate=case.reassociate)
+    env = build_env(case, np.float32)
+    series = ("race_kernel_level_major"
+              f"{{program={res.plan.program.label}}}")
+    for order, want in zip(ORDERS, (0.0, 1.0)):
+        spec = specialize_stencil(
+            res.plan, {k: np.shape(v) for k, v in env.items()},
+            {k: np.asarray(v).dtype for k, v in env.items()}, order=order)
+        assert spec.order == order
+        assert obs.snapshot()["gauges"][series] == want
+
+
 def test_backend_fallback_event():
     _enable()
     res = race(_strided_program("bf"))

@@ -320,6 +320,38 @@ def test_sharded_matches_single_device_on_unit_mesh(name, n, strategy):
         assert np.array_equal(np.asarray(got[k]), np.asarray(base[k])), k
 
 
+def test_one_chip_as_laid_sharded_local_level_major():
+    """One chip's executors iterate as laid: MG's psinv and resid and POP's
+    hdifft_gm reach the kernel with no transpose of an operand or an
+    output.  The sharded executor's local kernel (psinv on a size-1 CPU
+    mesh) keeps level-major, transposing each operand by (2, 1, 0), since
+    its halo prologue writes the operands anew.  Read from the
+    specializations, with no compile."""
+    from repro.lowering import ORDER_AS_LAID, ORDER_LEVEL_MAJOR
+    from repro.shard import compile_sharded
+
+    for name, n in (("psinv", 10), ("resid", 10), ("hdifft_gm", 14)):
+        case = get_case(name, n)
+        res = race(case.program, reassociate=case.reassociate)
+        ex = compile_plan(res.plan, build_env(case), "pallas",
+                          cache=ExecutorCache())
+        assert ex.cache_info()["order"] == ex.spec.order == ORDER_AS_LAID
+        assert [p.tperm for p in ex.spec.prep.values()] == [()] * len(
+            ex.spec.prep), name
+        assert set(ex.spec.out_axes.values()) == {()}, name
+    case = get_case("psinv", 10)
+    mesh = make_stencil_mesh(1, ("sx", "sy"))
+    res = race(case.program, reassociate=case.reassociate, mesh=mesh)
+    ex = compile_sharded(res, build_env(case), mesh, backend="pallas",
+                         cache=ExecutorCache())
+    spec = ex.local.spec
+    assert ex.cache_info()["local"]["order"] == spec.order
+    assert spec.order == ORDER_LEVEL_MAJOR
+    assert {nm: p.tperm for nm, p in spec.prep.items()} == {
+        "U": (2, 1, 0), "R": (2, 1, 0)}
+    assert spec.out_axes == {"U": (2, 1, 0)}
+
+
 def test_race_mesh_option_routes_run():
     case = get_case("poisson", 10)
     env = build_env(case, np.float32, seed=5)

@@ -22,6 +22,7 @@ import repro.core.backend as backend
 from repro.apps.paper_kernels import CASES, get_case
 from repro.core.backend import probe_pallas
 from repro.core.codegen import required_shapes
+from repro.core.ir import expr_refs
 from repro.core.race import race
 from repro.lowering import R_TPU_GATHER, R_TPU_STRIDED, specialize_stencil
 
@@ -108,6 +109,92 @@ def test_kernel_compiles_for_v5e(one_chip, on_tpu, name, n, batch):
     case, plan = _plan(name, n)
     assert probe_pallas(plan).eligible
     _compile(plan, case.program, one_chip, batch)
+
+
+def _layout_ops(text: str) -> tuple:
+    """The whole-array ops of a compiled module's entry computation besides
+    the kernel (a result at least 90% of the largest parameter), and those
+    of them that lay an array out anew: a transpose, a result in another
+    dim order than the parameters' row-major one, or a copy whose result
+    is laid out otherwise than its operand."""
+    import re
+
+    entry = text[text.index("\nENTRY "):].split("\n}", 1)[0]
+    ops = {}
+    for name, shape, opcode, args in re.findall(
+            r"\n\s+(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+            r"([a-z][a-z\-]*)\(([^)]*)\)", entry):
+        arrays = [([int(d) for d in dims.split(",") if d], lay)
+                  for dims, lay in re.findall(
+                      r"[a-z]\w*\[([\d,]*)\]\{([\d,]*)", shape)]
+        ops[name] = dict(
+            opcode=opcode, arrays=arrays,
+            operands=re.findall(r"%([\w.\-]+)", args),
+            size=max((int(np.prod(d)) for d, _ in arrays), default=0))
+    biggest = max(op["size"] for op in ops.values()
+                  if op["opcode"] == "parameter")
+    whole, relayout = [], []
+    for name, op in ops.items():
+        if op["size"] < 0.9 * biggest or op["opcode"] in (
+                "parameter", "bitcast", "get-tuple-element", "tuple",
+                "custom-call"):
+            continue
+        whole.append(name)
+        src = ops.get(op["operands"][0]) if op["operands"] else None
+        if (op["opcode"] == "transpose"
+                or any(lay != ",".join(map(str, range(len(d) - 1, -1, -1)))
+                       for d, lay in op["arrays"] if d)
+                or (op["opcode"] in ("copy", "copy-start") and src
+                    and src["arrays"][:1] != op["arrays"][:1])):
+            relayout.append(name)
+    return whole, relayout
+
+
+@pytest.mark.parametrize("name,n,batch,at_most", [
+    ("resid", 258, 0, 2),                 # NPB MG class B, the cell's size
+    ("psinv", 258, 0, 2),
+    ("hdifft_gm", (320, 384), 60, None),  # POP gx1v6, all 60 levels
+])
+def test_one_chip_modules_lay_out_nothing(one_chip, on_tpu, name, n, batch,
+                                          at_most):
+    """The one-chip executor's module at the benchmark cells' sizes: its
+    kernel iterates as laid, so no whole operand or output is copied into
+    another dim order or transposed around it, and MG keeps at most its
+    input pad and its centre operand's slice outside the kernel.  The same
+    module level-major holds five such ops in MG: the two operands' and the
+    output's relayouts, the pad and the slice."""
+    from repro.core.executor import ExecutorCache, compile_plan
+    from repro.lowering import ORDER_AS_LAID, ORDER_LEVEL_MAJOR
+
+    case, plan = _plan(name, n)
+    shapes = required_shapes(case.program)
+    if name != "hdifft_gm":  # MG holds every grid at 258^3 (NPB's comm3)
+        shapes = {k: (n,) * 3 if s else () for k, s in shapes.items()}
+    body = case.program.body
+    outs = ({st.lhs.name for st in body}
+            - {r.name for st in body for r in expr_refs(st.rhs)})
+    one = {k: jax.ShapeDtypeStruct(tuple(s), jnp.float32, sharding=one_chip)
+           for k, s in shapes.items() if k not in outs}
+    texts = {}
+    for order in (ORDER_AS_LAID, ORDER_LEVEL_MAJOR):
+        ex = compile_plan(plan, one, "pallas", cache=ExecutorCache(),
+                          order=order)
+        if batch:
+            lowered = ex.batch_jit().lower({
+                k: jax.ShapeDtypeStruct((batch,) + v.shape, v.dtype,
+                                        sharding=one_chip)
+                for k, v in one.items()})
+        else:
+            lowered = ex._jit.lower(*ex._split(one))
+        texts[order] = lowered.compile().as_text()
+    whole, relayout = _layout_ops(texts[ORDER_AS_LAID])
+    assert relayout == [], whole
+    if at_most is not None:
+        assert len(whole) <= at_most, whole
+    whole, relayout = _layout_ops(texts[ORDER_LEVEL_MAJOR])
+    assert relayout, whole  # the check sees what the order removes
+    if at_most is not None:
+        assert len(whole) == 5 and len(relayout) == 3, whole
 
 
 def test_tuning_grid_compiles_for_v5e(one_chip):

@@ -20,6 +20,7 @@ from repro.core.executor import (ExecutorCache, compile_plan,
                                  executor_cache, plan_hash, program_hash)
 from repro.core.ir import Scalar, arr, loopnest, mul, program
 from repro.core.race import race
+from repro.lowering import ORDER_AS_LAID, ORDER_LEVEL_MAJOR
 from repro.testing.differential import build_env, run_case
 from repro.tuning import (SCHEMA_VERSION, TuningStore, autotune,
                           default_store, record_key, runtime_fence,
@@ -405,6 +406,32 @@ def test_compile_plan_applies_stored_block_config():
                                    rtol=1e-5, atol=1e-5, err_msg=k)
     # explicit backend requests bypass the store entirely
     assert compile_plan(res.plan, env, "pallas").block_rows == 8
+
+
+@pytest.mark.pallas
+def test_stored_choice_keyed_by_order():
+    """The tuner measures one chip's executors, which iterate as laid: its
+    record answers an as-laid compile and never a level-major one (a
+    sharded executor's local kernel), which keeps the static default."""
+    case = _case("gaussian", 14)
+    res = race(case.program, reassociate=case.reassociate,
+               rewrite_div=case.rewrite_div)
+    env = build_env(case)
+    sig = env_signature(env)
+    key = record_key("plan", plan_hash(res.plan), sig, runtime_fence())
+    assert key == record_key("plan", plan_hash(res.plan), sig,
+                             runtime_fence(), order=ORDER_AS_LAID)
+    assert key != record_key("plan", plan_hash(res.plan), sig,
+                             runtime_fence(), order=ORDER_LEVEL_MAJOR)
+    default_store().put(_rec(key, choice=dict(
+        reassociate=case.reassociate, backend="pallas", block_rows=16,
+        block_cols=8, block_inner=8)))
+    laid = compile_plan(res.plan, env, "auto")
+    assert (laid.order, laid.block_rows, laid.block_inner) == (
+        ORDER_AS_LAID, 16, 8)
+    lm = compile_plan(res.plan, env, "auto", order=ORDER_LEVEL_MAJOR)
+    assert (lm.order, lm.block_rows, lm.block_inner) == (
+        ORDER_LEVEL_MAJOR, 8, 0)
 
 
 @pytest.mark.pallas
